@@ -46,8 +46,7 @@ _SUB = """
                                          make_stream_engine)
     from repro.core import prng, snn
     from repro.core.lif import LIFStateInt
-    from repro.distributed.sharding import (make_2d_device_mesh,
-                                            shard_map_compat)
+    from repro.distributed.sharding import make_2d_device_mesh
     from repro.kernels.fused_snn import layer_shard_ways
     from repro.serve import SNNStreamEngine
 
@@ -124,9 +123,9 @@ _SUB = """
     st_specs = tuple(LIFStateInt(v=rep, enable=rep) for _ in states)
     tel_spec = {"n_spk": rep, "n_en": rep,
                 "tiles": P(None, ("data", "model"))}
-    f = shard_map_compat(body, mesh,
-                         in_specs=(rep, rep, st_specs, w_specs),
-                         out_specs=(rep, st_specs, rep, rep, tel_spec))
+    f = jax.shard_map(body, mesh=mesh, check_vma=False,
+                      in_specs=(rep, rep, st_specs, w_specs),
+                      out_specs=(rep, st_specs, rep, rep, tel_spec))
     _, st2, x2, adds2, tel2 = f(rng_state, pixels, states, weights)
     t1t = np.asarray(tel1["tiles"])
     t2t = np.asarray(tel2["tiles"])

@@ -63,6 +63,8 @@ def main(argv=None) -> None:
     ap.add_argument("--tiny", action="store_true",
                     help="shrink problem sizes (CI kernel-regression smoke)")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.tiny:
         os.environ["REPRO_BENCH_TINY"] = "1"
     want = args.only.split(",") if args.only else list(SUITES)

@@ -13,6 +13,7 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.configs.base import reduced
 from repro.launch.train import train
@@ -54,4 +55,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -16,6 +16,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.snn_mnist import SNN_CONFIG
 from repro.core import prng, snn
 from repro.core.train_snn import int_accuracy, train_bptt
@@ -57,4 +58,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

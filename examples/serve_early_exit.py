@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.snn_mnist import SNN_CONFIG
 from repro.core import encoding, lif as lif_mod, prng
 from repro.core.pruning import stability_early_exit
@@ -96,6 +97,7 @@ def lm_demo():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     snn_demo()
     stream_demo()
     lm_demo()

@@ -15,6 +15,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.snn_mnist import SNN_CONFIG, SNN_CONFIG_PRUNED
 from repro.core import energy, snn
 from repro.core.train_snn import int_accuracy, train_bptt, train_converted
@@ -69,4 +70,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -255,8 +255,7 @@ def make_cluster(params_q: dict, snn_cfg: SNNConfig = SNN_CONFIG,
 
 
 def make_stream_mesh(knobs: SNNStreamMeshConfig = SNN_STREAM_MESH):
-    """Build the serving lane mesh from the knobs (AxisType-free fallback
-    via distributed.sharding, so it works on the pinned 0.4.x jax).
+    """Build the serving lane mesh from the knobs.
 
     ``model_devices == 1`` keeps the historical 1-D data mesh;
     ``model_devices > 1`` builds the validated 2-D (data × model) mesh.
@@ -269,10 +268,10 @@ def make_stream_mesh(knobs: SNNStreamMeshConfig = SNN_STREAM_MESH):
             data_devices=knobs.num_devices,
             model_devices=knobs.model_devices,
             axis_names=(knobs.axis_name, knobs.model_axis_name))
-    from ..distributed.sharding import make_device_mesh
+    from jax.sharding import AxisType
     n = knobs.num_devices or len(jax.devices())
-    return make_device_mesh((n,), (knobs.axis_name,),
-                            devices=jax.devices()[:n])
+    return jax.make_mesh((n,), (knobs.axis_name,), (AxisType.Auto,),
+                         devices=jax.devices()[:n])
 
 
 def make_stream_engine(params_q: dict, snn_cfg: SNNConfig = SNN_CONFIG,

@@ -469,6 +469,8 @@ def _derive_stack_telemetry(layer_ins, layer_outs, layer_vtr,
 def _apply_int_staged(params_q, pixels_u8, prng_state, cfg: SNNConfig):
     """Staged Pallas kernels: encoder launch + one LIF launch per layer."""
     from ..kernels import ops
+    ops.validate_weight_codes(
+        tuple(layer["w_q"] for layer in params_q["layers"]))
     spikes, prng_next = ops.poisson_encode_op(
         pixels_u8, prng_state, cfg.num_steps)
     x = spikes

@@ -25,33 +25,11 @@ import threading
 from dataclasses import dataclass, field
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["ShardingRules", "use_rules", "current_rules", "logical_spec",
            "shard", "named_sharding", "DEFAULT_RULES", "FSDP_RULES",
-           "make_device_mesh", "make_2d_device_mesh", "shard_map_compat"]
-
-
-def make_device_mesh(shape: tuple, axis_names: tuple, *,
-                     devices=None) -> Mesh:
-    """``jax.make_mesh`` with an ``AxisType``-free fallback.
-
-    Newer jax exposes ``jax.sharding.AxisType`` and accepts an
-    ``axis_types=`` kwarg; the pinned 0.4.x container has neither.  All
-    meshes in this repo use Auto axes (the 0.4.x default), so the fallback
-    — plain ``jax.make_mesh(shape, axis_names)``, or a direct ``Mesh`` over
-    ``mesh_utils.create_device_mesh`` on releases predating ``make_mesh``
-    — constructs the semantically identical mesh.
-    """
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axis_names, devices=devices,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axis_names, devices=devices)
-    from jax.experimental import mesh_utils
-    devs = mesh_utils.create_device_mesh(shape, devices=devices)
-    return Mesh(devs, axis_names)
+           "make_2d_device_mesh"]
 
 
 def make_2d_device_mesh(data_devices: int | None = None,
@@ -91,27 +69,8 @@ def make_2d_device_mesh(data_devices: int | None = None,
         raise ValueError(
             f"{data_devices}×{model_devices} (data × model) mesh needs "
             f"{need} devices but only {len(pool)} are visible")
-    return make_device_mesh((data_devices, model_devices),
-                            tuple(axis_names), devices=pool[:need])
-
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` (per-device SPMD mapping).
-
-    Replication checking is disabled: the streaming SNN chunk runs Pallas
-    calls inside the mapped body, which have no replication rule on the
-    jax releases this repo pins.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-        except TypeError:
-            continue
-    raise RuntimeError("no usable shard_map in this jax installation")
+    return jax.make_mesh((data_devices, model_devices), tuple(axis_names),
+                         (AxisType.Auto,) * 2, devices=pool[:need])
 
 
 @dataclass(frozen=True)

@@ -16,14 +16,13 @@ al. 2019).  This kernel restores the RTL's event-stream locality for an
   * every layer's weight matrix is resident as the paper's native 8-bit
     fixed-point codes: the 9-bit signed weight codes are **packed into two
     int8 planes** (``hi = w >> 1``, ``lo = w & 1``; see
-    :func:`pack_weights`) and widened to int32 only per 128×128 tile, per
-    use — 2 bytes/weight resident instead of the 6 (int16 storage + a
-    whole-matrix int32 cast) the first revision kept live, which is what
-    lets ~3× deeper/wider stacks fit the VMEM residency budget;
+    :func:`pack_weights`) that feed the MXU directly as two int8 passes
+    with int32 accumulation (:func:`spike_dot`) — 2 bytes/weight resident,
+    which is what lets deep/wide stacks fit the VMEM residency budget;
   * the per-layer Σ W·S contraction is tiled 128×128 and **event-driven**
     (``sparse_skip=True``): a K-tile whose spike block is all-zero, or an
     output tile whose enable block is fully pruned, is skipped via
-    ``lax.cond`` — no MXU pass, no widen — instead of merely having its
+    ``lax.cond`` — no MXU pass — instead of merely having its
     result masked.  Skipped tiles contribute exactly zero to the integer
     accumulator and zero executed adds, so the sparse path is bit-identical
     to the dense one (results AND energy counters; integer addition is
@@ -116,8 +115,8 @@ def pack_weights(w_q: jax.Array) -> jax.Array:
     (``core.snn.quantize_params``' output contract), which is what lets
     the resident stack live at 2 bytes/weight instead of int16 + a
     whole-matrix int32 cast.  Returns ``(2, n_in, n_out)`` int8 with
-    plane 0 = hi, plane 1 = lo; the kernel widens per 128×128 tile, per
-    use (:func:`_widen_tile`).
+    plane 0 = hi, plane 1 = lo; the kernels contract each plane on the
+    MXU (:func:`spike_dot`).
     """
     w32 = w_q.astype(jnp.int32)
     hi = jnp.right_shift(w32, 1)
@@ -125,9 +124,27 @@ def pack_weights(w_q: jax.Array) -> jax.Array:
     return jnp.stack([hi.astype(jnp.int8), lo.astype(jnp.int8)])
 
 
-def _widen_tile(packed: jax.Array) -> jax.Array:
-    """(2, k, n) int8 planes → (k, n) int32 weight tile (w = 2*hi + lo)."""
-    return (packed[0].astype(jnp.int32) * 2 + packed[1].astype(jnp.int32))
+def spike_dot(x: jax.Array, packed: jax.Array) -> jax.Array:
+    """Exact Σ W·S of 0/1 spikes ``x`` (b, k) against int8 planes (2, k, n).
+
+    Two int8 MXU passes with int32 accumulation, ``2·(x·hi) + x·lo`` —
+    the form the TPU MXU takes natively (it has no int32×int32 matmul),
+    exact for any contraction length because the operands are the packed
+    codes themselves and integer accumulation does not round.
+    """
+    xi = x.astype(jnp.int8)
+
+    def dot(w):
+        return jax.lax.dot_general(xi, w, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.int32)
+
+    return 2 * dot(packed[0]) + dot(packed[1])
+
+
+def _any(a: jax.Array) -> jax.Array:
+    """Scalar "any nonzero" of a 0/1 tile as an int32 reduction (Mosaic
+    cannot relayout an i1 vector into the scalar ``jnp.any`` needs)."""
+    return jnp.max(a.astype(jnp.int32)) > 0
 
 
 def layer_shard_ways(layer_sizes, model_shards: int):
@@ -158,9 +175,10 @@ def stack_vmem_bytes(layer_sizes, block_b: int = DEFAULT_BLOCK_B,
 
     Counts the padded int8-packed weight planes (2 bytes/weight resident;
     replaced by the 2-slot DMA slab scratch when ``streamed``), pixels +
-    PRNG lanes, per-layer membrane/enable state, the final-layer trace
-    block, the single per-use widened int32 weight tile and a working-set
-    allowance for the per-step spike/current temporaries.  Kept in
+    PRNG lanes and the pixels widened to int32 for the comparator,
+    per-layer membrane/enable state, the final-layer trace block, the
+    per-step lane-dense side-channel rows and a working-set allowance for
+    the per-step spike/current temporaries.  Kept in
     lockstep with the launcher: same padding, same ``block_b_for`` block,
     same scratch shapes as :func:`fused_snn_stack_pallas` allocates.
 
@@ -178,18 +196,16 @@ def stack_vmem_bytes(layer_sizes, block_b: int = DEFAULT_BLOCK_B,
     sizes = [_pad128(n) for n in sizes_raw]
     shard_outs = [_pad128(n // w) for n, w in zip(sizes_raw[1:], ways)]
     bB = block_b
-    L = len(sizes) - 1
     max_out = max(shard_outs)
-    total = sizes[0] * bB * (1 + 4)                      # pixels + PRNG
+    total = sizes[0] * bB * (1 + 4 + 4)          # pixels + PRNG + i32 pixels
     for n_in, n_out in zip(sizes[:-1], shard_outs):
         if not streamed:
             total += n_in * n_out * 2                    # packed int8 hi+lo
         total += bB * n_out * (4 + 4 + 1 + 4)            # v + v_peak + en + current
     if streamed:
         total += 2 * 2 * LANE * max_out                  # 2-slot DMA slabs
-    total += LANE * max_out * 4                          # widened i32 tile
     total += num_steps * bB * shard_outs[-1] * 4         # v_trace block
-    total += num_steps * L * (2 * bB + 1) * 4            # telemetry blocks
+    total += num_steps * bB * LANE * 4                   # side-channel rows
     total += bB * max(sizes[0], max_out) * 8             # spike temporaries
     return total
 
@@ -221,7 +237,7 @@ def _tiled_contraction(x, en, read_tile, n_out_pad: int, sparse_skip: bool,
     only ever skip tiles whose contribution is exactly zero (no spikes →
     zero rows; fully pruned → the result is zeroed by the enable mask),
     so dense and sparse execution are bit-identical — the skip saves the
-    widen + MXU pass, not correctness (integer addition is exact, so the
+    MXU passes, not correctness (integer addition is exact, so the
     K-tiled accumulation order cannot change results either).
 
     Returns ``(result, skipped)`` where ``skipped`` is the scalar i32
@@ -242,13 +258,10 @@ def _tiled_contraction(x, en, read_tile, n_out_pad: int, sparse_skip: bool,
             en_t = en[:, nt * LANE:(nt + 1) * LANE]
 
             def tile(x_t=x_t, kt=kt, nt=nt):
-                w32 = _widen_tile(read_tile(kt, nt))
-                return jax.lax.dot_general(
-                    x_t.astype(jnp.int32), w32, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32)
+                return spike_dot(x_t, read_tile(kt, nt))
 
             if sparse_skip:
-                live = jnp.logical_and(jnp.any(x_t), jnp.any(en_t))
+                live = jnp.logical_and(_any(x_t), _any(en_t))
                 skipped = skipped + (1 - live.astype(jnp.int32))
                 accs[nt] = accs[nt] + jax.lax.cond(live, tile,
                                                    lambda: zeros)
@@ -269,7 +282,7 @@ def _partial_kernel(x_ref, en_ref, w_ref, out_ref, skip_ref, *,
     cur, skipped = _tiled_contraction(x, en, read_tile, w_ref.shape[2],
                                       sparse_skip)
     out_ref[...] = cur
-    skip_ref[0, 0] = skipped
+    skip_ref[pl.program_id(0)] = skipped         # whole (n_blocks,) SMEM
 
 
 def partial_contraction_pallas(x_u8: jax.Array, en_u8: jax.Array,
@@ -314,14 +327,14 @@ def partial_contraction_pallas(x_u8: jax.Array, en_u8: jax.Array,
         ],
         out_specs=[
             pl.BlockSpec((bB, n_out_pad), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, n_out_pad), jnp.int32),
-            jax.ShapeDtypeStruct((n_blocks, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n_blocks,), jnp.int32),
         ],
         interpret=interpret)(x_u8, en_u8, w_packed)
-    return out, skipped[:, 0]
+    return out, skipped
 
 
 def _stack_kernel(*refs, num_layers: int, chunk_steps: int, window_steps: int,
@@ -339,23 +352,34 @@ def _stack_kernel(*refs, num_layers: int, chunk_steps: int, window_steps: int,
     cnt_ref, first_ref, steps_ref = next(it), next(it), next(it)
     if gated:
         act_ref, gprev_ref, gstreak_ref = next(it), next(it), next(it)
-    cnt_out, vtr_out, first_out, adds_out, st_out = (
+    cnt_out, vtr_out, first_out, side_out, st_out = (
         next(it), next(it), next(it), next(it), next(it))
     v_outs = [next(it) for _ in range(L)]
     en_outs = [next(it) for _ in range(L)]
     vp_outs = [next(it) for _ in range(L)]
-    tspk_out, ten_out, ttile_out = next(it), next(it), next(it)
+    ttile_out = next(it)
     steps_out = next(it)
     if gated:
         act_out, gprev_out, gstreak_out = next(it), next(it), next(it)
 
-    px = px_ref[...]                                   # (bB, n_in) uint8
+    blk = pl.program_id(0)           # batch block: row of the tile counters
+    # widened once per chunk: the chip's compiler has no 8-bit compare
+    px = px_ref[...].astype(jnp.int32)                 # (bB, n_in)
     n_pads = [w.shape[2] for w in w_refs]              # padded layer widths
     n_out = cnt_ref.shape[1]
     # streamed mode: (layer, K-slab) pairs in execution order — the DMA
     # pipeline walks them with a 2-slot double buffer each step.
     slabs = [(l, kt) for l in range(L)
              for kt in range(w_refs[l].shape[1] // LANE)]
+
+    side_col = jax.lax.broadcasted_iota(jnp.int32, (px.shape[0], LANE), 1)
+
+    def side_row(cols):
+        """Pack (bB, 1) counters into one lane-dense (bB, LANE) row."""
+        row = jnp.zeros(side_col.shape, jnp.int32)
+        for c, v in enumerate(cols):
+            row = jnp.where(side_col == c, v, row)
+        return row
 
     def run(w_scr=None, sems=None):
         def slab_dma(i: int):
@@ -366,17 +390,19 @@ def _stack_kernel(*refs, num_layers: int, chunk_steps: int, window_steps: int,
                 w_scr.at[slot, :, :, pl.ds(0, n_pads[l])],
                 sems.at[slot])
 
+        # enables and the gate's active flag ride the loop as int32 0/1:
+        # Mosaic cannot carry i1 vectors through the step loop
         carry0 = (
             st_ref[...],
             tuple(v_refs[l][...] for l in range(L)),
-            tuple(en_refs[l][...] != 0 for l in range(L)),
+            tuple(en_refs[l][...].astype(jnp.int32) for l in range(L)),
             tuple(vp_refs[l][...] for l in range(L)),
             cnt_ref[...],
             first_ref[...],
             steps_ref[...],                            # (bB, 1) i32
         )
         if gated:
-            carry0 = carry0 + (act_ref[...] != 0, gprev_ref[...],
+            carry0 = carry0 + (act_ref[...], gprev_ref[...],
                                gstreak_ref[...])
 
         def body(t, carry):
@@ -390,7 +416,7 @@ def _stack_kernel(*refs, num_layers: int, chunk_steps: int, window_steps: int,
             s_new = s ^ (s << 13)
             s_new = s_new ^ (s_new >> 17)
             s_new = s_new ^ (s_new << 5)
-            r = (s_new >> 24).astype(jnp.uint8)
+            r = (s_new >> 24).astype(jnp.int32)        # top byte, 0..255
             x = px > r                                 # (bB, n_in) on-chip
             if streamed:
                 slab_dma(0).start()                    # warm the pipeline
@@ -398,10 +424,10 @@ def _stack_kernel(*refs, num_layers: int, chunk_steps: int, window_steps: int,
             # --- static layer loop: spikes stay in VMEM between layers ---
             adds_t = jnp.zeros(steps.shape, jnp.int32)  # (bB, 1)
             new_vs, new_ens, new_vps = [], [], []
-            spk_t, en_t_tel, skip_t = [], [], []       # telemetry rows
+            side = []                                  # (bB, 1) columns
             base = 0                                   # streamed slab cursor
             for l in range(L):
-                en = ens[l]
+                en = ens[l] != 0
                 if streamed:
                     # Double-buffered HBM→VMEM slab pipeline: each K
                     # iteration kicks off the NEXT slab's copy (into the
@@ -444,13 +470,15 @@ def _stack_kernel(*refs, num_layers: int, chunk_steps: int, window_steps: int,
                 n_spk = jnp.sum(x.astype(jnp.int32), axis=-1, keepdims=True)
                 n_en = jnp.sum(en.astype(jnp.int32), axis=-1, keepdims=True)
                 adds_t = adds_t + n_spk * n_en
-                spk_t.append(n_spk[:, 0])
-                en_t_tel.append(n_en[:, 0])
-                skip_t.append(skipped)
+                side += [n_spk, n_en]
+                # per-block skipped tile pairs, unmasked in gated mode: it
+                # records what the block's contraction actually executed,
+                # and frozen lanes still sit in the block
+                ttile_out[t, l, blk] = skipped
                 if active_pruning:
                     en = jnp.logical_and(en, jnp.logical_not(fired))
                 new_vs.append(v_new)
-                new_ens.append(en)
+                new_ens.append(en.astype(jnp.int32))
                 new_vps.append(jnp.maximum(vps[l], v_new))
                 x = fired                              # next layer's input
 
@@ -459,14 +487,6 @@ def _stack_kernel(*refs, num_layers: int, chunk_steps: int, window_steps: int,
             first_new = jnp.where(
                 jnp.logical_and(x, first == window_steps), steps, first)
             v_last = new_vs[-1]
-
-            # telemetry rows for this step: (L, bB) spike/enable counts and
-            # (L,) per-block skipped tiles.  The tile row stays unmasked in
-            # gated mode — it records what the block's contraction actually
-            # executed/skipped, and frozen lanes still sit in the block.
-            tel_spk = jnp.stack(spk_t)
-            tel_en = jnp.stack(en_t_tel)
-            ttile_out[t, :, 0] = jnp.stack(skip_t)
 
             if gated:
                 # stability gate, mirroring serve.snn_engine.stream_chunk's
@@ -489,37 +509,32 @@ def _stack_kernel(*refs, num_layers: int, chunk_steps: int, window_steps: int,
                 gprev_new = jnp.where(has_spike, pred, -1)
                 gstreak_new = jnp.where(has_spike, streak_raw, 0)
                 done = jnp.logical_and(done, has_spike)
-                steps_new = steps + act.astype(jnp.int32)
-                still = jnp.logical_and(act, jnp.logical_not(done))
+                live = act != 0
+                steps_new = steps + act
+                still = jnp.logical_and(live, jnp.logical_not(done))
                 still = jnp.logical_and(still, steps_new < window_steps)
 
                 def keep(new, old):
-                    return jnp.where(act, new, old)
+                    return jnp.where(live, new, old)
 
                 s_new = keep(s_new, s)
                 new_vs = [keep(nv, ov) for nv, ov in zip(new_vs, vs)]
-                new_ens = [jnp.where(act, ne, oe)
-                           for ne, oe in zip(new_ens, ens)]
+                new_ens = [keep(ne, oe) for ne, oe in zip(new_ens, ens)]
                 new_vps = [keep(nv, ov) for nv, ov in zip(new_vps, vps)]
                 cnt_new = keep(cnt_new, cnt)
                 first_new = keep(first_new, first)
                 gprev_new = keep(gprev_new, gprev)
                 gstreak_new = keep(gstreak_new, gstreak)
                 vtr_out[t, :, :] = new_vs[-1]
-                adds_out[t, :] = jnp.where(act, adds_t, 0)[:, 0]
-                # frozen lanes execute nothing, so their telemetry rows are
-                # zero — matching the frozen executed-add channel above
-                lane_act = act[:, 0][None, :]          # (1, bB)
-                tspk_out[t, :, :] = jnp.where(lane_act, tel_spk, 0)
-                ten_out[t, :, :] = jnp.where(lane_act, tel_en, 0)
+                # frozen lanes execute nothing, so their executed-add and
+                # telemetry rows are zero
+                side_out[t, :, :] = keep(side_row([adds_t] + side), 0)
                 return (s_new, tuple(new_vs), tuple(new_ens),
                         tuple(new_vps), cnt_new, first_new, steps_new,
-                        still, gprev_new, gstreak_new)
+                        still.astype(jnp.int32), gprev_new, gstreak_new)
 
             vtr_out[t, :, :] = v_last
-            adds_out[t, :] = adds_t[:, 0]
-            tspk_out[t, :, :] = tel_spk
-            ten_out[t, :, :] = tel_en
+            side_out[t, :, :] = side_row([adds_t] + side)
             return (s_new, tuple(new_vs), tuple(new_ens), tuple(new_vps),
                     cnt_new, first_new, steps + 1)
 
@@ -527,7 +542,7 @@ def _stack_kernel(*refs, num_layers: int, chunk_steps: int, window_steps: int,
         if gated:
             (s_f, vs_f, ens_f, vps_f, cnt_f, first_f, steps_f, act_f, gp_f,
              gs_f) = carry_f
-            act_out[...] = act_f.astype(jnp.int32)
+            act_out[...] = act_f
             gprev_out[...] = gp_f
             gstreak_out[...] = gs_f
         else:
@@ -538,7 +553,7 @@ def _stack_kernel(*refs, num_layers: int, chunk_steps: int, window_steps: int,
         steps_out[...] = steps_f
         for l in range(num_layers):
             v_outs[l][...] = vs_f[l]
-            en_outs[l][...] = ens_f[l].astype(jnp.uint8)
+            en_outs[l][...] = ens_f[l].astype(en_outs[l].dtype)
             vp_outs[l][...] = vps_f[l]
 
     if streamed:
@@ -615,7 +630,7 @@ def fused_snn_stack_pallas(pixels_u8: jax.Array, state_u32: jax.Array,
 
     # Streamed weights never enter VMEM whole: the kernel DMAs 128-row
     # slabs out of HBM/ANY on demand.
-    w_spec = ((lambda w: pl.BlockSpec(memory_space=pltpu.ANY)) if streamed
+    w_spec = ((lambda w: pl.BlockSpec(memory_space=pl.ANY)) if streamed
               else (lambda w: whole(w.shape)))
 
     in_specs = [row(pixels_u8.shape), row(state_u32.shape)]
@@ -632,18 +647,24 @@ def fused_snn_stack_pallas(pixels_u8: jax.Array, state_u32: jax.Array,
         in_specs += [row(g.shape) for g in gate_init]
         inputs += list(gate_init)
 
+    # per-step side channel, one lane-dense (bB, LANE) row per step:
+    # column 0 = executed adds, then (input spikes, enabled outputs) per
+    # layer — the energy + telemetry counters of each lane
+    if 1 + 2 * L > LANE:
+        raise ValueError(f"{L} layers overflow the {LANE}-column side row")
+    steps_block = lambda i: (0, i, 0)                       # noqa: E731
     out_specs = [
         row((B, n_out)),
-        pl.BlockSpec((chunk_steps, bB, n_out), lambda i: (0, i, 0)),
+        pl.BlockSpec((chunk_steps, bB, n_out), steps_block),
         row((B, n_out)),
-        pl.BlockSpec((chunk_steps, bB), lambda i: (0, i)),
+        pl.BlockSpec((chunk_steps, bB, LANE), steps_block),
         row((B, n_in)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((B, n_out), jnp.int32),
         jax.ShapeDtypeStruct((chunk_steps, B, n_out), jnp.int32),
         jax.ShapeDtypeStruct((B, n_out), jnp.int32),
-        jax.ShapeDtypeStruct((chunk_steps, B), jnp.int32),
+        jax.ShapeDtypeStruct((chunk_steps, B, LANE), jnp.int32),
         jax.ShapeDtypeStruct((B, n_in), jnp.uint32),
     ]
     for l in range(L):
@@ -655,19 +676,10 @@ def fused_snn_stack_pallas(pixels_u8: jax.Array, state_u32: jax.Array,
     for l in range(L):                     # per-layer peak membranes
         out_specs.append(row((B, sizes[l + 1])))
         out_shape.append(jax.ShapeDtypeStruct((B, sizes[l + 1]), jnp.int32))
-    # telemetry side channel: per-step/layer spike + enable counts per
-    # lane, skipped tile pairs per batch block
-    n_blocks = grid[0]
-    out_specs += [
-        pl.BlockSpec((chunk_steps, L, bB), lambda i: (0, 0, i)),
-        pl.BlockSpec((chunk_steps, L, bB), lambda i: (0, 0, i)),
-        pl.BlockSpec((chunk_steps, L, 1), lambda i: (0, 0, i)),
-    ]
-    out_shape += [
-        jax.ShapeDtypeStruct((chunk_steps, L, B), jnp.int32),
-        jax.ShapeDtypeStruct((chunk_steps, L, B), jnp.int32),
-        jax.ShapeDtypeStruct((chunk_steps, L, n_blocks), jnp.int32),
-    ]
+    # skipped tile pairs per step, layer and batch block: scalars, in SMEM
+    out_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+    out_shape.append(
+        jax.ShapeDtypeStruct((chunk_steps, L, grid[0]), jnp.int32))
     out_specs.append(row((B, 1)))
     out_shape.append(jax.ShapeDtypeStruct((B, 1), jnp.int32))
     if gated:
@@ -679,14 +691,17 @@ def fused_snn_stack_pallas(pixels_u8: jax.Array, state_u32: jax.Array,
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, interpret=interpret)(*inputs)
 
-    cnt, vtr, first, adds, st_out = outs[:5]
+    cnt, vtr, first, side, st_out = outs[:5]
     v_fin = tuple(outs[5:5 + L])
     en_fin = tuple(outs[5 + L:5 + 2 * L])
     vp_fin = tuple(outs[5 + 2 * L:5 + 3 * L])
-    tel = tuple(outs[5 + 3 * L:8 + 3 * L])
-    steps_out = outs[8 + 3 * L]
+    # (chunk, B, 1 + 2L) side row → adds (chunk, B), spk/en (chunk, L, B)
+    adds = side[:, :, 0]
+    pairs = jnp.transpose(side[:, :, 1:1 + 2 * L], (0, 2, 1))
+    tel = (pairs[:, 0::2], pairs[:, 1::2], outs[5 + 3 * L])
+    steps_out = outs[6 + 3 * L]
     if gated:
         return (cnt, vtr, first, adds, st_out, v_fin, en_fin, vp_fin, tel,
-                steps_out, tuple(outs[9 + 3 * L:12 + 3 * L]))
+                steps_out, tuple(outs[7 + 3 * L:10 + 3 * L]))
     return (cnt, vtr, first, adds, st_out, v_fin, en_fin, vp_fin, tel,
             steps_out)

@@ -4,13 +4,14 @@ RTL datapath (paper Fig. 1): Weight-Reg → Adder → Accumulator → shift-base
 Decay → Comparator → reset, sequenced by a local FSM over timesteps.
 
 TPU mapping (the hardware-adaptation core of this repro):
-  * The int16 weight matrix tile stays **resident in VMEM for all T steps**
+  * The weight tile stays **resident in VMEM for all T steps**, as the
+    two int8 planes of ``fused_snn.pack_weights``
     — the analogue of the RTL's on-chip BRAM weight bank ("no external
     memory access", paper §V-B).  Spikes stream in; membrane state lives in
     a VMEM scratch accumulator, exactly like the Accumulator register.
-  * The synaptic sum Σ W·S with S ∈ {0,1} is a dot against an int8 spike
-    vector — the MXU executes it as wide integer MACs, but since one operand
-    is binary the effective arithmetic is the paper's "adds only" datapath;
+  * The synaptic sum Σ W·S with S ∈ {0,1} is two int8 MXU passes with
+    int32 accumulation (``fused_snn.spike_dot``) — since one operand is
+    binary the effective arithmetic is the paper's "adds only" datapath;
     the energy model (core.energy) accounts it that way.
   * Leak = arithmetic right shift, fire = compare, reset = select: all VPU
     byte-lane ops, fused into the same pipeline stage as the MXU drain.
@@ -18,7 +19,7 @@ TPU mapping (the hardware-adaptation core of this repro):
     current and the state write-back — the clock-gate bit of §III-D.
 
 Grid: (B/bB, N_out/bN); contraction dim N_in is kept whole in VMEM (the
-SNN-scale layers the paper targets fit comfortably: 784×128 int16 = 200 KB).
+SNN-scale layers the paper targets fit comfortably: 896×128×2 int8 = 224 KB).
 """
 
 from __future__ import annotations
@@ -29,6 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .fused_snn import spike_dot
+
 __all__ = ["lif_forward_pallas"]
 
 DEFAULT_BLOCK = (8, 128)  # (batch tile, out-neuron tile)
@@ -37,20 +40,19 @@ DEFAULT_BLOCK = (8, 128)  # (batch tile, out-neuron tile)
 def _lif_kernel(spikes_ref, w_ref, spk_out_ref, vtr_out_ref, vfin_out_ref,
                 *, num_steps: int, decay_shift: int, v_threshold: int,
                 v_rest: int, v_min: int, v_max: int, active_pruning: bool):
-    w = w_ref[...].astype(jnp.int32)              # (N_in, bN) resident all T
+    w = w_ref[...]                          # (2, N_in, bN) int8, resident all T
     bB = spk_out_ref.shape[1]
     bN = spk_out_ref.shape[2]
 
     v0 = jnp.full((bB, bN), v_rest, jnp.int32)
-    en0 = jnp.ones((bB, bN), jnp.bool_)
+    en0 = jnp.ones((bB, bN), jnp.int32)     # int32 0/1: no i1 loop carry on TPU
 
     def body(t, carry):
-        v, en = carry
-        s_t = spikes_ref[t, :, :].astype(jnp.int32)      # (bB, N_in)
-        # Σ W·S — binary operand ⇒ adds-only datapath (MXU int path on TPU).
-        cur = jax.lax.dot_general(
-            s_t, w, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)
+        v, en_i = carry
+        en = en_i != 0
+        s_t = spikes_ref[t, :, :] != 0                   # (bB, N_in)
+        # Σ W·S — binary operand ⇒ adds-only datapath (MXU int8 passes).
+        cur = spike_dot(s_t, w)
         cur = jnp.where(en, cur, 0)                      # pruning clock-gate
         v_int = jnp.clip(v + cur, v_min, v_max)          # saturating Adder
         v_leak = v_int - (v_int >> decay_shift)          # Decay-Reg shift
@@ -61,23 +63,23 @@ def _lif_kernel(spikes_ref, w_ref, spk_out_ref, vtr_out_ref, vfin_out_ref,
         vtr_out_ref[t, :, :] = v_new
         if active_pruning:
             en = jnp.logical_and(en, jnp.logical_not(fired))
-        return (v_new, en)
+        return (v_new, en.astype(jnp.int32))
 
     v_f, _ = jax.lax.fori_loop(0, num_steps, body, (v0, en0))
     vfin_out_ref[...] = v_f
 
 
-def lif_forward_pallas(spikes_t: jax.Array, w_q: jax.Array, *,
+def lif_forward_pallas(spikes_t: jax.Array, w_packed: jax.Array, *,
                        decay_shift: int, v_threshold: int, v_rest: int = 0,
                        v_min: int = -(1 << 20), v_max: int = (1 << 20) - 1,
                        active_pruning: bool = False,
                        block=DEFAULT_BLOCK, interpret: bool = False):
-    """spikes_t: (T, B, N_in) u8; w_q: (N_in, N_out) int16/int8.
+    """spikes_t: (T, B, N_in) u8; w_packed: (2, N_in, N_out) int8 planes.
 
     Returns (out_spikes u8 (T,B,N_out), v_trace i32 (T,B,N_out), v_final i32 (B,N_out)).
     """
     T, B, n_in = spikes_t.shape
-    n_out = w_q.shape[1]
+    n_out = w_packed.shape[2]
     bB, bN = block
     grid = (pl.cdiv(B, bB), pl.cdiv(n_out, bN))
 
@@ -92,7 +94,7 @@ def lif_forward_pallas(spikes_t: jax.Array, w_q: jax.Array, *,
         in_specs=[
             # Full T and full N_in per batch tile; only batch dim is split.
             pl.BlockSpec((T, bB, n_in), lambda i, j: (0, i, 0)),
-            pl.BlockSpec((n_in, bN), lambda i, j: (0, j)),
+            pl.BlockSpec((2, n_in, bN), lambda i, j: (0, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((T, bB, bN), lambda i, j: (0, i, j)),
@@ -105,5 +107,5 @@ def lif_forward_pallas(spikes_t: jax.Array, w_q: jax.Array, *,
             jax.ShapeDtypeStruct((B, n_out), jnp.int32),
         ],
         interpret=interpret,
-    )(spikes_t, w_q)
+    )(spikes_t, w_packed)
     return spk, vtr, vfin

@@ -44,12 +44,12 @@ def _use_interpret() -> bool:
 def validate_weight_codes(weights) -> None:
     """Raise if concrete weights fall outside the int8-packable range.
 
-    The fused kernels store weights as two int8 planes (``hi = w >> 1``,
-    ``lo = w & 1``), exact only for the paper's signed 9-bit codes
-    [-256, 255] (``core.snn.quantize_params``' output contract) — a wider
-    code would wrap the hi plane SILENTLY, where the pre-packing int16
-    kernel was exact.  Checked wherever the weights are concrete (engine
-    construction, un-jitted ``snn_apply_int``/``snn_window_chunk`` calls);
+    Every Pallas kernel stores weights as two int8 planes (``hi = w >> 1``,
+    ``lo = w & 1``) — the MXU's native operand type — exact only for the
+    paper's signed 9-bit codes [-256, 255] (``core.snn.quantize_params``'
+    output contract); a wider code would wrap the hi plane SILENTLY.
+    Checked wherever the weights are concrete (engine construction,
+    un-jitted ``snn_apply_int``/``snn_window_chunk`` calls);
     under a caller's jit the values are tracers and the contract is
     trusted.
     """
@@ -62,7 +62,7 @@ def validate_weight_codes(weights) -> None:
                 f"layer {i} weight codes span [{lo}, {hi}] — outside the "
                 f"signed 9-bit range [-256, 255] the fused kernels' int8 "
                 f"packing represents exactly (quantize_params' contract); "
-                f"use the staged or reference backend for wider codes")
+                f"use the reference backend for wider codes")
 
 
 # Trace-time env resolution of the tile-skip flag — the canonical rule
@@ -102,13 +102,17 @@ def lif_forward_op(spikes_t: jax.Array, w_q: jax.Array, *, decay_shift: int,
                    v_min: int = -(1 << 20), v_max: int = (1 << 20) - 1,
                    active_pruning: bool = False,
                    interpret: bool | None = None):
-    """Fused T-step LIF layer via the Pallas kernel. See lif_step.py."""
+    """Fused T-step LIF layer via the Pallas kernel. See lif_step.py.
+
+    ``w_q`` holds signed 9-bit codes (the int8 packing contract of
+    :func:`validate_weight_codes`)."""
     interpret = _use_interpret() if interpret is None else interpret
     T, B, n_in = spikes_t.shape
     n_out = w_q.shape[1]
     bB, bN = lif_step.DEFAULT_BLOCK
-    s = _pad_to(spikes_t, 1, bB)
-    w = _pad_to(w_q, 1, bN)
+    lane = fused_snn.LANE
+    s = _pad_to(_pad_to(spikes_t, 1, bB), 2, lane)
+    w = fused_snn.pack_weights(_pad_to(_pad_to(w_q, 0, lane), 1, bN))
     spk, vtr, vfin = lif_step.lif_forward_pallas(
         s, w, decay_shift=decay_shift, v_threshold=v_threshold,
         v_rest=v_rest, v_min=v_min, v_max=v_max,
@@ -362,7 +366,9 @@ def spike_matmul_op(spikes: jax.Array, w_q: jax.Array, *,
     chunk never recompiles.  ``with_telemetry=True`` additionally returns
     a ``core.telemetry.MatmulTelemetry`` (observed density + branch
     taken), the per-call twin of the fused kernel's chunk side channel.
+    ``w_q`` holds signed 9-bit codes (checked here when concrete).
     """
+    validate_weight_codes((w_q,))
     return _spike_matmul_impl(
         spikes, w_q,
         jnp.float32(resolve_density_threshold(density_threshold)),
@@ -378,7 +384,7 @@ def _spike_matmul_impl(spikes: jax.Array, w_q: jax.Array,
     n_out = w_q.shape[1]
     bB, bN, bK = spike_matmul.DEFAULT_BLOCK
     s = _pad_to(_pad_to(spikes, 0, bB), 1, bK)
-    w = _pad_to(_pad_to(w_q, 0, bK), 1, bN)
+    w = fused_snn.pack_weights(_pad_to(_pad_to(w_q, 0, bK), 1, bN))
     density = jnp.mean((spikes != 0).astype(jnp.float32))
     if mode == "auto":
         used_masked = density < threshold

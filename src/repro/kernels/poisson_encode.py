@@ -30,7 +30,7 @@ DEFAULT_BLOCK = (8, 128)
 def _encode_kernel(pixels_ref, state_ref, spikes_ref, state_out_ref, *,
                    num_steps: int):
     """One (bB, bN) tile: run T xorshift steps, emit spikes per step."""
-    px = pixels_ref[...]              # (bB, bN) uint8
+    px = pixels_ref[...].astype(jnp.int32)   # (bB, bN): no 8-bit compare on TPU
     s0 = state_ref[...]               # (bB, bN) uint32
 
     def body(t, s):
@@ -38,7 +38,7 @@ def _encode_kernel(pixels_ref, state_ref, spikes_ref, state_out_ref, *,
         s = s ^ (s << 13)
         s = s ^ (s >> 17)
         s = s ^ (s << 5)
-        r = (s >> 24).astype(jnp.uint8)          # comparator draws top byte
+        r = (s >> 24).astype(jnp.int32)          # comparator draws top byte
         spikes_ref[t, :, :] = (px > r).astype(jnp.uint8)
         return s
 

@@ -15,8 +15,7 @@ Target hardware: TPU v5e pods, 256 chips/pod, 16×16 ICI torus.
 from __future__ import annotations
 
 import jax
-
-from ..distributed.sharding import make_device_mesh
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh", "mesh_axis_sizes"]
 
@@ -24,13 +23,13 @@ __all__ = ["make_production_mesh", "make_local_mesh", "mesh_axis_sizes"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_device_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh():
     """Whatever devices exist, as a 1×N ("data","model") mesh (tests/CPU)."""
     n = len(jax.devices())
-    return make_device_mesh((1, n), ("data", "model"))
+    return jax.make_mesh((1, n), ("data", "model"), (AxisType.Auto,) * 2)
 
 
 def mesh_axis_sizes(mesh) -> dict:

@@ -63,6 +63,13 @@ Workers are spawned as ``python -c '... _worker_main(sys.argv[1:])'
 <read_fd> <write_fd>`` with both pipe ends inherited via ``pass_fds`` —
 dedicated fds, so stray ``print``\\ s to stdout can never corrupt a
 frame.
+
+A chip belongs to one process.  A coordinator that has already run JAX on
+an accelerator (quantizing the weights is enough) holds that chip, and a
+worker that inherits its environment fails in its init (on a TPU host,
+with libtpu's lock-file error).  So such a coordinator refuses to start;
+with ``JAX_PLATFORMS=cpu`` set for the coordinator's process, it and its
+workers run on the host CPU.
 """
 
 from __future__ import annotations
@@ -75,6 +82,7 @@ import time
 from dataclasses import dataclass, field
 from glob import glob
 
+import jax
 import numpy as np
 
 from ..core.snn import SNNConfig
@@ -134,6 +142,19 @@ def _record_fields(cls) -> set:
     return {f.name for f in dataclasses.fields(cls)}
 
 
+def _held_accelerator() -> str | None:
+    """Accelerator platform this process has already claimed, if any.
+
+    Only asks when JAX's backends are initialised, so the check itself
+    never claims a chip.
+    """
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return None
+    platform = jax.default_backend()
+    return None if platform == "cpu" else platform
+
+
 class ClusterCoordinator:
     """Tier coordinator over N per-host engine processes (module doc).
 
@@ -159,6 +180,14 @@ class ClusterCoordinator:
                 "ClusterCoordinator requires ledger_dir: the write-ahead "
                 "accounting ledger is the crash-recovery contract, not an "
                 "option")
+        held = _held_accelerator()
+        if held is not None:
+            raise RuntimeError(
+                f"this process already holds the {held} (it has run JAX "
+                f"there), so a worker process cannot reach that chip and "
+                f"would fail in init; run the coordinator with "
+                f"JAX_PLATFORMS=cpu (its workers inherit it), or build it "
+                f"before this process touches JAX on the accelerator")
         if isinstance(fault_plan, str):
             fault_plan = FaultPlan.from_spec(fault_plan)
         self.fault_plan = fault_plan

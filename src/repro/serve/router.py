@@ -156,7 +156,8 @@ class SNNServingTier:
         if sharded:
             import jax
 
-            from ..distributed.sharding import make_device_mesh
+            from jax.sharding import AxisType
+
             from .snn_engine import ShardedSNNStreamEngine
             devs = jax.devices()
             per = (devices_per_engine if devices_per_engine is not None
@@ -166,8 +167,9 @@ class SNNServingTier:
                     f"cannot carve {num_engines} × {per}-device hosts out "
                     f"of {len(devs)} visible devices")
             for i in range(num_engines):
-                mesh = make_device_mesh(
-                    (per,), ("data",), devices=devs[i * per:(i + 1) * per])
+                mesh = jax.make_mesh(
+                    (per,), ("data",), (AxisType.Auto,),
+                    devices=devs[i * per:(i + 1) * per])
                 self.engines.append(ShardedSNNStreamEngine(
                     params_q, cfg, mesh=mesh,
                     batch_size=lanes_per_engine, chunk_steps=chunk_steps,

@@ -63,7 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import lif as lif_mod
 from ..core import prng as prng_mod
@@ -71,7 +71,6 @@ from ..core.snn import (SNNConfig, readout_pred, snn_int_stack_step,
                         snn_int_stack_step_sharded)
 from ..core.telemetry import (ChunkTelemetry, EngineLoad,
                               telemetry_partition_specs)
-from ..distributed.sharding import make_device_mesh, shard_map_compat
 from .early_exit import StabilityGateState, stability_specs, stability_step
 from .faults import (DeviceLostFault, DispatchFault, EngineFailure,
                      EngineHealthState, FaultInjector, FaultToleranceConfig,
@@ -396,8 +395,10 @@ def make_sharded_stream_chunk(mesh: Mesh, axis_name: str, n_layers: int, *,
         patience=patience, readout=readout, backend=backend,
         sparse_skip=sparse_skip, interpret=interpret,
         model_axis=model_axis, model_ways=model_ways, block_b=block_b)
-    mapped = shard_map_compat(body, mesh, in_specs=(specs, w_specs),
-                              out_specs=(specs, tel_specs))
+    # no replication check: the mapped body runs Pallas calls, which
+    # have no replication rule
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=(specs, w_specs),
+                           out_specs=(specs, tel_specs), check_vma=False)
     return jax.jit(mapped)
 
 
@@ -1204,7 +1205,8 @@ class ShardedSNNStreamEngine(SNNStreamEngine):
         from ..kernels.fused_snn import layer_shard_ways
         from ..tune.cache import CacheDecision, decide_dispatch
         if mesh is None:
-            mesh = make_device_mesh((len(jax.devices()),), (axis_name,))
+            mesh = jax.make_mesh((len(jax.devices()),), (axis_name,),
+                                 (AxisType.Auto,))
         if axis_name not in mesh.axis_names:
             raise ValueError(f"mesh {mesh.axis_names} has no "
                              f"{axis_name!r} axis")

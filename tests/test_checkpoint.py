@@ -81,13 +81,12 @@ def test_manager_async_save_restore_and_gc(tmp_path):
 
 def test_restore_with_shardings_elastic(tmp_path):
     """Restore onto an explicit sharding (single-device 'new mesh')."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-    from repro.distributed.sharding import make_device_mesh
     tree = make_tree(jax.random.PRNGKey(0))
     d = str(tmp_path / "ck")
     save_pytree(tree, d)
-    mesh = make_device_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), (AxisType.Auto,))
     sh = jax.tree.map(lambda x: NamedSharding(mesh, P()), tree)
     got = restore_pytree(tree, d, shardings=sh)
     assert_trees_equal(tree, got)

@@ -551,3 +551,34 @@ def test_cluster_config_validation_and_factory(tmp_path):
         co.submit(IMGS[0], request_id=0)
         res = co.run()
         assert as_tuple(res[0]) == baseline("reference")[0]
+
+
+def test_coordinator_holding_accelerator_fails_fast(tmp_path, monkeypatch):
+    """A coordinator that already holds the chip would leave its workers
+    failing or blocked in init for the whole init timeout: it must refuse
+    at once, before any worker process is spawned."""
+    import time
+
+    from repro.serve import cluster
+
+    def no_spawn(*a, **kw):
+        raise AssertionError("a worker was spawned")
+
+    monkeypatch.setattr(cluster, "_held_accelerator", lambda: "tpu")
+    monkeypatch.setattr(cluster.subprocess, "Popen", no_spawn)
+    t0 = time.monotonic()
+    with pytest.raises(RuntimeError,
+                       match="already holds the tpu.*JAX_PLATFORMS=cpu"):
+        make_co(tmp_path)
+    assert time.monotonic() - t0 < 5.0
+
+
+def test_held_accelerator_probe_on_host_cpu():
+    """The real probe: a process that has only run JAX on the host CPU
+    holds no chip, so the guard never fires there."""
+    import jax.numpy as jnp
+
+    from repro.serve import cluster
+
+    jnp.ones(2).block_until_ready()
+    assert cluster._held_accelerator() is None

@@ -18,10 +18,8 @@ from repro.launch.specs import abstract_params
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
-# The mesh-based subprocess tests build their meshes through
-# distributed.sharding.make_device_mesh, which falls back to the
-# AxisType-free jax.make_mesh/Mesh constructors on the pinned 0.4.x jax —
-# so they run (not skip) on every jax this repo supports.
+# The mesh-based subprocess tests build Auto-axis meshes with
+# jax.make_mesh and must run (not skip).
 
 
 def run_sub(code: str) -> str:
@@ -73,8 +71,8 @@ def test_sharded_train_step_matches_single_device():
         from repro.configs import get_reduced
         from repro.distributed.partition import (batch_specs, to_shardings,
                                                  train_state_specs)
-        from repro.distributed.sharding import (make_device_mesh, make_rules,
-                                                use_rules)
+        from jax.sharding import AxisType
+        from repro.distributed.sharding import make_rules, use_rules
         from repro.train import TrainSettings, init_state
         from repro.train.step import make_train_step
 
@@ -87,7 +85,7 @@ def test_sharded_train_step_matches_single_device():
         state = init_state(key, cfg, s)
         ref, mref = jax.jit(make_train_step(cfg, s))(state, batch)
 
-        mesh = make_device_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), (AxisType.Auto,) * 2)
         rules = make_rules(mesh, fsdp=True)
         with mesh, use_rules(rules):
             st_specs = train_state_specs(cfg, cfg.optimizer, state)
@@ -119,14 +117,14 @@ def test_compressed_psum_int8_error_feedback():
         import jax, jax.numpy as jnp, numpy as np, json
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        from repro.distributed.sharding import make_device_mesh, shard_map_compat
+        from jax.sharding import AxisType
         from repro.optim import compression
 
-        mesh = make_device_mesh((8,), ("pod",))
+        mesh = jax.make_mesh((8,), ("pod",), (AxisType.Auto,))
         grads = {"w": jnp.asarray(
             np.random.default_rng(0).normal(0, 1, (8, 64, 32)).astype(np.float32))}
 
-        @partial(shard_map_compat, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh, check_vma=False,
                  in_specs=(P("pod"), P("pod")), out_specs=(P("pod"), P("pod")))
         def step(g, err):
             gl = {"w": g[0]}

@@ -145,8 +145,7 @@ def test_model_sharded_telemetry_bit_identical():
     from jax.sharding import PartitionSpec as P
     from repro.core import prng, snn
     from repro.core.lif import LIFStateInt
-    from repro.distributed.sharding import (make_2d_device_mesh,
-                                            shard_map_compat)
+    from repro.distributed.sharding import make_2d_device_mesh
     from repro.kernels.fused_snn import layer_shard_ways
 
     rng = np.random.default_rng(1)
@@ -182,10 +181,10 @@ def test_model_sharded_telemetry_bit_identical():
     st_specs = tuple(LIFStateInt(v=rep, enable=rep) for _ in states)
     tel_spec = {"n_spk": rep, "n_en": rep,
                 "tiles": P(None, ("data", "model"))}
-    f = shard_map_compat(
-        body, mesh,
+    f = jax.shard_map(
+        body, mesh=mesh,
         in_specs=(rep, rep, st_specs, w_specs),
-        out_specs=(rep, st_specs, rep, rep, tel_spec))
+        out_specs=(rep, st_specs, rep, rep, tel_spec), check_vma=False)
     _, st2, x2, adds2, tel2 = f(rng_state, pixels, states, weights)
 
     assert (np.asarray(x1) == np.asarray(x2)).all()

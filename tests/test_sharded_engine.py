@@ -194,10 +194,10 @@ def test_random_admission_matches_one_shot(seed, chunk_steps, burst,
 
 
 def test_mesh_validation():
-    from repro.distributed.sharding import make_device_mesh
+    from jax.sharding import AxisType
     rng = np.random.default_rng(0)
     params_q = small_net(rng, (12, 6))
-    mesh = make_device_mesh((len(jax.devices()),), ("data",))
+    mesh = jax.make_mesh((len(jax.devices()),), ("data",), (AxisType.Auto,))
     with pytest.raises(ValueError, match="axis"):
         ShardedSNNStreamEngine(params_q, SNN_CONFIG, mesh=mesh,
                                axis_name="model", backend="reference")

@@ -81,6 +81,9 @@ def test_fused_rejects_unpackable_codes(rng):
         snn.snn_apply_int(params_q, px, state, cfg, backend="fused")
     with pytest.raises(ValueError, match="9-bit"):
         SNNStreamEngine(params_q, cfg, batch_size=2, backend="fused")
+    # the staged kernels take the same int8 planes
+    with pytest.raises(ValueError, match="9-bit"):
+        snn.snn_apply_int(params_q, px, state, cfg, backend="staged")
     # the un-packing backends still accept wider codes
     snn.snn_apply_int(params_q, px, state, cfg, backend="reference")
     SNNStreamEngine(params_q, cfg, batch_size=2, backend="reference")
