@@ -333,7 +333,8 @@ def partial_contraction_pallas(x_u8: jax.Array, en_u8: jax.Array,
             jax.ShapeDtypeStruct((B, n_out_pad), jnp.int32),
             jax.ShapeDtypeStruct((n_blocks,), jnp.int32),
         ],
-        interpret=interpret)(x_u8, en_u8, w_packed)
+        interpret=interpret, name="snn_partial_contraction")(
+        x_u8, en_u8, w_packed)
     return out, skipped
 
 
@@ -689,7 +690,8 @@ def fused_snn_stack_pallas(pixels_u8: jax.Array, state_u32: jax.Array,
 
     outs = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=interpret)(*inputs)
+        out_shape=out_shape, interpret=interpret,
+        name="snn_stack_kernel")(*inputs)
 
     cnt, vtr, first, side, st_out = outs[:5]
     v_fin = tuple(outs[5:5 + L])

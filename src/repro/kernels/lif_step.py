@@ -90,6 +90,7 @@ def lif_forward_pallas(spikes_t: jax.Array, w_packed: jax.Array, *,
 
     spk, vtr, vfin = pl.pallas_call(
         kernel,
+        name="lif_step",
         grid=grid,
         in_specs=[
             # Full T and full N_in per batch tile; only batch dim is split.

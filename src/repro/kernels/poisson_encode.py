@@ -57,6 +57,7 @@ def poisson_encode_pallas(pixels_u8: jax.Array, state_u32: jax.Array,
     kernel = functools.partial(_encode_kernel, num_steps=num_steps)
     spikes, state_out = pl.pallas_call(
         kernel,
+        name="poisson_encode",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bB, bN), lambda i, j: (i, j)),

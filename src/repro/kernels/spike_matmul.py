@@ -87,6 +87,7 @@ def spike_matmul_pallas(spikes: jax.Array, w_packed: jax.Array, *,
                else [])
     return pl.pallas_call(
         kernel,
+        name="spike_matmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bB, bK), lambda i, j, k: (i, k)),

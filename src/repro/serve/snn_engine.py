@@ -70,12 +70,13 @@ from ..core import prng as prng_mod
 from ..core.snn import (SNNConfig, readout_pred, snn_int_stack_step,
                         snn_int_stack_step_sharded)
 from ..core.telemetry import (ChunkTelemetry, EngineLoad,
-                              telemetry_partition_specs)
+                              telemetry_partition_specs, tiles_total)
 from .early_exit import StabilityGateState, stability_specs, stability_step
 from .faults import (DeviceLostFault, DispatchFault, EngineFailure,
                      EngineHealthState, FaultInjector, FaultToleranceConfig,
                      PoisonDispatchError, injector_from_env, telemetry_ok)
 from .rollout import WeightBank, merge_version_chunks
+from . import spans
 from .telemetry import AdaptiveDispatchConfig, TelemetryController, \
     make_controller, \
     summarize_chunk
@@ -600,8 +601,20 @@ class SNNStreamEngine:
         self._lane_versions = np.zeros(batch_size, np.int64)
         self._service_ewma: float | None = None
         self._retired_total = 0
+        # span counts (serve.spans): chunk launches so far, the last
+        # committed chunk's telemetry (kept only while spans record, read
+        # at the next sync) and the 128x128 tile pairs one batch block of
+        # one launch holds per step (a model peer's own weight shard)
+        from ..kernels.fused_snn import layer_shard_ways
+        self._launches = 0
+        self._chunk_tel: ChunkTelemetry | None = None
+        ways = layer_shard_ways(self.layer_sizes, self.model_shards)
+        self._tiles_per_block = sum(
+            tiles_total((k, n // w))[0] for k, n, w in
+            zip(self.layer_sizes[:-1], self.layer_sizes[1:], ways))
 
     _SERVICE_EWMA_ALPHA = 0.25
+    n_devices = 1        # lane slot blocks; compaction keeps a lane in its own
 
     @property
     def weights(self) -> tuple:
@@ -693,26 +706,29 @@ class SNNStreamEngine:
     # ---- scheduling -----------------------------------------------------
     def _harvest(self, st: LaneState, finished: np.ndarray) -> list[int]:
         """Collect RequestResults for every lane in the ``finished`` mask."""
-        done_ids = []
-        for i in np.nonzero(finished)[0]:
-            rid = self.lane_req[int(i)]
-            steps = int(st.steps[i])
-            self.results[rid] = RequestResult(
-                request_id=rid,
-                pred=self._host_pred(st.counts[i], st.first[i],
-                                     st.v[-1][i], st.v_peak[-1][i]),
-                spike_counts=st.counts[i].copy(),
-                steps=steps,
-                adds=int(st.adds[i]),
-                early_exit=steps < self.cfg.num_steps,
-                weight_version=int(st.weight_version[i]),
-            )
-            done_ids.append(rid)
-            self._retired_total += 1
-            a = self._SERVICE_EWMA_ALPHA
-            self._service_ewma = (float(steps) if self._service_ewma is None
-                                  else (1 - a) * self._service_ewma
-                                  + a * steps)
+        with spans.span("snn.harvest") as counts:
+            done_ids = []
+            for i in np.nonzero(finished)[0]:
+                rid = self.lane_req[int(i)]
+                steps = int(st.steps[i])
+                self.results[rid] = RequestResult(
+                    request_id=rid,
+                    pred=self._host_pred(st.counts[i], st.first[i],
+                                         st.v[-1][i], st.v_peak[-1][i]),
+                    spike_counts=st.counts[i].copy(),
+                    steps=steps,
+                    adds=int(st.adds[i]),
+                    early_exit=steps < self.cfg.num_steps,
+                    weight_version=int(st.weight_version[i]),
+                )
+                done_ids.append(rid)
+                self._retired_total += 1
+                a = self._SERVICE_EWMA_ALPHA
+                self._service_ewma = (
+                    float(steps) if self._service_ewma is None
+                    else (1 - a) * self._service_ewma + a * steps)
+            if counts is not None:
+                counts.update(n=len(done_ids), rids=tuple(done_ids))
         return done_ids
 
     def _admit_into(self, st: LaneState, slot: int) -> None:
@@ -766,12 +782,31 @@ class SNNStreamEngine:
         """Host tile → device (the sharded engine re-places onto its mesh)."""
         return jax.tree.map(jnp.asarray, st)
 
+    def _read_tile(self) -> LaneState:
+        """Device tile → host: a numpy copy of every lane-state leaf."""
+        with spans.span("snn.readback"):
+            return jax.tree.map(np.array, self.lanes)
+
     def _needs_compaction(self) -> bool:
         """Cheap pre-check: only the (B,) active mask crosses the device
         boundary.  The full lane-state round trip happens only when a lane
-        actually retired or a queued request can be admitted."""
+        actually retired or a queued request can be admitted.
+
+        This is where the host waits for the previous chunk.  While spans
+        record, that chunk's skipped tile pairs come back in the same
+        transfer and ride on the ``snn.sync`` span."""
         occupied = np.array([r is not None for r in self.lane_req])
-        active = np.asarray(self.lanes.active)
+        tel, self._chunk_tel = self._chunk_tel, None
+        with spans.span("snn.sync") as counts:
+            if tel is None or counts is None:
+                active = np.asarray(self.lanes.active)
+            else:
+                active, skipped = jax.device_get(
+                    (self.lanes.active, tel.tiles_skipped))
+                chunk, _, blocks = skipped.shape
+                counts.update(tiles_skipped=int(skipped.sum()),
+                              tile_pairs=(self._tiles_per_block * blocks
+                                          * chunk))
         waiting = bool(self.queue or self._adoptions)
         return bool((occupied & ~active).any() or (
             waiting and not (occupied & active).all()))
@@ -781,31 +816,49 @@ class SNNStreamEngine:
 
         Returns the request ids finished in this call.  Runs on the host at
         chunk boundaries: the batch tile stays dense, so freed slots start
-        contributing to throughput on the very next chunk.
+        contributing to throughput on the very next chunk.  Compaction is
+        local to each device's slot block (one block on one device), so a
+        lane never changes device and no resharding traffic is generated;
+        admission fills freed slots round-robin across the blocks,
+        adoptions first (:meth:`_admit_into` drains them before the fresh
+        queue).
         """
         if not self._needs_compaction():
             return []
         occupied = np.array([r is not None for r in self.lane_req])
-        st = jax.tree.map(lambda a: np.array(a), self.lanes)
+        st = self._read_tile()
         done_ids = self._harvest(st, occupied & ~st.active)
 
-        # Compact: live lanes first (stable), freed/empty lanes after.
-        live = np.nonzero(occupied & st.active)[0]
-        free = np.nonzero(~(occupied & st.active))[0]
-        order = np.concatenate([live, free]).astype(np.int32)
-        st = jax.tree.map(lambda a: a[order], st)
-        n_live = len(live)
-        self.lane_req = ([self.lane_req[int(i)] for i in live]
-                         + [None] * (self.batch_size - n_live))
+        # Compact each block: live lanes first (stable), freed slots after.
+        live = occupied & st.active
+        size = self.batch_size // self.n_devices
+        order, lane_req, free_slots = [], [], []
+        for lo in range(0, self.batch_size, size):
+            block = np.arange(lo, lo + size)
+            keep = block[live[block]]
+            order.extend(keep.tolist() + block[~live[block]].tolist())
+            lane_req.extend([self.lane_req[int(i)] for i in keep]
+                            + [None] * (size - len(keep)))
+            free_slots.append(list(range(lo + len(keep), lo + size)))
+        st = jax.tree.map(lambda a: a[np.asarray(order, np.int32)], st)
+        self.lane_req = lane_req
 
-        # Admit waiting work (adoptions first) into the freed tail slots.
-        for slot in range(n_live, self.batch_size):
-            if not (self.queue or self._adoptions):
-                break
-            self._admit_into(st, slot)
+        with spans.span("snn.admit") as counts:
+            admitted = []
+            while (self.queue or self._adoptions) and any(free_slots):
+                for slots in free_slots:
+                    if not (self.queue or self._adoptions):
+                        break
+                    if slots:
+                        slot = slots.pop(0)
+                        self._admit_into(st, slot)
+                        admitted.append(self.lane_req[slot])
+            if counts is not None:
+                counts.update(n=len(admitted), rids=tuple(admitted))
 
         self._sync_versions(st)
-        self.lanes = self._upload(st)
+        with spans.span("snn.upload"):
+            self.lanes = self._upload(st)
         return done_ids
 
     def _sync_versions(self, st: LaneState) -> None:
@@ -837,7 +890,7 @@ class SNNStreamEngine:
         version mirror cleared, so a dead engine holds no live versions.
         """
         occupied = np.array([r is not None for r in self.lane_req])
-        st = jax.tree.map(lambda a: np.array(a), self.lanes)
+        st = self._read_tile()
         self._harvest(st, occupied & ~st.active)
         rows = []
         for i in np.nonzero(occupied & st.active)[0]:
@@ -860,7 +913,7 @@ class SNNStreamEngine:
         invariant makes the row placement-independent).
         """
         occupied = np.array([r is not None for r in self.lane_req])
-        st = jax.tree.map(lambda a: np.array(a), self.lanes)
+        st = self._read_tile()
         rows = []
         for i in np.nonzero(occupied & st.active)[0]:
             idx = int(i)
@@ -877,7 +930,7 @@ class SNNStreamEngine:
         touching any other lane.
         """
         slot = self.lane_req.index(request_id)
-        st = jax.tree.map(lambda a: np.array(a), self.lanes)
+        st = self._read_tile()
         row = jax.tree.map(lambda a: a[slot].copy(), st)
         st.active[slot] = False
         self.lane_req[slot] = None
@@ -958,6 +1011,7 @@ class SNNStreamEngine:
         occ = [r is not None for r in self.lane_req]
         versions = sorted({int(v) for v, o in zip(self._lane_versions, occ)
                            if o})
+        self._launches += max(1, len(versions))
         if len(versions) <= 1:
             v = versions[0] if versions else self.bank.current
             return self._advance(lanes, self.bank.weights(v))
@@ -1039,6 +1093,17 @@ class SNNStreamEngine:
         Returns ``(lanes', telemetry | None)`` — ``None`` marks a round
         that produced no observable record (hang / backoff / corruption).
         """
+        with spans.span("snn.dispatch") as counts:
+            launches = self._launches
+            out = self._dispatch_guarded(lanes)
+            if counts is not None:
+                counts.update(
+                    launches=self._launches - launches,
+                    lanes_busy=sum(r is not None for r in self.lane_req),
+                    chunk_steps=self.controller.chunk_steps)
+        return out
+
+    def _dispatch_guarded(self, lanes: LaneState):
         if self.injector is None:
             return self._dispatch_versions(lanes)
         if not self.health.alive:
@@ -1095,10 +1160,13 @@ class SNNStreamEngine:
             return out, tel
 
     def _observe(self, src: LaneState, nxt: LaneState,
-                 tel: ChunkTelemetry) -> None:
-        """Feed one chunk's telemetry to the controller (adaptive only —
-        frozen mode never forces the device→host readback)."""
-        if self.controller.frozen:
+                 tel: ChunkTelemetry | None) -> None:
+        """Take one committed chunk's telemetry: kept for the next sync's
+        tile counts while spans record, and fed to the controller
+        (adaptive only — frozen mode never forces the device→host
+        readback)."""
+        self._chunk_tel = tel if spans.enabled() else None
+        if tel is None or self.controller.frozen:
             return
         self.controller.observe(summarize_chunk(
             tel, self.layer_sizes,
@@ -1107,6 +1175,10 @@ class SNNStreamEngine:
 
     def step(self) -> list[int]:
         """Admit + run one chunk.  Returns request ids finished so far."""
+        with spans.span("snn.step", engine=self.engine_id):
+            return self._step()
+
+    def _step(self) -> list[int]:
         done = self._admit_and_compact()
         if self._cooldown > 0:
             # transient-fault backoff: sit this scheduling round out
@@ -1114,8 +1186,7 @@ class SNNStreamEngine:
             return done
         src = self.lanes
         self.lanes, tel = self._dispatch_chunk(src)
-        if tel is not None:
-            self._observe(src, self.lanes, tel)
+        self._observe(src, self.lanes, tel)
         return done
 
     def run(self, max_chunks: int | None = None) -> dict[int, RequestResult]:
@@ -1166,23 +1237,25 @@ class ShardedSNNStreamEngine(SNNStreamEngine):
     model axis instead of streaming weights from HBM.  Results stay
     bit-identical: disjoint integer column shards concatenate exactly.
 
-    Scheduling differences from the base engine:
+    Scheduling on the mesh:
 
       * **Device-local compaction** — retired lanes are compacted within
         their device's slot block, never across blocks, so lane state is
         re-uploaded onto the same device and no resharding traffic is
-        generated at chunk boundaries.
+        generated at chunk boundaries (the base engine's compaction, one
+        block per device).
       * **Round-robin admission** — queued requests fill freed slots
         cycling across device blocks, keeping every device's live-lane
         count balanced under partial load.
-      * **Admission/compute overlap** — after dispatching chunk *k* the
-        engine speculatively enqueues chunk *k+1* on its (not yet ready)
-        output, so the devices keep running while the host blocks on the
-        chunk-*k* retirement readback and does queue bookkeeping.  If the
-        readback shows a retirement or a possible admission, the
-        speculative state is discarded and the chunk re-dispatched from
-        the compacted tile — speculation is the pure chunk function on
-        the same state, so using it never changes results.
+      * **Admission/compute overlap**, this class's own — after
+        dispatching chunk *k* the engine speculatively enqueues chunk
+        *k+1* on its (not yet ready) output, so the devices keep running
+        while the host blocks on the chunk-*k* retirement readback and
+        does queue bookkeeping.  If the readback shows a retirement or a
+        possible admission, the speculative state is discarded and the
+        chunk re-dispatched from the compacted tile — speculation is the
+        pure chunk function on the same state, so using it never changes
+        results.
         ``stats['spec_used']``/``stats['spec_wasted']`` count the
         outcomes (the benchmark's admission-overlap timing).
     """
@@ -1320,44 +1393,7 @@ class ShardedSNNStreamEngine(SNNStreamEngine):
             lanes, weights)
 
     # ---- scheduling -----------------------------------------------------
-    def _admit_and_compact(self) -> list[int]:
-        """Block-local compaction + round-robin admission (see class doc)."""
-        if not self._needs_compaction():
-            return []
-        occupied = np.array([r is not None for r in self.lane_req])
-        st = jax.tree.map(lambda a: np.array(a), self.lanes)
-        done_ids = self._harvest(st, occupied & ~st.active)
-
-        # Compact each device block independently: live lanes first within
-        # the block, freed slots after — a lane never changes device.
-        order, lane_req, free_slots = [], [], []
-        for d in range(self.n_devices):
-            lo = d * self.local_batch
-            block = np.arange(lo, lo + self.local_batch)
-            live = block[occupied[block] & st.active[block]]
-            free = block[~(occupied[block] & st.active[block])]
-            order.extend(live.tolist() + free.tolist())
-            lane_req.extend([self.lane_req[int(i)] for i in live]
-                            + [None] * len(free))
-            free_slots.append(list(range(lo + len(live),
-                                         lo + self.local_batch)))
-        st = jax.tree.map(lambda a: a[np.asarray(order, np.int32)], st)
-        self.lane_req = lane_req
-
-        # Round-robin admission across device blocks (adoptions first —
-        # _admit_into drains them before the fresh queue).
-        while (self.queue or self._adoptions) and any(free_slots):
-            for d in range(self.n_devices):
-                if not (self.queue or self._adoptions):
-                    break
-                if free_slots[d]:
-                    self._admit_into(st, free_slots[d].pop(0))
-
-        self._sync_versions(st)
-        self.lanes = self._upload(st)
-        return done_ids
-
-    def step(self) -> list[int]:
+    def _step(self) -> list[int]:
         """Admit + run one chunk, overlapping the next with host work."""
         done = self._admit_and_compact()
         if self._cooldown > 0:
@@ -1388,8 +1424,7 @@ class ShardedSNNStreamEngine(SNNStreamEngine):
         self._spec_steps = None
         self.lanes = nxt
         self.stats["chunks"] += 1
-        if tel is not None:
-            self._observe(src, nxt, tel)
+        self._observe(src, nxt, tel)
         # Speculation is off while a fault harness is armed: a speculative
         # launch would consume injector consults (and could fault) one
         # step early, detaching the fault coordinates from the committed
