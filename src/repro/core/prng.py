@@ -19,6 +19,7 @@ import numpy as np
 
 __all__ = [
     "seed_state",
+    "seed_state_host",
     "xorshift32_step",
     "xorshift32_sequence",
     "uniform_u8",
@@ -28,28 +29,38 @@ __all__ = [
 _ZERO_SEED_REMAP = np.uint32(0x9E3779B9)  # 2**32 / golden ratio
 
 
+def seed_state_host(seed: int, shape: tuple[int, ...]) -> np.ndarray:
+    """Integer-seeded per-lane uint32 xorshift state, built in host memory.
+
+    Hashed counter seeding matching the RTL's LFSR preload chain: lane
+    ``i`` of seed ``s`` is a SplitMix64-style finalizer of
+    ``s·φ64 + i·c`` truncated to 32 bits, zeros remapped.  The streaming
+    engine writes these rows straight into its host lane tile.
+    """
+    n = int(np.prod(shape)) if shape else 1
+    with np.errstate(over="ignore"):  # intentional mod-2^64 wraparound
+        lane = np.arange(n, dtype=np.uint64)
+        s = (np.uint64(seed) * np.uint64(0x9E3779B97F4A7C15)
+             + lane * np.uint64(0xBF58476D1CE4E5B9))
+        # SplitMix64-style finalizer, truncated to 32 bits.
+        s ^= s >> np.uint64(30)
+        s *= np.uint64(0xBF58476D1CE4E5B9)
+        s ^= s >> np.uint64(27)
+        s *= np.uint64(0x94D049BB133111EB)
+        s ^= s >> np.uint64(31)
+    state = (s & np.uint64(0xFFFFFFFF)).astype(np.uint32).reshape(shape)
+    return np.where(state == 0, _ZERO_SEED_REMAP, state)
+
+
 def seed_state(key_or_int, shape: tuple[int, ...]) -> jax.Array:
     """Build a per-lane uint32 xorshift state array.
 
-    Accepts either a python int (hashed counter seeding, matching the RTL's
-    LFSR preload chain) or a ``jax.random`` key (used by the training paths,
-    where bit-compatibility with RTL is not required).
+    Accepts either a python int (:func:`seed_state_host`, placed on the
+    device) or a ``jax.random`` key (used by the training paths, where
+    bit-compatibility with RTL is not required).
     """
     if isinstance(key_or_int, (int, np.integer)):
-        n = int(np.prod(shape)) if shape else 1
-        with np.errstate(over="ignore"):  # intentional mod-2^64 wraparound
-            lane = np.arange(n, dtype=np.uint64)
-            s = (np.uint64(key_or_int) * np.uint64(0x9E3779B97F4A7C15)
-                 + lane * np.uint64(0xBF58476D1CE4E5B9))
-            # SplitMix64-style finalizer, truncated to 32 bits.
-            s ^= s >> np.uint64(30)
-            s *= np.uint64(0xBF58476D1CE4E5B9)
-            s ^= s >> np.uint64(27)
-            s *= np.uint64(0x94D049BB133111EB)
-            s ^= s >> np.uint64(31)
-        state = (s & np.uint64(0xFFFFFFFF)).astype(np.uint32).reshape(shape)
-        state = np.where(state == 0, _ZERO_SEED_REMAP, state)
-        return jnp.asarray(state)
+        return jnp.asarray(seed_state_host(key_or_int, shape))
     # jax key path
     bits = jax.random.bits(key_or_int, shape, dtype=jnp.uint32)
     return jnp.where(bits == 0, jnp.uint32(_ZERO_SEED_REMAP), bits)

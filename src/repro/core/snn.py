@@ -330,7 +330,7 @@ def resolve_backend(cfg: SNNConfig, backend: str | None = None,
 def readout_pred(counts: jax.Array, first_t: jax.Array, v_final: jax.Array,
                  readout: str, num_steps: int,
                  v_trace: jax.Array | None = None,
-                 v_peak: jax.Array | None = None) -> jax.Array:
+                 v_peak: jax.Array | None = None, *, xp=jnp) -> jax.Array:
     """Per-lane prediction under the configured readout.
 
     The single source of truth shared by ``snn_apply_int``, the streaming
@@ -341,21 +341,26 @@ def readout_pred(counts: jax.Array, first_t: jax.Array, v_final: jax.Array,
     accumulator ``v_peak`` (the streaming form: max is associative, so the
     chunked running peak is bit-identical to the one-shot maximum) or,
     when only a trace is at hand, from ``max(v_trace)`` over time.
+
+    ``xp`` is the array namespace: ``jnp`` (traced or on the device) or
+    ``np`` for rows already in host memory, which the engine's harvest
+    ranks without a device round trip.  Both give the same integers; the
+    ``v_trace`` form is ``jnp`` only.
     """
     if readout == "count":
-        return jnp.argmax(counts, axis=-1)
+        return xp.argmax(counts, axis=-1)
     if readout == "membrane":
         if v_peak is not None:
-            return jnp.argmax(v_peak, axis=-1)
+            return xp.argmax(v_peak, axis=-1)
         return pruning.peak_membrane_readout(v_trace)
     # Two score tiers: any class that spiked outranks every membrane-only
     # class (spiked tier is additive, large + (T - first), so it cannot
     # overflow int32 for any realistic window — (T - first)·large would
     # wrap already at T = 128).
-    large = jnp.int32(1 << 24)
-    score = jnp.where(counts > 0, large + (num_steps - first_t),
-                      jnp.clip(v_final, -large + 1, large - 1))
-    return jnp.argmax(score, axis=-1)
+    large = xp.int32(1 << 24)
+    score = xp.where(counts > 0, large + (num_steps - first_t),
+                     xp.clip(v_final, -large + 1, large - 1))
+    return xp.argmax(score, axis=-1)
 
 
 def snn_apply_int(params_q: dict, pixels_u8: jax.Array, prng_state: jax.Array,

@@ -699,9 +699,10 @@ class SNNStreamEngine:
     # ---- readout --------------------------------------------------------
     def _host_pred(self, counts: np.ndarray, first: np.ndarray,
                    v_last: np.ndarray, v_peak: np.ndarray) -> int:
-        """Harvest-time prediction for one retired lane."""
+        """Harvest-time prediction for one retired lane, ranked in numpy
+        on the host row: no device round trip per request."""
         return int(readout_pred(counts, first, v_last, self.cfg.readout,
-                                self.cfg.num_steps, v_peak=v_peak))
+                                self.cfg.num_steps, v_peak=v_peak, xp=np))
 
     # ---- scheduling -----------------------------------------------------
     def _harvest(self, st: LaneState, finished: np.ndarray) -> list[int]:
@@ -760,8 +761,8 @@ class SNNStreamEngine:
             return
         rid, pixels = self.queue.pop(0)
         st.px[slot] = pixels
-        st.rng[slot] = np.asarray(
-            prng_mod.seed_state(self.seed + rid, (self.n_in,)))
+        st.rng[slot] = prng_mod.seed_state_host(self.seed + rid,
+                                                (self.n_in,))
         for v in st.v:
             v[slot] = self.cfg.lif.v_rest
         for en in st.en:

@@ -3,6 +3,7 @@ including hypothesis property tests on the encoding invariants."""
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import encoding, prng
@@ -91,3 +92,40 @@ def test_hw_and_jax_encoders_same_distribution():
     sp = encoding.poisson_encode_jax(px01, jax.random.PRNGKey(0), 512)
     rate = np.asarray(sp.mean(axis=0))
     np.testing.assert_allclose(rate, np.asarray(px01), atol=0.08)
+
+
+def _hashed_lane(seed: int, i: int) -> int:
+    """Lane ``i`` of the integer seeding, in Python integers."""
+    m = (1 << 64) - 1
+    s = (seed * 0x9E3779B97F4A7C15 + i * 0xBF58476D1CE4E5B9) & m
+    s ^= s >> 30
+    s = (s * 0xBF58476D1CE4E5B9) & m
+    s ^= s >> 27
+    s = (s * 0x94D049BB133111EB) & m
+    s ^= s >> 31
+    return (s & 0xFFFFFFFF) or 0x9E3779B9
+
+
+# Seed 0 hashes lane 0 to zero, and this seed lane 3 (the finalizer
+# inverted from 2 << 32): both take the zero remap.
+_ZERO_LANE_SEEDS = [(0, 0), (2680425821871638649, 3)]
+
+
+@pytest.mark.parametrize("shape", [(), (4,), (3, 5), (784,), (2, 784)])
+@pytest.mark.parametrize("seed", [0, 1, 7, 2**31 - 1, 2**31 + 12345,
+                                  3000000019, 2**32 + 5,
+                                  2680425821871638649])
+def test_seed_state_host_matches_seed_state(seed, shape):
+    """The engine's host seeding is ``seed_state``'s integer path, bit for
+    bit, as a uint32 numpy array; every lane is the counter hash."""
+    host = prng.seed_state_host(seed, shape)
+    assert type(host) is np.ndarray and host.dtype == np.uint32
+    assert host.shape == shape
+    np.testing.assert_array_equal(host, np.asarray(prng.seed_state(seed,
+                                                                   shape)))
+    want = [_hashed_lane(seed, i) for i in range(host.size)]
+    np.testing.assert_array_equal(host.ravel(), np.asarray(want, np.uint32))
+    assert (host != 0).all()
+    for s, lane in _ZERO_LANE_SEEDS:
+        if seed == s and host.size > lane:
+            assert host.ravel()[lane] == 0x9E3779B9

@@ -20,6 +20,7 @@ import dataclasses
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs.snn_mnist import SNN_CONFIG_PRUNED
 from repro.core import prng, snn
@@ -165,3 +166,67 @@ def test_pruned_engine_counts_are_saturated(rng):
                                 cfg.num_steps)))
     # spiked ⇔ a real first-spike time; silent ⇔ sentinel
     assert ((first < cfg.num_steps) == (counts == 1)).all()
+
+
+_I32 = np.iinfo(np.int32)
+
+
+def _rows(counts, first, v):
+    return (np.asarray(counts, np.int32), np.asarray(first, np.int32),
+            np.asarray(v, np.int32))
+
+
+def _random_rows(seed, b=6, n=10):
+    r = np.random.default_rng(seed)
+    counts = r.integers(0, 3, (b, n)) * (r.random((b, n)) < 0.4)
+    first = np.where(counts > 0, r.integers(0, T, (b, n)), SENT)
+    return _rows(counts, first, r.integers(-300, 300, (b, n)))
+
+
+# (counts, first, v): v stands for both v_final and v_peak; each readout
+# ranks the one it reads.
+_HOST_CASES = {
+    "all_zero": _rows(np.zeros((2, 10)), np.full((2, 10), SENT),
+                      np.zeros((2, 10))),
+    "all_zero_v_peak_sentinel": _rows(np.zeros((1, 10)),
+                                      np.full((1, 10), SENT),
+                                      np.full((1, 10), _I32.min)),
+    "count_tie": _rows([[0, 2, 2, 1]], [[SENT, 1, 4, 6]], [[0, 0, 9, 9]]),
+    "first_spike_tie": _rows([[0, 1, 1, 0]], [[SENT, 3, 3, SENT]],
+                             [[0, 0, 10_000, 0]]),
+    "membrane_tie": _rows([[0, 0, 0, 0]], [[SENT] * 4], [[5, 9, 9, 2]]),
+    "spiked_outranks_membrane": _rows([[0, 0, 0, 1]],
+                                      [[SENT, SENT, SENT, T - 1]],
+                                      [[(1 << 24) - 2, 127, 0, -5]]),
+    "int32_max_membranes": _rows([[0, 0, 1, 0]], [[SENT, SENT, T - 1, SENT]],
+                                 [[_I32.max, _I32.max, 0, _I32.min]]),
+    "int32_max_silent": _rows(np.zeros((1, 4)), np.full((1, 4), SENT),
+                              [[_I32.min, _I32.max, _I32.max, _I32.min]]),
+    "int32_min_silent": _rows(np.zeros((1, 4)), np.full((1, 4), SENT),
+                              [[_I32.min, _I32.min, _I32.min + 1, _I32.min]]),
+    "clip_ties_above_large": _rows(np.zeros((1, 4)), np.full((1, 4), SENT),
+                                   [[1 << 24, (1 << 24) + 7, _I32.max, 3]]),
+    "random_0": _random_rows(0),
+    "random_1": _random_rows(1),
+    "random_2": _random_rows(2),
+}
+
+
+@pytest.mark.parametrize("readout", ["count", "first_spike", "membrane"])
+@pytest.mark.parametrize("case", sorted(_HOST_CASES))
+def test_host_readout_matches_jnp(case, readout):
+    """``xp=np`` (the engine's harvest) ranks host rows exactly as the jnp
+    default: the count argmax, the two first-spike tiers with their clip,
+    the peak-membrane argmax, lowest index on every tie; and whole rows as
+    single lanes."""
+    counts, first, v = _HOST_CASES[case]
+    want = np.asarray(readout_pred(jnp.asarray(counts), jnp.asarray(first),
+                                   jnp.asarray(v), readout, T,
+                                   v_peak=jnp.asarray(v)))
+    got = readout_pred(counts, first, v, readout, T, v_peak=v, xp=np)
+    assert type(got) is np.ndarray
+    np.testing.assert_array_equal(got, want)
+    for i in range(counts.shape[0]):
+        lane = readout_pred(counts[i], first[i], v[i], readout, T,
+                            v_peak=v[i], xp=np)
+        assert isinstance(lane, np.integer) and int(lane) == int(want[i])
