@@ -4,10 +4,11 @@
 
 In one process, for each seed: the program serves a short window of the
 cell's own traffic at the cell's own size, and the comparison reads its
-numbers (the lower readings).  Then the control, the reference at the
-next precision below the configuration's (int4 weight codes), is put in
-the program's place for the same requests and read the same way (the
-upper readings).  One JSON line per seed.
+numbers (the lower readings).  Then the control, the reference of the
+configuration's network at the next precision below the configuration's
+(its ``control_weights``: int4 codes for the dense stack's 8-bit ones),
+is put in the program's place for the same requests and read the same
+way (the upper readings).  One JSON line per seed.
 """
 
 import time
@@ -37,7 +38,6 @@ def main(argv=None) -> int:
     import numpy as np
 
     import harness
-    import reference
     from generator import Traffic
     from repro.compile_cache import enable_compile_cache
 
@@ -49,12 +49,13 @@ def main(argv=None) -> int:
     cfg = bench.config(wl["config"])
     mix = bench.traffic(wl["traffic"])
     lanes = cfg["lanes_per_device"] * cfg["mesh"]["data"]
-    spec = reference.spec_of(cfg)
-    weights = reference.make_weights(cfg)
-    ctl_weights = reference.control_weights(weights)
+    net = harness.network(cfg, bench.dir)
+    spec = net.spec_of(cfg)
+    weights = net.make_weights(cfg)
+    ctl_weights = net.control_weights(weights)
     for seed in (int(s) for s in args.seeds.split(",")):
         traffic = Traffic(mix, seed, lanes, bench.dir)
-        eng = harness.build_engine(cfg, weights, seed)
+        eng = harness.build_engine(cfg, weights, seed, bench.dir)
         traffic.warm_up(eng)
         window = traffic.run(eng, args.seconds)
         steps = -(-cfg["num_steps"] // int(eng.chunk_steps)) + 1
@@ -65,14 +66,16 @@ def main(argv=None) -> int:
                       np.asarray(res[r].spike_counts))
                   for r in due if r in res}
         del eng, res
-        prog, n_prog, _ = harness.compare(cfg, seed, traffic, due, served)
+        prog, n_prog, _ = harness.compare(cfg, seed, traffic, due, served,
+                                          bench.dir)
         rids = np.array(sorted(due))
         px = traffic.pixels[[traffic.rid_to_index[int(r)] for r in rids]]
-        out = reference.serve(spec, ctl_weights, px, seed + rids)
+        out = net.serve(spec, ctl_weights, px, seed + rids)
         ctl_served = {int(r): (out["pred"][i], out["steps"][i],
                                out["adds"][i], out["counts"][i])
                       for i, r in enumerate(rids)}
-        ctl, n_ctl, _ = harness.compare(cfg, seed, traffic, due, ctl_served)
+        ctl, n_ctl, _ = harness.compare(cfg, seed, traffic, due, ctl_served,
+                                        bench.dir)
         print(json.dumps({
             "workload": args.workload, "seed": seed, "due": len(due),
             "program": {k: v for k, (v, _) in prog.items()},

@@ -29,9 +29,15 @@ from jax.profiler import TraceAnnotation
 HERE = os.path.dirname(os.path.abspath(__file__))
 
 
+_loaded: dict = {}             # absolute path -> module
+
+
 def plugin(bench_dir: str, kind: str, name: str):
-    """The module ``<bench_dir>/<kind>/<name>.py``."""
-    path = os.path.join(bench_dir, kind, name + ".py")
+    """The module ``<bench_dir>/<kind>/<name>.py``, executed once a
+    process, as an import is, so that what it compiles is compiled once."""
+    path = os.path.abspath(os.path.join(bench_dir, kind, name + ".py"))
+    if path in _loaded:
+        return _loaded[path]
     if not os.path.exists(path):
         raise KeyError(f"no {kind} named {name!r}: {path} does not exist")
     spec = importlib.util.spec_from_file_location(
@@ -39,6 +45,7 @@ def plugin(bench_dir: str, kind: str, name: str):
         path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    _loaded[path] = mod
     return mod
 
 

@@ -1,5 +1,5 @@
 """One run of one cell: set-up, a measured window, the check of what the
-window served against ``reference.py``, and the cell's metrics.
+window served against the network's reference, and the cell's metrics.
 
 Everything that belongs to one configuration, traffic mix or metric is a
 file of its own, found by the name that ``BENCHMARK.json`` gives it:
@@ -10,6 +10,12 @@ file of its own, found by the name that ``BENCHMARK.json`` gives it:
     chipbench/arrivals/<kind>.py   an arrival process a mix names
     chipbench/inputs/<kind>.py     an input kind a mix names
     chipbench/metrics/<metric>.py  ``read(run) -> float | None``
+    chipbench/networks/<network>.py
+                                   the network a configuration names
+                                   (``"network"``): its reference,
+                                   weights, control, program parameters
+                                   and work counts (``networks/dense.py``
+                                   lists the interface)
 
 A cell is added by adding such files and entries; no file here changes.
 """
@@ -26,10 +32,10 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-import reference
+import program_spans
 import trace_reduce
 import work
-from generator import Traffic, Window, plugin
+from generator import HERE, Traffic, Window, plugin
 
 CHECKS_LIMIT = 0             # every compared number must read exactly 0
 SAMPLE = 4096                # served requests compared per run, at most
@@ -90,6 +96,7 @@ class Run:
     chunk_steps: int
     setup_s: float
     window: Window
+    network: object                # the module networks/<network>.py
     retired_steps: dict            # rid -> window steps, retired in window
     spec_stats: dict | None        # sharded engine: used / wasted deltas
     launches: int | None           # stack-kernel launches in the window,
@@ -105,28 +112,21 @@ class Run:
                          if r in w.retired_at])
 
 
-def snn_config(cfg: dict):
-    from repro.core.lif import LIFConfig
-    from repro.core.snn import SNNConfig
-    lif = cfg["lif"]
-    return SNNConfig(
-        layer_sizes=tuple(cfg["layer_sizes"]), num_steps=cfg["num_steps"],
-        lif=LIFConfig(decay_shift=lif["decay_shift"],
-                      v_threshold=lif["v_threshold"], v_rest=lif["v_rest"],
-                      v_min=lif["v_min"], v_max=lif["v_max"]),
-        weight_bits=cfg["weight_bits"], readout=cfg["readout"],
-        active_pruning=cfg["active_pruning"], backend="auto")
+def network(cfg: dict, bench_dir: str = HERE):
+    """The module ``<bench_dir>/networks/<network>.py`` of the network
+    that the configuration names."""
+    return plugin(bench_dir, "networks", cfg["network"])
 
 
-def build_engine(cfg: dict, weights: tuple, seed: int):
+def build_engine(cfg: dict, weights, seed: int, bench_dir: str = HERE):
     """The program's streaming engine for this deployment: one device, or
     the sharded engine over a (data x model) mesh of the first chips."""
     import jax
     from repro.serve import ShardedSNNStreamEngine, SNNStreamEngine
-    params_q = {"layers": [{"w_q": w} for w in weights]}
+    params_q, snn = network(cfg, bench_dir).program(cfg, weights)
     data, model = cfg["mesh"]["data"], cfg["mesh"]["model"]
     if data * model == 1:
-        return SNNStreamEngine(params_q, snn_config(cfg),
+        return SNNStreamEngine(params_q, snn,
                                batch_size=cfg["lanes_per_device"],
                                patience=cfg["patience"], seed=seed,
                                dispatch_cache=False)
@@ -134,7 +134,7 @@ def build_engine(cfg: dict, weights: tuple, seed: int):
     mesh = make_2d_device_mesh(data, model,
                                devices=jax.devices()[:data * model])
     return ShardedSNNStreamEngine(
-        params_q, snn_config(cfg), mesh=mesh,
+        params_q, snn, mesh=mesh,
         lanes_per_device=cfg["lanes_per_device"], patience=cfg["patience"],
         seed=seed, overlap=cfg["overlap"], dispatch_cache=False)
 
@@ -163,8 +163,9 @@ def memory_peak(devices) -> int:
 
 
 def compare(cfg: dict, seed: int, traffic: Traffic, due: list,
-            served: dict) -> tuple[dict, int, int]:
-    """Served answers of the requests ``due`` against the reference.
+            served: dict, bench_dir: str = HERE) -> tuple[dict, int, int]:
+    """Served answers of the requests ``due`` against the reference of
+    the configuration's network.
 
     ``served`` maps rid -> (pred, steps, adds, spike counts).  Every rid
     due must have an answer; a sample of them, drawn from the seed, is
@@ -180,11 +181,11 @@ def compare(cfg: dict, seed: int, traffic: Traffic, due: list,
     fields = ("pred", "steps", "adds", "counts")
     bad = {k: np.zeros(len(sample), bool) for k in fields}
     if len(sample):
-        spec = reference.spec_of(cfg)
+        net = network(cfg, bench_dir)
         pixels = traffic.pixels[[traffic.rid_to_index[int(r)]
                                  for r in sample]]
-        ref = reference.serve(spec, reference.make_weights(cfg), pixels,
-                              seed + sample)
+        ref = net.serve(net.spec_of(cfg), net.make_weights(cfg), pixels,
+                        seed + sample)
         for i, k in enumerate(fields):
             got = np.array([served[int(r)][i] for r in sample])
             bad[k] = (got != ref[k]).reshape(len(sample), -1).any(axis=1)
@@ -228,9 +229,10 @@ def run_cell(bench: Bench, cell: str, seed: int, seconds: float,
     lanes_total = cfg["lanes_per_device"] * cfg["mesh"]["data"]
     traffic = Traffic(mix, seed, lanes_total, bench.dir)
     t_pool = time.perf_counter()
-    weights = jax.block_until_ready(reference.make_weights(cfg))
+    net = network(cfg, bench.dir)
+    weights = jax.block_until_ready(net.make_weights(cfg))
     t_weights = time.perf_counter()
-    eng = build_engine(cfg, weights, seed)
+    eng = build_engine(cfg, weights, seed, bench.dir)
     chunk_steps = int(eng.chunk_steps)
     t_build = time.perf_counter()
     traffic.warm_up(eng)
@@ -279,7 +281,8 @@ def run_cell(bench: Bench, cell: str, seed: int, seconds: float,
     del eng, results, weights
     gc.collect()
 
-    checks, compared, failed = compare(cfg, seed, traffic, due, served)
+    checks, compared, failed = compare(cfg, seed, traffic, due, served,
+                                       bench.dir)
     lat = window.lateness
     log(f"window: seconds={window.seconds:.3f} step_calls="
         f"{window.step_calls} due={len(window.due)} "
@@ -297,7 +300,8 @@ def run_cell(bench: Bench, cell: str, seed: int, seconds: float,
 
     run = Run(cell=cell, config=cfg, traffic=mix, chips=chips,
               lanes_total=lanes_total, chunk_steps=chunk_steps,
-              setup_s=setup_s, window=window, retired_steps=retired_steps,
+              setup_s=setup_s, window=window, network=net,
+              retired_steps=retired_steps,
               spec_stats=spec_stats, launches=launches, peaks=peaks)
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices), "memory_peak_bytes": mem}
@@ -306,7 +310,8 @@ def run_cell(bench: Bench, cell: str, seed: int, seconds: float,
         t = time.perf_counter()
         if dev.platform != "cpu":
             run.trace = trace_reduce.reduce(trace_reduce.from_xplane(
-                trace_dir, devices=[d.id for d in used]))
+                trace_dir, devices=[d.id for d in used],
+                spans=trace_reduce.SPANS + program_spans.names()))
             device["busy_s"] = run.trace.busy_s
             device["window_s"] = run.trace.window_s
             out["breakdown"] = trace_reduce.breakdown(run.trace)

@@ -1,8 +1,9 @@
 """The fused stack kernel's share of its roofline, in %.
 
 The least time of the window's launches (``work.py``: the larger of
-int8 operations over the int8 peak and bytes over HBM bandwidth, at the
-mean lanes in flight per chip) over the summed device time of the
+int8 operations over the int8 peak and bytes over HBM bandwidth, from
+the network's multiply-accumulates, weight bytes and lane-state bytes at
+the mean lanes in flight per chip) over the summed device time of the
 kernel's own events in the trace.  The launches are the chunks the
 engine dispatched in the window, not the events matched, so an op
 matched twice can only lower the share.
@@ -40,8 +41,9 @@ def read(run):
         return None
     devices = run.config["mesh"]["data"] * run.config["mesh"]["model"]
     lanes = run.window.busy_lanes / run.window.step_calls / devices
-    call = work.stack_call(run.config["layer_sizes"], lanes,
-                           run.chunk_steps)
+    net, cfg = run.network, run.config
+    call = work.stack_call(net.macs_per_lane_step(cfg), net.weight_bytes(cfg),
+                           net.lane_state_bytes(cfg), lanes, run.chunk_steps)
     least, bound = work.least_time_s(call, run.peaks)
     run.notes.append(
         f"stack_kernel_roofline: ops {ops}: {events} events for "

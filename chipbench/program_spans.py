@@ -8,6 +8,16 @@ the recorder's ring dropped spans inside the window.
 """
 
 
+def names() -> tuple:
+    """The names of the program's spans, or none on a tree without the
+    recorder."""
+    try:
+        from repro.serve import spans
+    except ImportError:
+        return ()
+    return tuple(spans.NAMES)
+
+
 def in_window(run) -> dict | None:
     """Span name -> the spans of that name inside ``run``'s window, or
     None if there is nothing to read."""

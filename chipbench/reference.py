@@ -1,22 +1,26 @@
-"""The benchmark's plain ``jax.numpy`` reference of one served request.
+"""The plain ``jax.numpy`` datapath that every network's reference shares.
 
 A request is a 784-pixel uint8 image and its PRNG seed.  Its window is
 the paper's integer datapath, written out step by step with no kernel,
 no lanes and no chunking:
 
     Poisson encoder   xorshift32 lane per pixel; spike iff pixel > top byte
-    LIF layer l       I = sum_i W_i * S_i (int32), clip, V - (V >> shift),
-                      fire at V >= threshold, hard reset, optional pruning
+    LIF layer l       I = the network's currents from layer l-1's spikes
+                      (int32), clip, V - (V >> shift), fire at
+                      V >= threshold, hard reset, optional pruning
     readout           count | first_spike | membrane
     stability gate    retire once the prediction repeated ``patience``
                       times after the first output spike, or at T
 
-This file imports nothing of the program under test and reads nothing it
-made: the weights are built here from the configuration's weight seed,
-and the PRNG lanes from the request's seed.  ``control_weights`` gives
-the same weights at the next precision below the configuration's (int4
-for its 8-bit weights), which is the control that the comparison must
-reject.
+What differs between networks is in ``networks/<network>.py``: the
+weights, each layer's currents from the previous layer's spikes, the
+neuron shapes and the adds.  A network's file calls :func:`serve` here
+with those.
+
+Neither this file nor a network's reference imports anything of the
+program under test or reads anything it made: the weights are built
+from the configuration's weight seed, and the PRNG lanes from the
+request's seed.  Only a network's ``program()`` imports the program.
 """
 
 from __future__ import annotations
@@ -31,10 +35,9 @@ import numpy as np
 V_PEAK_INIT = np.iinfo(np.int32).min
 
 
-class Spec(NamedTuple):
-    """The hashable part of a configuration that the window depends on."""
+class Datapath(NamedTuple):
+    """The hashable settings that every network's window shares."""
 
-    layer_sizes: tuple
     num_steps: int
     decay_shift: int
     v_threshold: int
@@ -46,52 +49,15 @@ class Spec(NamedTuple):
     patience: int
 
 
-def spec_of(cfg: dict) -> Spec:
+def datapath_of(cfg: dict) -> Datapath:
     lif = cfg["lif"]
-    return Spec(layer_sizes=tuple(cfg["layer_sizes"]),
-                num_steps=int(cfg["num_steps"]),
-                decay_shift=int(lif["decay_shift"]),
-                v_threshold=int(lif["v_threshold"]),
-                v_rest=int(lif["v_rest"]), v_min=int(lif["v_min"]),
-                v_max=int(lif["v_max"]), readout=cfg["readout"],
-                active_pruning=bool(cfg["active_pruning"]),
-                patience=int(cfg["patience"]))
-
-
-@partial(jax.jit, static_argnames=("sizes", "weight_bits"))
-def _weights(key, *, sizes: tuple, weight_bits: int):
-    lo, hi = -(1 << weight_bits), (1 << weight_bits) - 1
-    out = []
-    for fan_in, fan_out in zip(sizes[:-1], sizes[1:]):
-        key, sub = jax.random.split(key)
-        w = jax.random.normal(sub, (fan_in, fan_out), jnp.float32)
-        # codes of about N(0, 1) * 256 / sqrt(fan_in): the program's own
-        # init scale (2 / sqrt(fan_in)) times its threshold gain (128)
-        w = jnp.round(w * (256.0 / np.sqrt(fan_in)))
-        out.append(jnp.clip(w, lo, hi).astype(jnp.int16))
-    return tuple(out)
-
-
-def make_weights(cfg: dict) -> tuple:
-    """Signed ``weight_bits + 1``-bit weight codes (int16), per layer, made
-    on the device in one call from the configuration's weight seed."""
-    return _weights(jax.random.PRNGKey(int(cfg["weight_seed"])),
-                    sizes=tuple(cfg["layer_sizes"]),
-                    weight_bits=int(cfg["weight_bits"]))
-
-
-@jax.jit
-def control_weights(weights: tuple) -> tuple:
-    """The weights on a 4-bit grid at the same scale (int4 codes times a
-    per-layer step): the control's precision, one below the 8-bit codes
-    the configuration states."""
-    out = []
-    for w in weights:
-        w = w.astype(jnp.float32)
-        step = jnp.maximum(jnp.max(jnp.abs(w)) / 7.0, 1.0)
-        out.append((jnp.clip(jnp.round(w / step), -8, 7) * step)
-                   .round().astype(jnp.int16))
-    return tuple(out)
+    return Datapath(num_steps=int(cfg["num_steps"]),
+                    decay_shift=int(lif["decay_shift"]),
+                    v_threshold=int(lif["v_threshold"]),
+                    v_rest=int(lif["v_rest"]), v_min=int(lif["v_min"]),
+                    v_max=int(lif["v_max"]), readout=cfg["readout"],
+                    active_pruning=bool(cfg["active_pruning"]),
+                    patience=int(cfg["patience"]))
 
 
 def seed_state(seed: int, n: int) -> np.ndarray:
@@ -110,36 +76,41 @@ def seed_state(seed: int, n: int) -> np.ndarray:
     return np.where(state == 0, np.uint32(0x9E3779B9), state)
 
 
-def _readout(spec: Spec, counts, first, v_last, v_peak_last):
-    if spec.readout == "count":
+def _readout(dp: Datapath, counts, first, v_last, v_peak_last):
+    if dp.readout == "count":
         return jnp.argmax(counts, axis=-1)
-    if spec.readout == "membrane":
+    if dp.readout == "membrane":
         return jnp.argmax(v_peak_last, axis=-1)
-    if spec.readout == "first_spike":
+    if dp.readout == "first_spike":
         large = jnp.int32(1 << 24)
-        score = jnp.where(counts > 0, large + (spec.num_steps - first),
+        score = jnp.where(counts > 0, large + (dp.num_steps - first),
                           jnp.clip(v_last, -large + 1, large - 1))
         return jnp.argmax(score, axis=-1)
-    raise ValueError(f"unknown readout {spec.readout!r}")
+    raise ValueError(f"unknown readout {dp.readout!r}")
 
 
-@partial(jax.jit, static_argnames=("spec",))
-def _window(px, rng, weights, *, spec: Spec):
+@partial(jax.jit, static_argnames=("dp", "shapes", "layer"))
+def _window(px, rng, weights, *, dp: Datapath, shapes: tuple, layer):
+    """The window of ``px.shape[0]`` requests.  ``shapes[l]`` is layer
+    l's neuron shape (the last one a vector of classes); ``layer(l, x, w,
+    en)`` gives layer l's int32 currents from the previous layer's bool
+    spikes ``x`` (the encoder's, shape (rows, pixels), for l = 0) and its
+    weights ``w`` widened to int32, and the int32 adds of each row, with
+    ``en`` the layer's enables before the step."""
     rows = px.shape[0]
-    T = spec.num_steps
-    n_out = spec.layer_sizes[-1]
+    T = dp.num_steps
+    n_out = shapes[-1]
     px32 = px.astype(jnp.int32)
-    ws = tuple(w.astype(jnp.int32) for w in weights)
+    ws = jax.tree.map(lambda w: w.astype(jnp.int32), weights)
     zeros = jnp.zeros((rows,), jnp.int32)
     carry = dict(
         rng=rng,
-        v=tuple(jnp.full((rows, n), spec.v_rest, jnp.int32)
-                for n in spec.layer_sizes[1:]),
-        en=tuple(jnp.ones((rows, n), bool) for n in spec.layer_sizes[1:]),
-        peak=tuple(jnp.full((rows, n), V_PEAK_INIT, jnp.int32)
-                   for n in spec.layer_sizes[1:]),
-        counts=jnp.zeros((rows, n_out), jnp.int32),
-        first=jnp.full((rows, n_out), T, jnp.int32),
+        v=tuple(jnp.full((rows, *s), dp.v_rest, jnp.int32) for s in shapes),
+        en=tuple(jnp.ones((rows, *s), bool) for s in shapes),
+        peak=tuple(jnp.full((rows, *s), V_PEAK_INIT, jnp.int32)
+                   for s in shapes),
+        counts=jnp.zeros((rows, *n_out), jnp.int32),
+        first=jnp.full((rows, *n_out), T, jnp.int32),
         prev=jnp.full((rows,), -1, jnp.int32), streak=zeros, steps=zeros,
         adds=zeros, active=jnp.ones((rows,), bool))
 
@@ -151,18 +122,16 @@ def _window(px, rng, weights, *, spec: Spec):
         x = px32 > (rng >> 24).astype(jnp.int32)
         adds = zeros
         vs, ens = [], []
-        for v, en, w in zip(c["v"], c["en"], ws):
-            adds = adds + (jnp.sum(x, axis=-1, dtype=jnp.int32)
-                           * jnp.sum(en, axis=-1, dtype=jnp.int32))
-            cur = jnp.dot(x.astype(jnp.int32), w,
-                          preferred_element_type=jnp.int32)
+        for l, (v, en, w) in enumerate(zip(c["v"], c["en"], ws)):
+            cur, layer_adds = layer(l, x, w, en)
+            adds = adds + layer_adds
             cur = jnp.where(en, cur, 0)
-            vi = jnp.clip(v + cur, spec.v_min, spec.v_max)
-            vl = vi - (vi >> spec.decay_shift)
-            fired = vl >= spec.v_threshold
-            v_new = jnp.where(en, jnp.where(fired, spec.v_rest, vl), v)
+            vi = jnp.clip(v + cur, dp.v_min, dp.v_max)
+            vl = vi - (vi >> dp.decay_shift)
+            fired = vl >= dp.v_threshold
+            v_new = jnp.where(en, jnp.where(fired, dp.v_rest, vl), v)
             fired = jnp.logical_and(fired, en)
-            if spec.active_pruning:
+            if dp.active_pruning:
                 en = jnp.logical_and(en, jnp.logical_not(fired))
             vs.append(v_new)
             ens.append(en)
@@ -172,10 +141,10 @@ def _window(px, rng, weights, *, spec: Spec):
                           c["steps"][:, None], c["first"])
         peak = tuple(jnp.maximum(p, v) for p, v in zip(c["peak"], vs))
         has_spike = jnp.max(counts, axis=-1) > 0
-        pred = _readout(spec, counts, first, vs[-1], peak[-1]).astype(
+        pred = _readout(dp, counts, first, vs[-1], peak[-1]).astype(
             jnp.int32)
         streak = jnp.where(pred == c["prev"], c["streak"] + 1, 0)
-        done = jnp.logical_and(streak >= spec.patience, has_spike)
+        done = jnp.logical_and(streak >= dp.patience, has_spike)
         prev = jnp.where(has_spike, pred, -1)
         streak = jnp.where(has_spike, streak, 0)
         steps = c["steps"] + act.astype(jnp.int32)
@@ -197,27 +166,31 @@ def _window(px, rng, weights, *, spec: Spec):
             active=jnp.where(act, still, c["active"]))
 
     c = jax.lax.fori_loop(0, T, step, carry)
-    pred = _readout(spec, c["counts"], c["first"], c["v"][-1],
+    pred = _readout(dp, c["counts"], c["first"], c["v"][-1],
                     c["peak"][-1]).astype(jnp.int32)
     return pred, c["steps"], c["counts"], c["adds"]
 
 
-def serve(spec: Spec, weights: tuple, pixels: np.ndarray,
+def serve(dp: Datapath, shapes: tuple, layer, weights, pixels: np.ndarray,
           seeds: np.ndarray, block: int = 4096) -> dict:
-    """Reference results of the requests (``pixels[i]``, ``seeds[i]``),
-    computed ``block`` rows at a time.  Returns numpy arrays ``pred``
-    (R,), ``steps`` (R,), ``counts`` (R, n_out) and ``adds`` (R,)."""
-    n_in = spec.layer_sizes[0]
+    """Reference results of the requests (``pixels[i]``, ``seeds[i]``)
+    through a network given by its neuron ``shapes`` and ``layer``
+    function (see :func:`_window`), computed ``block`` rows at a time.
+    Returns numpy arrays ``pred`` (R,), ``steps`` (R,), ``counts`` (R,
+    n_out) and ``adds`` (R,)."""
+    pixels = np.asarray(pixels, np.uint8)
+    n_in = pixels.shape[1]
     parts = []
     for lo in range(0, len(pixels), block):
-        px = np.asarray(pixels[lo:lo + block], np.uint8)
+        px = pixels[lo:lo + block]
         rng = np.stack([seed_state(int(s), n_in)
                         for s in seeds[lo:lo + block]])
         pad = block - len(px) if len(pixels) > block else 0
         if pad:          # one compiled shape for every block
             px = np.concatenate([px, np.zeros((pad, n_in), np.uint8)])
             rng = np.concatenate([rng, np.ones((pad, n_in), np.uint32)])
-        out = _window(jnp.asarray(px), jnp.asarray(rng), weights, spec=spec)
+        out = _window(jnp.asarray(px), jnp.asarray(rng), weights, dp=dp,
+                      shapes=shapes, layer=layer)
         parts.append([np.asarray(a)[:block - pad] for a in out])
     keys = ("pred", "steps", "counts", "adds")
     return {k: np.concatenate([p[i] for p in parts])

@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 import harness
-import reference
+from networks import dense as reference
 from generator import Traffic, Window
 from helpers import ROOT
 

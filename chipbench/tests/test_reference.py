@@ -1,19 +1,21 @@
-"""reference.py against the program's engine at a tiny size on the CPU,
-for every readout, with and without active pruning and a hidden layer."""
+"""The dense network's reference (``networks/dense.py`` on
+``reference.py``) against the program's engine at a tiny size on the
+CPU, for every readout, with and without active pruning and a hidden
+layer."""
 
 import numpy as np
 import pytest
 
 import harness
-import reference
+from networks import dense as reference
 from generator import HERE, plugin
 
 
 def _cfg(readout, pruning, sizes):
-    return {"layer_sizes": sizes, "num_steps": 20, "weight_bits": 8,
-            "weight_seed": 5, "readout": readout, "active_pruning": pruning,
-            "patience": 2, "mesh": {"data": 1, "model": 1},
-            "lanes_per_device": 8,
+    return {"network": "dense", "layer_sizes": sizes, "num_steps": 20,
+            "weight_bits": 8, "weight_seed": 5, "readout": readout,
+            "active_pruning": pruning, "patience": 2,
+            "mesh": {"data": 1, "model": 1}, "lanes_per_device": 8,
             "lif": {"decay_shift": 4, "v_threshold": 128, "v_rest": 0,
                     "v_min": -(1 << 20), "v_max": (1 << 20) - 1}}
 

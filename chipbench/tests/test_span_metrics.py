@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 import harness
-import reference
+from networks import dense
 from generator import Traffic
 from helpers import ROOT
 
@@ -121,7 +121,7 @@ def test_tile_pairs_at_the_configurations(config, tmp_path):
     bench = harness.Bench(ROOT)
     cfg = bench.config(config)
     lanes = cfg["lanes_per_device"]
-    eng = harness.build_engine(cfg, reference.make_weights(cfg), seed=7)
+    eng = harness.build_engine(cfg, dense.make_weights(cfg), seed=7)
     traffic = Traffic(bench.traffic("digits.backlog"), 7, lanes)
     for _ in range(lanes):
         traffic._submit(eng)

@@ -3,6 +3,7 @@
 import pytest
 
 import trace_reduce as tr
+from networks import dense
 
 
 def test_union_merges_overlaps_and_drops_empty():
@@ -33,13 +34,46 @@ def test_busy_kernel_and_gaps():
     assert calls == 3 and ops == ["_stack_kernel.1"]
     assert secs == pytest.approx((20 + 10 + 100) * 1e-9)
     # device 0 idle: [0,10) in gen.submit, [40,60) in engine.step,
-    # [70,100) midpoint 85 in bench.record; device 1 never idle
+    # [70,100) cut at 80: [70,80) in engine.step, [80,100) in
+    # bench.record; device 1 never idle
     assert s.idle_by_span == pytest.approx(
-        {"gen.submit": 5e-9, "engine.step": 10e-9, "bench.record": 15e-9})
+        {"gen.submit": 5e-9, "engine.step": 15e-9, "bench.record": 10e-9})
     b = tr.breakdown(s)
     assert b["device_ops"][0][0] == "_stack_kernel.1"
     assert [g[0] for g in b["idle_gaps"]] == [
-        "bench.record", "engine.step", "gen.submit"]
+        "engine.step", "bench.record", "gen.submit"]
+
+
+def test_program_spans_take_the_gap_and_leave_the_window():
+    # engine.step [20, 80) holds the program's snn.step [22, 78), which
+    # holds snn.readback [25, 45) and snn.upload [50, 70); a program span
+    # that reaches past the harness's spans does not widen the window
+    spans = [("gen.submit", 0, 20), ("engine.step", 20, 80),
+             ("bench.record", 80, 100), ("snn.step", 22, 78),
+             ("snn.readback", 25, 45), ("snn.upload", 50, 70),
+             ("snn.dispatch", 95, 130)]
+    devices = {"/device:TPU:0": [("op", 0, 21), ("op", 40, 52),
+                                 ("op", 75, 100)]}
+    s = tr.reduce(tr.Trace(devices=devices, spans=spans))
+    bare = tr.reduce(tr.Trace(devices=devices, spans=spans[:3]))
+    assert s.window_s == bare.window_s == pytest.approx(100e-9)
+    assert s.busy_s == bare.busy_s == pytest.approx(58e-9)
+    # gap [21, 40): engine.step [21,22), snn.step [22,25), snn.readback
+    # [25,40); gap [52, 75): snn.upload [52,70), snn.step [70,75)
+    assert s.idle_by_span == pytest.approx(
+        {"engine.step": 1e-9, "snn.step": 8e-9, "snn.readback": 15e-9,
+         "snn.upload": 18e-9})
+    assert bare.idle_by_span == pytest.approx({"engine.step": 42e-9})
+    assert [g[0] for g in tr.breakdown(s)["idle_gaps"]][:2] == [
+        "snn.upload", "snn.readback"]
+
+
+def test_spans_that_start_together_give_the_gap_to_the_inner():
+    t = tr.Trace(devices={"/device:TPU:0": [("op", 0, 10), ("op", 30, 40)]},
+                 spans=[("snn.sync", 10, 20), ("engine.step", 0, 40),
+                        ("snn.step", 10, 30)])
+    assert tr.reduce(t).idle_by_span == pytest.approx(
+        {"snn.sync": 10e-9, "snn.step": 10e-9})
 
 
 def test_gap_outside_every_span_is_none():
@@ -115,7 +149,7 @@ def test_roofline_counts_launches_not_events():
         trace=t, peaks={"int8_ops_per_s": 393e12, "hbm_bytes_per_s": 819e9},
         window=SimpleNamespace(step_calls=2, busy_lanes=128), launches=2,
         config={"mesh": {"data": 1, "model": 1}, "layer_sizes": [784, 10]},
-        chunk_steps=4, notes=[])
+        network=dense, chunk_steps=4, notes=[])
     # least time of a 64-lane paper launch: 541,888 B at 819 GB/s; the
     # kernel's own 80 ns, not the packing's or the slice's
     least = 2 * 7840 / 819e9 + 2 * 64 * 4111 / 819e9

@@ -1,28 +1,38 @@
-"""work.py against counts made by hand."""
+"""work.py and the dense network's work counts against counts made by
+hand."""
 
 import pytest
 
 import work
+from networks import dense
+
+
+def _call(cfg, lanes_busy, chunk_steps):
+    return work.stack_call(dense.macs_per_lane_step(cfg),
+                           dense.weight_bytes(cfg),
+                           dense.lane_state_bytes(cfg), lanes_busy,
+                           chunk_steps)
 
 
 def test_paper_stack_by_hand():
-    sizes = [784, 10]
-    assert work.synapses(sizes) == 7840
+    cfg = {"layer_sizes": [784, 10]}
+    assert dense.macs_per_lane_step(cfg) == 7840
+    assert dense.weight_bytes(cfg) == 2 * 7840
     # 784 px * (1 B pixel + 4 B PRNG) + 10 neurons * 9 B + 10 classes * 8 B
     # + five int32 scalars + the active flag
-    assert work.lane_state_bytes(sizes) == 3920 + 90 + 80 + 21
-    c = work.stack_call(sizes, lanes_busy=64, chunk_steps=4)
+    assert dense.lane_state_bytes(cfg) == 3920 + 90 + 80 + 21
+    c = _call(cfg, lanes_busy=64, chunk_steps=4)
     assert c["ops"] == 2 * 64 * 7840 * 4
     assert c["bytes"] == 2 * 7840 + 2 * 64 * 4111
 
 
 def test_wide_stack_by_hand():
-    sizes = [784, 2048, 2048, 10]
+    cfg = {"layer_sizes": [784, 2048, 2048, 10]}
     syn = 784 * 2048 + 2048 * 2048 + 2048 * 10
-    assert work.synapses(sizes) == syn == 5_820_416
+    assert dense.macs_per_lane_step(cfg) == syn == 5_820_416
     lane = 784 * 5 + (2048 + 2048 + 10) * 9 + 10 * 8 + 21
-    assert work.lane_state_bytes(sizes) == lane
-    c = work.stack_call(sizes, lanes_busy=32, chunk_steps=4)
+    assert dense.lane_state_bytes(cfg) == lane
+    c = _call(cfg, lanes_busy=32, chunk_steps=4)
     assert c["ops"] == 2 * 32 * syn * 4
     assert c["bytes"] == 2 * syn + 2 * 32 * lane
 

@@ -4,21 +4,26 @@ The reduction works on plain interval lists, so it can be checked on a
 synthetic trace: each device is a list of ``(op name, start ns, end ns)``
 events, the HLO text that the profiler attached to each op name is in
 ``op_text``, and the harness's own host spans are a list of ``(span
-name, start ns, end ns)``.  ``from_xplane`` fills them from the
-``.xplane.pb`` file that ``jax.profiler`` writes.
+name, start ns, end ns)``, to which the program's own spans may be
+added (``repro.serve.spans.NAMES``, nested inside ``engine.step``).
+``from_xplane`` fills them from the ``.xplane.pb`` file that
+``jax.profiler`` writes.
 
-- The window is the first harness span's start to the last one's end.
+- The window is the first harness span's start to the last one's end
+  (``SPANS`` only: the program's spans never move it).
 - Busy time of a device is the length of the union of its op intervals,
   clipped to the window; ``busy_s`` is its mean over the devices.
-- An idle gap is a stretch of the window that no op covers.  Each gap is
-  put down to the innermost harness span open at its midpoint ("none"
-  when no span is open).
+- An idle gap is a stretch of the window that no op covers.  A gap is
+  cut at every span's start and end inside it, and each piece is put
+  down to the innermost span open over it, the program's included
+  ("none" when no span is open).
 """
 
 from __future__ import annotations
 
 import bisect
 import glob
+import itertools
 import os
 import re
 from typing import NamedTuple
@@ -71,26 +76,46 @@ def _gaps(merged, lo, hi) -> list:
     return gaps
 
 
-def _span_at(spans, starts, t) -> str:
-    """Innermost span open at ``t``: the latest-starting one covering it
-    (``spans`` sorted by start, ``starts`` their start times)."""
-    i = bisect.bisect_right(starts, t) - 1
-    for j in range(i, max(i - 8, -1), -1):
-        name, s, e = spans[j]
-        if s <= t < e:
-            return name
-    return "none"
+class _Spans:
+    """Spans sorted by start, the outer of two that start together
+    first, for the innermost one open at a time."""
+
+    def __init__(self, spans):
+        self.spans = sorted(spans, key=lambda x: (x[1], -x[2]))
+        self.starts = [s for _, s, _ in self.spans]
+        # the latest end among the spans up to each one
+        self.reach = list(itertools.accumulate(
+            (e for _, _, e in self.spans), max))
+        self.edges = sorted({t for _, s, e in self.spans for t in (s, e)})
+
+    def at(self, t) -> str:
+        """The innermost span open at ``t``: the latest-starting one that
+        covers it."""
+        j = bisect.bisect_right(self.starts, t) - 1
+        while j >= 0 and self.reach[j] > t:
+            name, s, e = self.spans[j]
+            if s <= t < e:
+                return name
+            j -= 1
+        return "none"
+
+    def pieces(self, lo, hi):
+        """``(lo, hi)`` cut at every span edge inside it."""
+        i = bisect.bisect_right(self.edges, lo)
+        k = bisect.bisect_left(self.edges, hi)
+        cuts = [lo] + self.edges[i:k] + [hi]
+        return zip(cuts[:-1], cuts[1:])
 
 
 def reduce(trace: Trace) -> Summary:
-    if not trace.spans:
+    own = [sp for sp in trace.spans if sp[0] in SPANS]
+    if not own:
         raise ValueError("the trace holds none of the harness's spans")
     if not trace.devices:
         raise ValueError("the trace holds no device plane")
-    lo = min(s for _, s, _ in trace.spans)
-    hi = max(e for _, _, e in trace.spans)
-    spans = sorted(trace.spans, key=lambda x: x[1])
-    starts = [s for _, s, _ in spans]
+    lo = min(s for _, s, _ in own)
+    hi = max(e for _, _, e in own)
+    spans = _Spans(trace.spans)
     n = len(trace.devices)
     busy = 0.0
     op_s: dict = {}
@@ -104,9 +129,10 @@ def reduce(trace: Trace) -> Summary:
         for name, s, e in clipped:
             op_s[name] = op_s.get(name, 0.0) + (e - s)
             op_calls[name] = op_calls.get(name, 0) + 1
-        for s, e in _gaps(merged, lo, hi):
-            who = _span_at(spans, starts, (s + e) / 2)
-            idle[who] = idle.get(who, 0.0) + (e - s)
+        for gap in _gaps(merged, lo, hi):
+            for s, e in spans.pieces(*gap):
+                who = spans.at((s + e) / 2)
+                idle[who] = idle.get(who, 0.0) + (e - s)
     return Summary(window_s=(hi - lo) * 1e-9, busy_s=busy / n * 1e-9,
                    n_devices=n,
                    op_s={k: v / n * 1e-9 for k, v in op_s.items()},
@@ -144,7 +170,7 @@ def from_xplane(log_dir: str, devices, spans=SPANS) -> Trace:
     """Read the ``.xplane.pb`` that ``jax.profiler`` wrote under
     ``log_dir``: the op events of the accelerator planes of ``devices``
     (JAX device ids; the chips the cell uses, not every chip the host
-    holds), and the harness's spans from the host planes."""
+    holds), and the host spans named in ``spans`` from the host planes."""
     from jax.profiler import ProfileData
     files = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
                       recursive=True)

@@ -1,4 +1,6 @@
-"""Operations and bytes of one call, from shapes and configuration alone.
+"""Operations and bytes of one call, from counts of the network's work
+(``networks/<network>.py``: multiply-accumulates a lane step, weight
+bytes, lane-state bytes), and the chip's peaks.
 
 The counts are the work the algorithm needs, not what an implementation
 happens to do: no tile skipping, packing or padding enters them, so they
@@ -10,38 +12,18 @@ from __future__ import annotations
 import json
 import os
 
-WEIGHT_CODE_BYTES = 2        # a signed 9-bit code held in 2 bytes
 
-
-def synapses(layer_sizes) -> int:
-    """sum_l K_l * N_l: multiply-accumulates of one lane in one step."""
-    return sum(int(k) * int(n) for k, n in zip(layer_sizes[:-1],
-                                               layer_sizes[1:]))
-
-
-def lane_state_bytes(layer_sizes) -> int:
-    """Bytes of one lane's carried window state at unpadded widths: uint8
-    pixels and uint32 PRNG lanes per input; per neuron an int32 membrane,
-    an int32 running peak and a bool enable; int32 spike counts and
-    first-spike times per class; int32 gate memory, streak, steps, adds
-    and weight version, and a bool active flag."""
-    n_in, n_out = int(layer_sizes[0]), int(layer_sizes[-1])
-    neurons = sum(int(n) for n in layer_sizes[1:])
-    return n_in * (1 + 4) + neurons * (4 + 4 + 1) + n_out * (4 + 4) \
-        + 5 * 4 + 1
-
-
-def stack_call(layer_sizes, lanes_busy: float, chunk_steps: int) -> dict:
+def stack_call(macs: int, weight_bytes: int, lane_bytes: int,
+               lanes_busy: float, chunk_steps: int) -> dict:
     """One launch of the fused stack kernel over ``chunk_steps`` steps.
 
-    Operations: 2 x lanes busy x sum K*N x chunk steps (a multiply and an
-    add per synapse).  Bytes: one read of every weight code at 2 B, and
-    the busy lanes' state read and written once.
+    Operations: 2 x lanes busy x ``macs`` (one lane's multiply-accumulates
+    a step) x chunk steps (a multiply and an add each).  Bytes: one read
+    of every weight code, and the busy lanes' state (``lane_bytes`` each)
+    read and written once.
     """
-    syn = synapses(layer_sizes)
-    return {"ops": 2.0 * lanes_busy * syn * chunk_steps,
-            "bytes": float(WEIGHT_CODE_BYTES * syn
-                           + 2 * lanes_busy * lane_state_bytes(layer_sizes))}
+    return {"ops": 2.0 * lanes_busy * macs * chunk_steps,
+            "bytes": float(weight_bytes + 2 * lanes_busy * lane_bytes)}
 
 
 def least_time_s(call: dict, peak: dict) -> tuple[float, str]:
