@@ -4,8 +4,9 @@
     python chip_smoke.py --chips 4    # the four-chip mesh phase only
 
 One process.  Weights come from a seed: on one chip the paper config
-(784→10) is trained briefly on the procedural digits; DEEP, WIDE and the
-four-chip phase quantize seeded random weights.  No weight file, dataset
+(784→10) is trained briefly on the procedural digits; DEEP, WIDE, the
+convolutional 12C5-MP2-64C5-MP2-1024FC10 and the four-chip phase quantize
+seeded random weights.  No weight file, dataset
 path or dispatch cache is read.
 
 Each single-chip phase serves a few hundred digit requests through
@@ -44,8 +45,9 @@ import numpy as np  # noqa: E402
 from jax.experimental import pallas as pl  # noqa: E402
 
 from repro.compile_cache import enable_compile_cache  # noqa: E402
-from repro.configs.snn_mnist import (SNN_CONFIG, SNN_CONFIG_DEEP,  # noqa: E402
-                                     SNN_CONFIG_PRUNED, SNN_CONFIG_WIDE)
+from repro.configs.snn_mnist import (SNN_CONFIG, SNN_CONFIG_CONV,  # noqa: E402
+                                     SNN_CONFIG_DEEP, SNN_CONFIG_PRUNED,
+                                     SNN_CONFIG_WIDE)
 from repro.core import prng, snn  # noqa: E402
 from repro.core.train_snn import train_bptt  # noqa: E402
 from repro.data import digits  # noqa: E402
@@ -206,6 +208,10 @@ def single_chip(ds) -> None:
     wide_q = quantized(SNN_CONFIG_WIDE)
     serve_phase("wide", wide_q, SNN_CONFIG_WIDE, "fused_streamed", imgs)
     one_shot("wide", wide_q, SNN_CONFIG_WIDE, "fused_streamed", imgs)
+
+    conv_q = quantized(SNN_CONFIG_CONV)
+    serve_phase("conv", conv_q, SNN_CONFIG_CONV, "fused", imgs)
+    one_shot("conv", conv_q, SNN_CONFIG_CONV, "fused", imgs)
 
 
 def _devices_of(tree) -> set:

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
+from ..core.layers import ConvPool, Dense
 from ..core.lif import LIFConfig
 from ..core.snn import SNNConfig
 from .base import ArchConfig
@@ -322,6 +323,28 @@ SNN_CONFIG_WIDE = SNNConfig(
     lif=LIFConfig(decay_shift=4, v_threshold=128, v_rest=0),
     weight_bits=8,
     qat=True,
+    readout="count",
+    active_pruning=False,
+    backend="auto",
+)
+
+# The convolutional SNN 12C5-MP2-64C5-MP2-1024FC10 (Eshraghian et al.,
+# "Training Spiking Neural Networks Using Lessons From Deep Learning",
+# Proc. IEEE 2023; snnTorch tutorial 6) on the paper's integer LIF
+# datapath: 28x28 pixels, a 5x5 conv to 12 channels and a 2x2 max-pool of
+# the currents (12x12x12 LIF), a 5x5 conv to 64 and a 2x2 max-pool
+# (64x4x4 LIF), a fully connected 1024->10 LIF layer.  Bias-free, as the
+# paper's datapath.  1,411,840 synaptic MACs a lane step over 29,740
+# weight codes; resident in the fused stack kernel (layer_sizes derive to
+# 784-1728-1024-10).
+SNN_CONFIG_CONV = SNNConfig(
+    layers=(ConvPool(c_out=12, in_shape=(1, 28, 28), kernel=5, pool=2),
+            ConvPool(c_out=64, in_shape=(12, 12, 12), kernel=5, pool=2),
+            Dense(10)),
+    num_steps=20,
+    lif=LIFConfig(decay_shift=4, v_threshold=128, v_rest=0),
+    weight_bits=8,
+    qat=False,
     readout="count",
     active_pruning=False,
     backend="auto",

@@ -20,6 +20,7 @@ Weights layout: ``params = {"layers": [{"w": (n_in, n_out)}, ...]}``.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import NamedTuple
 
@@ -27,6 +28,9 @@ import jax
 import jax.numpy as jnp
 
 from . import encoding, fixed_point, lif, pruning
+from .layers import (ConvPool, conv_pool_adds, conv_pool_current,
+                     dense_layers, has_conv, stack_sizes, validate_stack,
+                     weight_shapes)
 from .telemetry import (ChunkTelemetry, layer_tile_skips, resolve_sparse_skip)
 
 __all__ = [
@@ -95,6 +99,30 @@ class SNNConfig:
                                                # (prediction-only serving)
     # Float-threshold used during training; the int path scales it (below).
     train_threshold: float = 1.0
+    # Layer descriptors (core.layers): None is the dense stack
+    # ``layer_sizes``.  Given, they fix ``layer_sizes`` (the flat neuron
+    # counts, input first: the first conv layer's map, or
+    # ``layer_sizes[0]`` for a stack that starts dense); an all-dense list
+    # normalises to None, so it builds the same config as ``layer_sizes``.
+    layers: tuple | None = None
+
+    def __post_init__(self):
+        if self.layers is None:
+            return
+        layers = tuple(self.layers)
+        first = layers[0] if layers else None
+        n_in = (math.prod(first.in_shape) if isinstance(first, ConvPool)
+                else self.layer_sizes[0])
+        validate_stack(n_in, layers)
+        object.__setattr__(self, "layer_sizes", stack_sizes(n_in, layers))
+        object.__setattr__(self, "layers", layers if has_conv(layers)
+                           else None)
+
+    @property
+    def layer_descs(self) -> tuple:
+        """One descriptor per LIF layer (dense ones for a plain stack)."""
+        return (self.layers if self.layers is not None
+                else dense_layers(self.layer_sizes))
 
     @property
     def n_in(self) -> int:
@@ -107,11 +135,12 @@ class SNNConfig:
 
 def snn_init(key: jax.Array, cfg: SNNConfig) -> dict:
     layers = []
-    sizes = cfg.layer_sizes
-    for i, (fan_in, fan_out) in enumerate(zip(sizes[:-1], sizes[1:])):
+    for shape in weight_shapes(cfg.n_in, cfg.layer_descs):
         key, sub = jax.random.split(key)
-        # LeCun-style init scaled for spiking inputs (rate ≲ 0.5).
-        w = jax.random.normal(sub, (fan_in, fan_out), jnp.float32)
+        # LeCun-style init scaled for spiking inputs (rate ≲ 0.5); a conv
+        # kernel's fan-in is C_in * k * k.
+        fan_in = math.prod(shape[1:]) if len(shape) == 4 else shape[0]
+        w = jax.random.normal(sub, shape, jnp.float32)
         w = w * (2.0 / jnp.sqrt(fan_in))
         layers.append({"w": w})
     return {"layers": layers}
@@ -133,6 +162,9 @@ def snn_apply_float(params: dict, pixels01: jax.Array, key: jax.Array,
     Returns dict(rates=(batch, n_classes) mean firing rates,
                  spikes=(T, batch, n_classes)).
     """
+    if cfg.layers is not None:
+        raise ValueError("float training runs dense stacks only; a "
+                         "conv-pool stack serves quantized codes")
     spikes = encoding.poisson_encode_jax(pixels01, key, cfg.num_steps)
     tcfg = _train_lif_cfg(cfg)
     for layer in params["layers"]:
@@ -207,6 +239,14 @@ def fused_unsupported_reason(cfg: SNNConfig, n_layers: int,
         return "the network has no layers"
     if model_shards < 1:
         return f"model_shards={model_shards} is not a positive shard count"
+    if cfg.layers is not None:
+        if streamed:
+            return ("conv-pool layers run only in the resident stack "
+                    "kernel: fused_streamed streams dense weight slabs")
+        if model_shards > 1:
+            return (f"conv-pool layers cannot split over a "
+                    f"{model_shards}-way model axis: a conv map's neurons "
+                    f"share every input row")
     sizes = layer_sizes
     if sizes is None and len(cfg.layer_sizes) - 1 == n_layers:
         sizes = cfg.layer_sizes
@@ -217,7 +257,7 @@ def fused_unsupported_reason(cfg: SNNConfig, n_layers: int,
         (fused_snn.block_b_for(local_batch) if block_b is None
          else int(block_b)),
         cfg.num_steps if trace_steps is None else trace_steps,
-        streamed=streamed, model_shards=model_shards)
+        streamed=streamed, model_shards=model_shards, layers=cfg.layers)
     if need > fused_snn.VMEM_BUDGET_BYTES:
         kind = "streamed working set" if streamed else \
             "resident stack footprint"
@@ -306,10 +346,17 @@ def resolve_backend(cfg: SNNConfig, backend: str | None = None,
             b = "reference"
         elif reason is None:
             b = "fused"
+        elif cfg.layers is not None:
+            b = "reference"               # no staged or streamed conv path
         elif streamed_reason() is None:
             b = "fused_streamed"
         else:
             b = "staged"
+    if b == "staged" and cfg.layers is not None:
+        raise ValueError(
+            "backend='staged' cannot run conv-pool layers: the staged "
+            "kernels contract dense (n_in, n_out) weights — use 'fused' or "
+            "'reference'")
     if b == "fused" and reason is not None:
         raise ValueError(
             f"backend='fused' was explicitly requested but the fused "
@@ -388,7 +435,7 @@ def snn_apply_int(params_q: dict, pixels_u8: jax.Array, prng_state: jax.Array,
     when ``cfg.emit_trace`` is off.
     """
     b = resolve_backend(cfg, backend, len(params_q["layers"]),
-                        layer_sizes=_param_sizes(params_q))
+                        layer_sizes=_stack_sizes(params_q, cfg))
     if b in ("fused", "fused_streamed"):
         res = _apply_int_fused(params_q, pixels_u8, prng_state, cfg,
                                streamed=(b == "fused_streamed"))
@@ -411,6 +458,26 @@ def _param_sizes(params_q: dict) -> tuple[int, ...]:
                  + [l["w_q"].shape[1] for l in params_q["layers"]])
 
 
+def _stack_sizes(params_q: dict, cfg: SNNConfig) -> tuple[int, ...]:
+    """Flat neuron counts of the stack: from the weights of a dense stack
+    (as ever), from the descriptors of a conv-pool one, whose weight
+    shapes are checked against them."""
+    if cfg.layers is None:
+        return _param_sizes(params_q)
+    check_weight_shapes(cfg, tuple(l["w_q"] for l in params_q["layers"]))
+    return cfg.layer_sizes
+
+
+def check_weight_shapes(cfg: SNNConfig, weights) -> None:
+    """Raise unless ``weights`` have the shapes ``cfg``'s descriptors
+    give (a conv-pool stack; a dense one reads its sizes off them)."""
+    want = weight_shapes(cfg.n_in, cfg.layer_descs)
+    got = tuple(tuple(w.shape) for w in weights)
+    if got != want:
+        raise ValueError(f"weights of shapes {got} do not fit the layers "
+                         f"{cfg.layer_descs}: they need {want}")
+
+
 def _apply_int_fused(params_q, pixels_u8, prng_state, cfg: SNNConfig, *,
                      streamed: bool = False):
     """Fused Pallas megakernel: the whole window, all layers, one launch
@@ -425,7 +492,7 @@ def _apply_int_fused(params_q, pixels_u8, prng_state, cfg: SNNConfig, *,
         v_threshold=cfg.lif.v_threshold, v_rest=cfg.lif.v_rest,
         v_min=cfg.lif.v_min, v_max=cfg.lif.v_max,
         active_pruning=cfg.active_pruning,
-        sparse_skip=cfg.sparse_skip, streamed=streamed)
+        sparse_skip=cfg.sparse_skip, streamed=streamed, layers=cfg.layers)
     return {
         "spike_counts": k["spike_counts"],
         "v_trace": k["v_trace"],
@@ -474,6 +541,8 @@ def _derive_stack_telemetry(layer_ins, layer_outs, layer_vtr,
 def _apply_int_staged(params_q, pixels_u8, prng_state, cfg: SNNConfig):
     """Staged Pallas kernels: encoder launch + one LIF launch per layer."""
     from ..kernels import ops
+    if cfg.layers is not None:
+        raise ValueError("the staged kernels cannot run conv-pool layers")
     ops.validate_weight_codes(
         tuple(layer["w_q"] for layer in params_q["layers"]))
     spikes, prng_next = ops.poisson_encode_op(
@@ -514,6 +583,9 @@ def _apply_int_staged(params_q, pixels_u8, prng_state, cfg: SNNConfig):
 
 def _apply_int_reference(params_q, pixels_u8, prng_state, cfg: SNNConfig):
     """Pure-jnp scans (the original engine), incl. the fuse_encoder path."""
+    if cfg.layers is not None:
+        return _apply_int_reference_conv(params_q, pixels_u8, prng_state,
+                                         cfg)
     if cfg.fuse_encoder and len(params_q["layers"]) == 1:
         # single fused scan: xorshift -> compare -> ΣW·S -> LIF, per step —
         # the (T, B, n_in) spike train never round-trips through memory
@@ -566,28 +638,66 @@ def _apply_int_reference(params_q, pixels_u8, prng_state, cfg: SNNConfig):
     }
 
 
-def encode_lif_timestep(rng: jax.Array, pixels_u8: jax.Array,
-                        state: lif.LIFStateInt, w_q: jax.Array,
-                        lif_cfg: lif.LIFConfig, *, dot_impl: str = "int32",
-                        active_pruning: bool = False):
-    """One fused encoder+LIF timestep: PRNG step → spike compare → Σ W·S →
-    integrate/leak/fire/reset → pruning gate.
+def _apply_int_reference_conv(params_q, pixels_u8, prng_state,
+                              cfg: SNNConfig):
+    """The reference of a conv-pool stack: one whole-window chunk of the
+    jnp stack step from a fresh window (``snn_window_chunk``)."""
+    st, chunk = snn_window_chunk(
+        params_q, pixels_u8, snn_window_init(params_q, prng_state, cfg),
+        cfg, chunk_steps=cfg.num_steps, backend="reference")
+    spikes, _ = encoding.poisson_encode_hw(pixels_u8, prng_state,
+                                           cfg.num_steps)
+    return {
+        "spike_counts": st.counts,
+        "v_trace": chunk["v_trace"],
+        "v_final": st.v[-1],
+        "active_adds": chunk["active_adds"],
+        "input_spikes": spikes,
+        "first_spike_t": st.first,
+        "prng_state": st.rng,
+        "v_peak": st.v_peak,
+        "telemetry": chunk["telemetry"],
+    }
 
-    The single source of truth for the per-step datapath shared by the
-    jnp fused scan below and the streaming engine's window chunk
-    (serve.snn_engine.stream_chunk) — both must stay bit-identical to the
-    staged pipeline.  Returns (rng, new_state, fired, input_spikes).
-    """
-    from . import prng as prng_mod
-    rng = prng_mod.xorshift32_step(rng)
-    s_t = pixels_u8 > prng_mod.uniform_u8(rng)
-    current = lif.synaptic_current_int(s_t, w_q, dot_impl)
+
+def layer_lif_step(x: jax.Array, state: lif.LIFStateInt, w_q: jax.Array,
+                   layer, lif_cfg: lif.LIFConfig, *, dot_impl: str = "int32",
+                   active_pruning: bool = False):
+    """One LIF layer's timestep from the previous layer's spikes ``x``:
+    Σ W·S (a conv-pool layer: :func:`conv_pool_current`) → integrate/leak/
+    fire/reset → pruning gate.  ``layer`` is the layer's descriptor (None
+    or ``Dense``: dense codes).  Returns (new_state, fired)."""
+    if isinstance(layer, ConvPool):
+        current = conv_pool_current(x, w_q, layer, dot_impl)
+    else:
+        current = lif.synaptic_current_int(x, w_q, dot_impl)
     current = jnp.where(state.enable, current, 0)
     new_state, fired = lif.lif_step_int(state, current, lif_cfg)
     if active_pruning:
         new_state = new_state._replace(
             enable=jnp.logical_and(new_state.enable,
                                    jnp.logical_not(fired)))
+    return new_state, fired
+
+
+def encode_lif_timestep(rng: jax.Array, pixels_u8: jax.Array,
+                        state: lif.LIFStateInt, w_q: jax.Array,
+                        lif_cfg: lif.LIFConfig, *, dot_impl: str = "int32",
+                        active_pruning: bool = False):
+    """One fused encoder+LIF timestep: PRNG step → spike compare →
+    :func:`layer_lif_step` of a dense layer.
+
+    The per-step datapath of the jnp fused scan below; the streaming
+    engine's window chunk (:func:`snn_int_stack_step`) runs the same
+    encoder and layer step, and both must stay bit-identical to the
+    staged pipeline.  Returns (rng, new_state, fired, input_spikes).
+    """
+    from . import prng as prng_mod
+    rng = prng_mod.xorshift32_step(rng)
+    s_t = pixels_u8 > prng_mod.uniform_u8(rng)
+    new_state, fired = layer_lif_step(s_t, state, w_q, None, lif_cfg,
+                                      dot_impl=dot_impl,
+                                      active_pruning=active_pruning)
     return rng, new_state, fired, s_t
 
 
@@ -625,44 +735,44 @@ def snn_int_stack_step(rng: jax.Array, pixels_u8: jax.Array,
                        states: tuple, weights: tuple,
                        lif_cfg: lif.LIFConfig, *, dot_impl: str = "int32",
                        active_pruning: bool = False,
-                       sparse_skip: bool | None = None):
+                       sparse_skip: bool | None = None,
+                       layers: tuple | None = None):
     """One fused timestep through the WHOLE layer stack.
 
-    Layer 0 runs :func:`encode_lif_timestep` (the encoder+LIF single source
-    of truth); deeper layers feed each fired vector straight into the next
-    Σ W·S — the jnp mirror of the multi-layer megakernel's static layer
-    loop.  Returns ``(rng, new_states, fired_out, adds, tel)`` where
+    The encoder, then :func:`layer_lif_step` per layer, each fired vector
+    fed straight into the next Σ W·S — the jnp mirror of the multi-layer
+    megakernel's static layer loop.  Returns ``(rng, new_states, fired_out, adds, tel)`` where
     ``adds`` is the executed-add count summed over layers (energy side
     channel) and ``tel`` is this step's telemetry row — ``n_spk``/``n_en``
     (L, B) i32 and ``tiles`` (L, n_blocks) i32, the jnp mirror of the
     megakernel's side channel (``sparse_skip`` resolves the same
     REPRO_SPARSE_SKIP env rule, so the tile counter matches the kernel's
-    under the CI forcing).
+    under the CI forcing).  ``layers`` (``SNNConfig.layers``) runs a
+    stack with conv-pool layers (flat (C, H, W) neuron rows): such a layer
+    takes its adds from ``conv_pool_adds`` and its tile mirror at the
+    kernel's conv geometry.
     """
+    from . import prng as prng_mod
+    from ..kernels.fused_snn import stack_geometry
     ss = resolve_sparse_skip(sparse_skip)
-    rng, st0, fired, s_t = encode_lif_timestep(
-        rng, pixels_u8, states[0], weights[0], lif_cfg, dot_impl=dot_impl,
-        active_pruning=active_pruning)
-    n_spk = [jnp.sum(s_t.astype(jnp.int32), axis=-1)]
-    n_en = [jnp.sum(states[0].enable.astype(jnp.int32), axis=-1)]
-    tiles = [layer_tile_skips(s_t, states[0].enable, sparse_skip=ss)]
-    adds = n_spk[0] * n_en[0]
-    new_states = [st0]
-    x = fired
-    for st, layer_w in zip(states[1:], weights[1:]):
+    descs = layers if layers is not None else (None,) * len(weights)
+    geoms = (stack_geometry(pixels_u8.shape[-1], layers)
+             or (None,) * len(weights))
+    rng = prng_mod.xorshift32_step(rng)
+    x = pixels_u8 > prng_mod.uniform_u8(rng)
+    n_spk, n_en, tiles, new_states = [], [], [], []
+    adds = jnp.zeros(pixels_u8.shape[:-1], jnp.int32)
+    for st, w, d, g in zip(states, weights, descs, geoms):
         n_spk.append(jnp.sum(x.astype(jnp.int32), axis=-1))
         n_en.append(jnp.sum(st.enable.astype(jnp.int32), axis=-1))
-        tiles.append(layer_tile_skips(x, st.enable, sparse_skip=ss))
-        current = lif.synaptic_current_int(x, layer_w, dot_impl)
-        current = jnp.where(st.enable, current, 0)
-        new_st, fired = lif.lif_step_int(st, current, lif_cfg)
-        adds = adds + n_spk[-1] * n_en[-1]
-        if active_pruning:
-            new_st = new_st._replace(
-                enable=jnp.logical_and(new_st.enable,
-                                       jnp.logical_not(fired)))
+        tiles.append(layer_tile_skips(x, st.enable, sparse_skip=ss, geom=g))
+        if isinstance(d, ConvPool):
+            adds = adds + conv_pool_adds(x, st.enable, d)
+        else:
+            adds = adds + n_spk[-1] * n_en[-1]
+        new_st, x = layer_lif_step(x, st, w, d, lif_cfg, dot_impl=dot_impl,
+                                   active_pruning=active_pruning)
         new_states.append(new_st)
-        x = fired
     tel = {"n_spk": jnp.stack(n_spk), "n_en": jnp.stack(n_en),
            "tiles": jnp.stack(tiles)}
     return rng, tuple(new_states), x, adds, tel
@@ -780,7 +890,7 @@ def snn_window_init(params_q: dict, prng_state: jax.Array,
                     cfg: SNNConfig) -> SNNWindowState:
     """Fresh start-of-window state for a batch of ``prng_state.shape[0]``."""
     batch = prng_state.shape[0]
-    sizes = _param_sizes(params_q)
+    sizes = _stack_sizes(params_q, cfg)
     return SNNWindowState(
         rng=prng_state,
         v=tuple(jnp.full((batch, n), cfg.lif.v_rest, jnp.int32)
@@ -818,7 +928,7 @@ def snn_window_chunk(params_q: dict, pixels_u8: jax.Array,
                          "'fused_streamed' and 'reference' backends only "
                          "(the staged kernels cannot resume mid-window)")
     b = resolve_backend(cfg, backend, len(weights),
-                        layer_sizes=_param_sizes(params_q),
+                        layer_sizes=_stack_sizes(params_q, cfg),
                         trace_steps=chunk_steps)
     if b == "staged":                      # auto picked it; we can't run it
         b = "reference"
@@ -832,7 +942,7 @@ def snn_window_chunk(params_q: dict, pixels_u8: jax.Array,
             v_min=cfg.lif.v_min, v_max=cfg.lif.v_max,
             active_pruning=cfg.active_pruning,
             sparse_skip=cfg.sparse_skip,
-            streamed=(b == "fused_streamed"),
+            streamed=(b == "fused_streamed"), layers=cfg.layers,
             init={"v": state.v, "en": state.en, "v_peak": state.v_peak,
                   "counts": state.counts, "first": state.first,
                   "steps": state.steps})
@@ -851,7 +961,7 @@ def snn_window_chunk(params_q: dict, pixels_u8: jax.Array,
         rng, new_states, fired, adds, tel = snn_int_stack_step(
             st.rng, pixels_u8, layer_states, weights, cfg.lif,
             dot_impl=cfg.dot_impl, active_pruning=cfg.active_pruning,
-            sparse_skip=cfg.sparse_skip)
+            sparse_skip=cfg.sparse_skip, layers=cfg.layers)
         counts = st.counts + fired.astype(jnp.int32)
         first = jnp.where(
             jnp.logical_and(fired, st.first == cfg.num_steps),

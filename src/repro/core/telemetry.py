@@ -113,10 +113,12 @@ class ChunkTelemetry(NamedTuple):
                      launch geometry, not individual lanes.
 
     ``adds`` is derived, not stored: per lane the executed synaptic adds
-    of layer ``l`` are exactly ``n_spk · n_en`` (a skipped tile pair has
-    zero of one factor), so the record stays minimal and the invariant
-    "telemetry adds == the frozen energy counters" is checkable rather
-    than tautological.
+    of a dense layer ``l`` are exactly ``n_spk · n_en`` (a skipped tile
+    pair has zero of one factor), so the record stays minimal and the
+    invariant "telemetry adds == the frozen energy counters" is checkable
+    rather than tautological.  A conv-pool layer's spike reaches only
+    the neurons whose fields cover it, so there ``n_spk · n_en`` bounds
+    its adds from above and the energy counter holds the count.
     """
 
     n_spk: jax.Array
@@ -125,7 +127,8 @@ class ChunkTelemetry(NamedTuple):
 
     @property
     def adds(self) -> jax.Array:
-        """Executed synaptic adds per (step, layer, lane) — n_spk · n_en."""
+        """Executed synaptic adds per (step, layer, lane) of a dense
+        layer — n_spk · n_en."""
         return self.n_spk * self.n_en
 
     def densities(self, layer_sizes) -> jax.Array:
@@ -280,16 +283,16 @@ def _pad_axis(x: jax.Array, axis: int, mult: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-def tiles_total(layer_sizes) -> tuple[int, ...]:
-    """Total 128×128 tile pairs per layer, per batch block, per step."""
-    from ..kernels.fused_snn import LANE, _pad128
-    sizes = [_pad128(int(n)) for n in layer_sizes]
-    return tuple((k // LANE) * (n // LANE)
-                 for k, n in zip(sizes[:-1], sizes[1:]))
+def tiles_total(layer_sizes, layers=None) -> tuple[int, ...]:
+    """Total 128×128 tile pairs per layer, per batch block, per step
+    (``layers``: the stack's descriptors, for conv-pool stages)."""
+    from ..kernels.fused_snn import layer_tile_pairs, stack_geometry
+    return layer_tile_pairs(layer_sizes,
+                            stack_geometry(int(layer_sizes[0]), layers))
 
 
 def layer_tile_skips(x: jax.Array, en: jax.Array, *,
-                     sparse_skip: bool) -> jax.Array:
+                     sparse_skip: bool, geom=None) -> jax.Array:
     """jnp mirror of the fused kernel's per-layer tile-skip predicates.
 
     ``x``: (B, n_in) bool input spikes; ``en``: (B, n_out) bool enables.
@@ -302,10 +305,23 @@ def layer_tile_skips(x: jax.Array, en: jax.Array, *,
     ``lax.cond`` predicate of ``fused_snn._tiled_contraction``, which is
     why this pure function is bit-checkable against the kernel's own
     counter.  All-jnp, so it runs inside scan/jit/shard_map bodies.
+
+    ``geom`` (``kernels.fused_snn.stack_geometry``) mirrors a conv-pool
+    stage (``ConvGeom``: :func:`_conv_tile_skips`) or the dense layer that
+    reads the last conv map as its row blocks side by side
+    (``FlatGeom``); the flat rows are laid out as the kernel's maps.
     """
-    from ..kernels.fused_snn import LANE, block_b_for
+    from ..kernels.fused_snn import (LANE, ConvGeom, FlatGeom, block_b_for,
+                                     to_map)
     B = x.shape[0]
     bB = block_b_for(B)
+    if isinstance(geom, ConvGeom):
+        return _conv_tile_skips(x, en, geom, bB, sparse_skip=sparse_skip)
+    if isinstance(geom, FlatGeom):
+        xm = to_map(_pad_axis(x.astype(bool), 0, bB), geom.in_map(), bB)
+        nb = xm.shape[0] // (geom.m * geom.hs * bB)
+        x = jnp.transpose(xm.reshape(nb, geom.m * geom.hs, bB, geom.nh),
+                          (0, 2, 1, 3)).reshape(nb * bB, geom.k_in)
     xp = _pad_axis(_pad_axis(x.astype(bool), 0, bB), 1, LANE)
     ep = _pad_axis(_pad_axis(en.astype(bool), 0, bB), 1, LANE)
     nb = xp.shape[0] // bB
@@ -316,6 +332,37 @@ def layer_tile_skips(x: jax.Array, en: jax.Array, *,
     if not sparse_skip:
         return jnp.zeros((nb,), jnp.int32)
     return jnp.sum(jnp.logical_not(live), axis=(1, 2)).astype(jnp.int32)
+
+
+def _conv_tile_skips(x, en, g, bB: int, *, sparse_skip: bool) -> jax.Array:
+    """The conv-pool stage's skipped tile pairs per batch block: for each
+    (row class q, row phase u, tap row dy), a K tile of the input rows the
+    stage reads is live if a lane of the block spikes in it, an N tile of
+    the pre-pool currents if class q holds an enabled neuron in its
+    pooled column tile (``kernels.fused_snn._conv_stage``)."""
+    from ..kernels.fused_snn import LANE, to_map
+    xm = to_map(_pad_axis(x.astype(bool), 0, bB), g.in_map(), bB)
+    em = to_map(_pad_axis(en.astype(bool), 0, bB), g.out_map(), bB)
+    nb = xm.shape[0] // (g.m_in * g.hs_in * bB)
+    any_x = jnp.any(xm.reshape(nb, g.m_in * g.hs_in, bB, g.kc // LANE, LANE),
+                    axis=(2, 4))                      # (nb, row blocks, kt)
+    any_e = jnp.any(em.reshape(nb, g.m_out * g.hs_out, bB, g.nh // LANE,
+                               LANE), axis=(2, 4))
+    if not sparse_skip:
+        return jnp.zeros((nb,), jnp.int32)
+    skipped = jnp.zeros((nb,), jnp.int32)
+    for q in range(g.m_out):
+        e_q = jnp.any(any_e[:, q * g.hs_out:(q + 1) * g.hs_out], axis=1)
+        e_pre = jnp.concatenate([e_q] * g.pool, axis=1)
+        for u in range(g.pool):
+            for dy in range(g.k):
+                s = g.pool * q + u + dy
+                r0 = (s % g.m_in) * g.hs_in + s // g.m_in
+                x_s = jnp.any(any_x[:, r0:r0 + g.hs_out], axis=1)
+                live = jnp.logical_and(x_s[:, :, None], e_pre[:, None, :])
+                skipped = skipped + jnp.sum(jnp.logical_not(live),
+                                            axis=(1, 2)).astype(jnp.int32)
+    return skipped
 
 
 def telemetry_partition_specs(axis_name: str | None = "data",

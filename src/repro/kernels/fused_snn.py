@@ -51,6 +51,15 @@ al. 2019).  This kernel restores the RTL's event-stream locality for an
     activity the serving layer's adaptive dispatch controller consumes.
     The jnp backends re-derive the identical record, so telemetry is
     bit-checkable exactly like the datapath.
+  * **conv-pool layers** (``core.layers.ConvPool``; :func:`stack_geometry`)
+    run inside the same launch, a prefix of the stack: each keeps its
+    spike and neuron maps as 2-D arrays grouped by row residue, so every
+    input run a (row class, row phase, tap row) contraction reads is a
+    contiguous row slice; the tap rows side by side go through the same
+    event-driven :func:`_tiled_contraction` against banded planes packed
+    once per weight set (:func:`pack_stack`), and the pooled current is
+    a max over the column phases (plane halves) and the row phases
+    (:func:`_conv_stage`).
   * optionally the kernel also runs the serving-layer **stability gate**
     per step (``gated=True``): a lane whose running prediction has been
     stable for ``patience`` steps freezes in place (PRNG, membranes,
@@ -66,15 +75,19 @@ advanced PRNG state.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fused_snn_stack_pallas", "pack_weights", "stack_vmem_bytes",
            "layer_shard_ways", "partial_contraction_pallas",
-           "block_b_for", "VMEM_BUDGET_BYTES", "DEFAULT_BLOCK_B", "LANE"]
+           "block_b_for", "VMEM_BUDGET_BYTES", "DEFAULT_BLOCK_B", "LANE",
+           "ConvGeom", "FlatGeom", "stack_geometry", "map_positions",
+           "to_map", "from_map", "pack_stack", "layer_tile_pairs"]
 
 DEFAULT_BLOCK_B = 8     # batch tile per program
 LANE = 128              # TPU lane width: every neuron axis pads to this
@@ -168,9 +181,205 @@ def layer_shard_ways(layer_sizes, model_shards: int):
                  for n in layer_sizes[1:])
 
 
+class ConvGeom(NamedTuple):
+    """Static layout of one conv-pool layer inside the stack kernel.
+
+    A spike or neuron map of shape (C, H, W) lives, per batch block, as a
+    2-D array: map row ``i`` of lane ``b`` at row ``((i % m) * hs + i // m)
+    * bB + b``, column ``j * C + c`` (padded to 128).  Rows grouped by
+    their residue mod ``m`` make every slice the stage reads a contiguous
+    run of 8-row blocks: the input map is split ``m_in = pool * m_out``
+    ways, so the pre-pool rows ``pool * y + u`` of the output rows
+    ``y = m_out * k + q`` read input rows ``s + m_in * k``, ``s = pool*q +
+    u + dy`` — one residue class, consecutive ``k``.  The last conv layer
+    has ``m_out = 1``.
+    """
+
+    k: int
+    pool: int
+    c_in: int
+    c_out: int
+    h_in: int
+    w_in: int
+    hp: int
+    wp: int
+    m_in: int
+    m_out: int
+    hs_in: int       # stored rows a residue class, input map
+    hs_out: int      # the same, pooled output map
+    kc: int          # input map columns: w_in * c_in padded to 128
+    nh: int          # output map columns: wp * c_out padded to 128
+
+    def in_map(self):
+        return (self.c_in, self.h_in, self.w_in, self.m_in, self.hs_in,
+                self.kc)
+
+    def out_map(self):
+        return (self.c_out, self.hp, self.wp, self.m_out, self.hs_out,
+                self.nh)
+
+
+class FlatGeom(NamedTuple):
+    """A dense layer reading the last conv layer's map: its input vector
+    is the map's row blocks side by side, (bB, m * hs * nh)."""
+
+    c: int
+    h: int
+    w: int
+    m: int
+    hs: int
+    nh: int
+
+    def in_map(self):
+        return (self.c, self.h, self.w, self.m, self.hs, self.nh)
+
+    @property
+    def k_in(self) -> int:
+        return self.m * self.hs * self.nh
+
+
+def stack_geometry(n_in: int, layers) -> tuple | None:
+    """Per layer: ``ConvGeom`` for a conv-pool layer, ``FlatGeom`` for the
+    dense layer after the last one, None for any other dense layer; or
+    None for a stack with no conv-pool layer (the dense kernel as is)."""
+    from ..core.layers import ConvPool
+    if layers is None or not any(isinstance(d, ConvPool) for d in layers):
+        return None
+    n_conv = sum(isinstance(d, ConvPool) for d in layers)
+    geoms: list = [None] * len(layers)
+    m_out, hs_out = 1, None
+    for l in range(n_conv - 1, -1, -1):          # the splits run backwards
+        d = layers[l]
+        c_in, h_in, w_in = d.in_shape
+        c_out, hp, wp = d.out_shape
+        if hs_out is None:
+            hs_out = hp
+        m_in = d.pool * m_out
+        top = d.pool * (m_out - 1) + d.pool - 1 + d.kernel - 1
+        hs_in = max(-(-h_in // m_in), hs_out + top // m_in)
+        geoms[l] = ConvGeom(
+            k=d.kernel, pool=d.pool, c_in=c_in, c_out=c_out, h_in=h_in,
+            w_in=w_in, hp=hp, wp=wp, m_in=m_in, m_out=m_out, hs_in=hs_in,
+            hs_out=hs_out, kc=_pad128(w_in * c_in), nh=_pad128(wp * c_out))
+        m_out, hs_out = m_in, hs_in
+    last = geoms[n_conv - 1]
+    geoms[n_conv] = FlatGeom(c=last.c_out, h=last.hp, w=last.wp, m=1,
+                             hs=last.hs_out, nh=last.nh)
+    return tuple(geoms)
+
+
+def map_positions(c: int, h: int, w: int, m: int, hs: int):
+    """Row block and column of every flat (C, H, W) index in a map."""
+    f = np.arange(c * h * w)
+    ci, i, j = f // (h * w), (f // w) % h, f % w
+    return (i % m) * hs + i // m, j * c + ci
+
+
+def to_map(a: jax.Array, geom, bB: int, fill=0) -> jax.Array:
+    """(Bp, C*H*W) flat rows -> the kernel's (Bp // bB * rows, cols) map,
+    ``geom = (C, H, W, m, hs, cols)``; padding holds ``fill``."""
+    c, h, w, m, hs, cols = geom
+    rb, col = map_positions(c, h, w, m, hs)
+    n = c * h * w
+    src = np.full((m * hs, cols), n, np.int32)        # n: the fill column
+    src[rb, col] = np.arange(n)
+    nb = a.shape[0] // bB
+    a = jnp.concatenate([a.reshape(nb, bB, n),
+                         jnp.full((nb, bB, 1), fill, a.dtype)], axis=2)
+    out = jnp.take(a, src.reshape(-1), axis=2).reshape(nb, bB, m * hs, cols)
+    return jnp.transpose(out, (0, 2, 1, 3)).reshape(nb * m * hs * bB, cols)
+
+
+def from_map(a: jax.Array, geom, bB: int) -> jax.Array:
+    """Inverse of :func:`to_map`: the (Bp, C*H*W) flat rows."""
+    c, h, w, m, hs, cols = geom
+    rb, col = map_positions(c, h, w, m, hs)
+    nb = a.shape[0] // (m * hs * bB)
+    vals = a.reshape(nb, m * hs, bB, cols)[:, rb, :, col]   # (n, nb, bB)
+    return jnp.transpose(vals, (1, 2, 0)).reshape(nb * bB, -1)
+
+
+def _conv_planes(w_q: jax.Array, g: ConvGeom):
+    """The conv-pool stage's resident operands, built once per weight set.
+
+    ``planes`` (2, k * kc, pool * nh) int8: row ``dy * kc + j * c_in +
+    c'``, column ``v * nh + x * c_out + c`` holds W[c, c', dy, j - (pool *
+    x + v)] (zero off the k-tap band), so the dy-concatenated input rows
+    times it are the pre-pool currents of column phase ``v``.  ``field``
+    (k * kc, 128) int8 counts, per output column x, the taps of both
+    phases each input reaches (its product is the spikes in the pre-pool
+    fields); ``per_x`` (nh, 128) int8 sums a neuron column over channels.
+    """
+    k, p = g.k, g.pool
+    c, ci, dy, dx, x, v = np.meshgrid(
+        np.arange(g.c_out), np.arange(g.c_in), np.arange(k), np.arange(k),
+        np.arange(g.wp), np.arange(p), indexing="ij")
+    j = p * x + v + dx
+    rows = (dy * g.kc + j * g.c_in + ci).ravel()
+    cols = (v * g.nh + x * g.c_out + c).ravel()
+    src = (((c * g.c_in + ci) * k + dy) * k + dx).ravel()
+    w = jnp.zeros((k * g.kc, p * g.nh), jnp.int32).at[rows, cols].set(
+        w_q.reshape(-1).astype(jnp.int32)[src])
+    field = np.zeros((k * g.kc, LANE), np.int8)
+    per_x = np.zeros((g.nh, LANE), np.int8)
+    for xx in range(g.wp):
+        for vv in range(p):
+            for jj in range(p * xx + vv, p * xx + vv + k):
+                for dd in range(k):
+                    field[dd * g.kc + jj * g.c_in:
+                          dd * g.kc + (jj + 1) * g.c_in, xx] += 1
+        per_x[xx * g.c_out:(xx + 1) * g.c_out, xx] = 1
+    return pack_weights(w), jnp.asarray(field), jnp.asarray(per_x)
+
+
+def _flat_plane(w_q: jax.Array, g: FlatGeom) -> jax.Array:
+    """A dense layer's codes with its rows moved from the (C, H, W)
+    flatten to the kernel's row-blocks-side-by-side input vector."""
+    rb, col = map_positions(g.c, g.h, g.w, g.m, g.hs)
+    n_out = w_q.shape[1]
+    w = jnp.zeros((g.k_in, _pad128(n_out)), w_q.dtype)
+    return pack_weights(w.at[rb * g.nh + col, :n_out].set(w_q))
+
+
+def pack_stack(weights, layers) -> tuple:
+    """Every layer's resident kernel operands for a stack with conv-pool
+    layers, built once when the weights are placed: per layer a tuple —
+    ``(planes,)`` dense, ``(planes, field, per_x)`` conv-pool."""
+    n_in = int(np.prod(layers[0].in_shape))
+    geoms = stack_geometry(n_in, layers)
+    out = []
+    for w, g in zip(weights, geoms):
+        if isinstance(g, ConvGeom):
+            out.append(_conv_planes(w, g))
+        elif isinstance(g, FlatGeom):
+            out.append((_flat_plane(w, g),))
+        else:
+            k, n = w.shape
+            pad = jnp.zeros((_pad128(k), _pad128(n)), w.dtype)
+            out.append((pack_weights(pad.at[:k, :n].set(w)),))
+    return tuple(out)
+
+
+def layer_tile_pairs(layer_sizes, geoms=None) -> tuple[int, ...]:
+    """128x128 tile pairs each layer's contraction holds, per batch block
+    and step: K tiles x N tiles, dense; for a conv-pool layer that, over
+    its m_out x pool (row class, row phase) contractions of k * kc
+    inputs into pool * nh pre-pool columns."""
+    out = []
+    for l, (k, n) in enumerate(zip(layer_sizes[:-1], layer_sizes[1:])):
+        g = None if geoms is None else geoms[l]
+        if isinstance(g, ConvGeom):
+            out.append(g.m_out * g.pool * (g.k * g.kc // LANE)
+                       * (g.pool * g.nh // LANE))
+        else:
+            k_pad = g.k_in if isinstance(g, FlatGeom) else _pad128(int(k))
+            out.append((k_pad // LANE) * (_pad128(int(n)) // LANE))
+    return tuple(out)
+
+
 def stack_vmem_bytes(layer_sizes, block_b: int = DEFAULT_BLOCK_B,
                      num_steps: int = 1, streamed: bool = False,
-                     model_shards: int = 1) -> int:
+                     model_shards: int = 1, layers=None) -> int:
     """Estimate of the kernel's resident VMEM footprint for one program.
 
     Counts the padded int8-packed weight planes (2 bytes/weight resident;
@@ -190,7 +399,16 @@ def stack_vmem_bytes(layer_sizes, block_b: int = DEFAULT_BLOCK_B,
     (every device holds the gathered spike vector).  Layers that don't
     divide stay whole.  ``model_shards=1`` reproduces the historical
     single-device estimate exactly.
+
+    ``layers`` with conv-pool descriptors (resident, unsharded only)
+    counts the conv stages instead: their planes and counting matrices,
+    the maps' state, the pixel map and a stage's concatenated input rows
+    and pre-pool currents.
     """
+    geoms = stack_geometry(int(layer_sizes[0]), layers)
+    if geoms is not None:
+        return _conv_stack_vmem_bytes(layer_sizes, geoms, block_b,
+                                      num_steps)
     sizes_raw = [int(n) for n in layer_sizes]
     ways = layer_shard_ways(sizes_raw, model_shards)
     sizes = [_pad128(n) for n in sizes_raw]
@@ -208,6 +426,29 @@ def stack_vmem_bytes(layer_sizes, block_b: int = DEFAULT_BLOCK_B,
     total += num_steps * bB * LANE * 4                   # side-channel rows
     total += bB * max(sizes[0], max_out) * 8             # spike temporaries
     return total
+
+
+def _conv_stack_vmem_bytes(layer_sizes, geoms, bB: int,
+                           num_steps: int) -> int:
+    g0 = geoms[0]
+    total = g0.m_in * g0.hs_in * bB * g0.kc * (1 + 4 + 4)  # pixel map
+    widest = 0
+    for k, n_out, g in zip(layer_sizes[:-1], layer_sizes[1:], geoms):
+        if isinstance(g, ConvGeom):
+            rows = g.m_out * g.hs_out * bB
+            total += 2 * g.k * g.kc * g.pool * g.nh          # planes
+            total += (g.k * g.kc + g.nh) * LANE              # counters
+            total += rows * g.nh * (4 + 4 + 1 + 4)           # state + current
+            widest = max(widest, g.hs_out * bB * (g.k * g.kc + 2 * g.pool
+                                                  * g.nh) * 4)
+        else:
+            k_in = g.k_in if isinstance(g, FlatGeom) else _pad128(int(k))
+            n_pad = _pad128(int(n_out))
+            total += k_in * n_pad * 2 + bB * n_pad * (4 + 4 + 1 + 4)
+            widest = max(widest, bB * k_in * 4)
+    total += num_steps * bB * _pad128(int(layer_sizes[-1])) * 4  # trace
+    total += num_steps * bB * LANE * 4                   # side-channel rows
+    return total + 2 * widest                            # stage temporaries
 
 
 def _first_argmax(x: jax.Array, n_true: int) -> jax.Array:
@@ -338,15 +579,86 @@ def partial_contraction_pallas(x_u8: jax.Array, en_u8: jax.Array,
     return out, skipped
 
 
+def _int8_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """Exact int32 product of small non-negative integers held as int8."""
+    return jax.lax.dot_general(a.astype(jnp.int8), b,
+                               (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.int32)
+
+
+def _lane_sum(rows: jax.Array, bB: int) -> jax.Array:
+    """(n * bB, 1) counts of a map's rows -> (bB, 1) sums per lane."""
+    if rows.shape[0] == bB:
+        return rows
+    out = rows[:bB]
+    for r in range(bB, rows.shape[0], bB):
+        out = out + rows[r:r + bB]
+    return out
+
+
+def _conv_stage(x, en, w_ref, field_ref, per_x_ref, g: ConvGeom, bB: int,
+                sparse_skip: bool):
+    """One conv-pool layer on the maps of a batch block (``ConvGeom``).
+
+    For each output row class q and row phase u, the k input row runs the
+    taps dy read are laid side by side, (hs_out * bB, k * kc), and
+    contracted against the layer's planes by :func:`_tiled_contraction`
+    (the same event-driven tile skipping, the output tile's enables being
+    class q's, once per column phase): the pre-pool currents of both
+    column phases.  Their max over the phases and over u is the pooled
+    current.  The executed adds come from the same rows: ``field`` counts
+    the spikes in each pooled neuron column's pre-pool fields, ``per_x``
+    its enabled channels.  Returns (currents (m_out * hs_out * bB, nh),
+    skipped tile pairs, adds (bB, 1)).
+    """
+    xi = x.astype(jnp.int32)
+    en_i = en.astype(jnp.int32)
+    hsb = g.hs_out * bB
+
+    def read_tile(kt, nt):
+        return w_ref[:, kt * LANE:(kt + 1) * LANE, nt * LANE:(nt + 1) * LANE]
+
+    field_w, per_x_w = field_ref[...], per_x_ref[...]
+    pooled, adds_rows, skipped = [], None, jnp.int32(0)
+    for q in range(g.m_out):
+        en_q = en_i[q * hsb:(q + 1) * hsb]
+        en_pre = jnp.concatenate([en_q] * g.pool, axis=1)
+        best, field = None, None
+        for u in range(g.pool):
+            runs = []
+            for dy in range(g.k):
+                s = g.pool * q + u + dy
+                r0 = ((s % g.m_in) * g.hs_in + s // g.m_in) * bB
+                runs.append(xi[r0:r0 + hsb])
+            xcat = jnp.concatenate(runs, axis=1)
+            pre, sk = _tiled_contraction(xcat, en_pre, read_tile,
+                                         g.pool * g.nh, sparse_skip)
+            skipped = skipped + sk
+            for v in range(g.pool):
+                a = pre[:, v * g.nh:(v + 1) * g.nh]
+                best = a if best is None else jnp.maximum(best, a)
+            f = _int8_dot(xcat, field_w)
+            field = f if field is None else field + f
+        rows = jnp.sum(field * _int8_dot(en_q, per_x_w), axis=1,
+                       keepdims=True)
+        adds_rows = rows if adds_rows is None else adds_rows + rows
+        pooled.append(best)
+    cur = pooled[0] if g.m_out == 1 else jnp.concatenate(pooled, axis=0)
+    return cur, skipped, _lane_sum(adds_rows, bB)
+
+
 def _stack_kernel(*refs, num_layers: int, chunk_steps: int, window_steps: int,
                   decay_shift: int, v_threshold: int, v_rest: int,
                   v_min: int, v_max: int, active_pruning: bool,
                   gated: bool, patience: int, readout: str,
-                  sparse_skip: bool, streamed: bool):
+                  sparse_skip: bool, streamed: bool, geoms=None):
     L = num_layers
     it = iter(refs)
     px_ref, st_ref = next(it), next(it)
     w_refs = [next(it) for _ in range(L)]   # packed (2, K, N) int8 planes
+    # conv-pool layers: their (field, per_x) counting matrices
+    aux_refs = [(next(it), next(it)) if isinstance(g, ConvGeom) else None
+                for g in (geoms or (None,) * L)]
     v_refs = [next(it) for _ in range(L)]
     en_refs = [next(it) for _ in range(L)]
     vp_refs = [next(it) for _ in range(L)]  # per-layer peak membranes
@@ -373,7 +685,8 @@ def _stack_kernel(*refs, num_layers: int, chunk_steps: int, window_steps: int,
     slabs = [(l, kt) for l in range(L)
              for kt in range(w_refs[l].shape[1] // LANE)]
 
-    side_col = jax.lax.broadcasted_iota(jnp.int32, (px.shape[0], LANE), 1)
+    bB = cnt_ref.shape[0]
+    side_col = jax.lax.broadcasted_iota(jnp.int32, (bB, LANE), 1)
 
     def side_row(cols):
         """Pack (bB, 1) counters into one lane-dense (bB, LANE) row."""
@@ -429,48 +742,66 @@ def _stack_kernel(*refs, num_layers: int, chunk_steps: int, window_steps: int,
             base = 0                                   # streamed slab cursor
             for l in range(L):
                 en = ens[l] != 0
-                if streamed:
-                    # Double-buffered HBM→VMEM slab pipeline: each K
-                    # iteration kicks off the NEXT slab's copy (into the
-                    # other scratch slot) before waiting on the current
-                    # one, so the copy of slab p+1 overlaps the
-                    # contraction against slab p.  ``base`` indexes this
-                    # layer's first entry in the step's (layer, K-slab)
-                    # order.
-                    def pre_k(kt, base=base):
-                        if base + kt + 1 < len(slabs):
-                            slab_dma(base + kt + 1).start()
-                        slab_dma(base + kt).wait()
-
-                    def read_tile(kt, nt, l=l, base=base):
-                        return w_scr[(base + kt) % 2, :, :,
-                                     nt * LANE:(nt + 1) * LANE]
-                    base += w_refs[l].shape[1] // LANE
+                g = None if geoms is None else geoms[l]
+                if isinstance(g, ConvGeom):
+                    cur, skipped, adds_l = _conv_stage(
+                        x, en, w_refs[l], *aux_refs[l], g, bB, sparse_skip)
                 else:
-                    pre_k = None
+                    if isinstance(g, FlatGeom):
+                        # the last map's row blocks side by side, one row
+                        # a lane
+                        xi = x.astype(jnp.int32)
+                        x = jnp.concatenate(
+                            [xi[r:r + bB] for r in range(0, xi.shape[0], bB)],
+                            axis=1)
+                    if streamed:
+                        # Double-buffered HBM→VMEM slab pipeline: each K
+                        # iteration kicks off the NEXT slab's copy (into
+                        # the other scratch slot) before waiting on the
+                        # current one, so the copy of slab p+1 overlaps
+                        # the contraction against slab p.  ``base``
+                        # indexes this layer's first entry in the step's
+                        # (layer, K-slab) order.
+                        def pre_k(kt, base=base):
+                            if base + kt + 1 < len(slabs):
+                                slab_dma(base + kt + 1).start()
+                            slab_dma(base + kt).wait()
 
-                    def read_tile(kt, nt, l=l):
-                        return w_refs[l][:, kt * LANE:(kt + 1) * LANE,
+                        def read_tile(kt, nt, l=l, base=base):
+                            return w_scr[(base + kt) % 2, :, :,
                                          nt * LANE:(nt + 1) * LANE]
+                        base += w_refs[l].shape[1] // LANE
+                    else:
+                        pre_k = None
 
-                cur, skipped = _tiled_contraction(x, en, read_tile,
-                                                  n_pads[l], sparse_skip,
-                                                  pre_k)
+                        def read_tile(kt, nt, l=l):
+                            return w_refs[l][:, kt * LANE:(kt + 1) * LANE,
+                                             nt * LANE:(nt + 1) * LANE]
+
+                    cur, skipped = _tiled_contraction(x, en, read_tile,
+                                                      n_pads[l], sparse_skip,
+                                                      pre_k)
+                    adds_l = None
                 cur = jnp.where(en, cur, 0)            # pruning clock-gate
                 v_int = jnp.clip(vs[l] + cur, v_min, v_max)
                 v_leak = v_int - (v_int >> decay_shift)
                 fired = jnp.logical_and(v_leak >= v_threshold, en)
                 v_new = jnp.where(fired, jnp.int32(v_rest), v_leak)
                 v_new = jnp.where(en, v_new, vs[l])    # frozen when gated
-                # energy: adds executed = input spikes × enabled outputs.
+                # per lane: a map's rows summed over its row blocks
+                n_spk = _lane_sum(jnp.sum(x.astype(jnp.int32), axis=-1,
+                                          keepdims=True), bB)
+                n_en = _lane_sum(jnp.sum(en.astype(jnp.int32), axis=-1,
+                                         keepdims=True), bB)
+                # energy: adds executed = input spikes × enabled outputs
+                # (a conv-pool stage counts its own, per receptive field).
                 # Identical on the sparse path: a skipped tile pair has
                 # either zero spikes or zero enabled outputs, so its
                 # n_spk·n_en term of the Σ_{kt,nt} expansion is zero —
                 # the dense product below already counts only executed
                 # work.
-                n_spk = jnp.sum(x.astype(jnp.int32), axis=-1, keepdims=True)
-                n_en = jnp.sum(en.astype(jnp.int32), axis=-1, keepdims=True)
-                adds_t = adds_t + n_spk * n_en
+                adds_t = adds_t + (n_spk * n_en if adds_l is None
+                                   else adds_l)
                 side += [n_spk, n_en]
                 # per-block skipped tile pairs, unmasked in gated mode: it
                 # records what the block's contraction actually executed,
@@ -516,7 +847,12 @@ def _stack_kernel(*refs, num_layers: int, chunk_steps: int, window_steps: int,
                 still = jnp.logical_and(still, steps_new < window_steps)
 
                 def keep(new, old):
-                    return jnp.where(live, new, old)
+                    if new.shape[0] == bB:
+                        return jnp.where(live, new, old)
+                    # a map: the lane's flag over its row blocks
+                    rows = jnp.concatenate([act] * (new.shape[0] // bB),
+                                           axis=0)
+                    return jnp.where(rows != 0, new, old)
 
                 s_new = keep(s_new, s)
                 new_vs = [keep(nv, ov) for nv, ov in zip(new_vs, vs)]
@@ -580,7 +916,7 @@ def fused_snn_stack_pallas(pixels_u8: jax.Array, state_u32: jax.Array,
                            readout: str = "count",
                            sparse_skip: bool = True, streamed: bool = False,
                            block_b: int = DEFAULT_BLOCK_B,
-                           interpret: bool = False):
+                           interpret: bool = False, aux=(), geoms=None):
     """Run ``chunk_steps`` timesteps of the full encode→LIF stack.
 
     All arrays must already be padded: batch to ``block_b``, every neuron
@@ -602,29 +938,38 @@ def fused_snn_stack_pallas(pixels_u8: jax.Array, state_u32: jax.Array,
     double-buffers 128-row slabs through VMEM scratch — the path for
     stacks whose resident footprint exceeds the VMEM budget.
 
+    ``geoms`` (:func:`stack_geometry`) runs conv-pool layers: their
+    weights are the planes of :func:`pack_stack`, ``aux`` their
+    (field, per_x) matrices in layer order, and the pixels, PRNG lanes
+    and each conv layer's v / en / v_peak arrive as maps (:func:`to_map`),
+    ``rows x bB`` rows a batch block; the dense arrays are as above.
+
     Returns (counts, v_trace (chunk,B,n_out), first, adds (chunk,B),
     state_u32', v_final tuple, en_final tuple (uint8), v_peak tuple,
     (tel_spk (chunk,L,B), tel_en (chunk,L,B),
     tel_tiles (chunk,L,n_blocks)), steps', and — when gated —
     (active', prev', streak')).
     """
-    B, n_in = pixels_u8.shape
+    B = counts_init.shape[0]
     L = len(weights_packed)
-    sizes = [n_in] + [w.shape[2] for w in weights_packed]
-    n_out = sizes[-1]
+    n_out = weights_packed[-1].shape[2]
     gated = gate_init is not None
     grid = (pl.cdiv(B, block_b),)
     bB = block_b
+    if geoms is not None and streamed:
+        raise ValueError("conv-pool layers run only resident")
 
     kernel = functools.partial(
         _stack_kernel, num_layers=L, chunk_steps=chunk_steps,
         window_steps=window_steps, decay_shift=decay_shift,
         v_threshold=v_threshold, v_rest=v_rest, v_min=v_min, v_max=v_max,
         active_pruning=active_pruning, gated=gated, patience=patience,
-        readout=readout, sparse_skip=sparse_skip, streamed=streamed)
+        readout=readout, sparse_skip=sparse_skip, streamed=streamed,
+        geoms=geoms)
 
-    def row(shape):      # batch-tiled 2-D state block
-        return pl.BlockSpec((bB,) + shape[1:], lambda i: (i,) + (0,) * (len(shape) - 1))
+    def row(shape):      # batch-tiled 2-D state block (a map: rows x bB)
+        return pl.BlockSpec((shape[0] // grid[0],) + shape[1:],
+                            lambda i: (i,) + (0,) * (len(shape) - 1))
 
     def whole(shape):    # fully VMEM-resident (packed weight planes)
         return pl.BlockSpec(shape, lambda i: (0,) * len(shape))
@@ -636,12 +981,14 @@ def fused_snn_stack_pallas(pixels_u8: jax.Array, state_u32: jax.Array,
 
     in_specs = [row(pixels_u8.shape), row(state_u32.shape)]
     in_specs += [w_spec(w) for w in weights_packed]
+    in_specs += [whole(a.shape) for a in aux]
     in_specs += [row(v.shape) for v in v_init]
     in_specs += [row(e.shape) for e in en_init]
     in_specs += [row(v.shape) for v in vp_init]
     in_specs += [row(counts_init.shape), row(first_init.shape),
                  row(steps_init.shape)]
-    inputs = ([pixels_u8, state_u32] + list(weights_packed) + list(v_init)
+    inputs = ([pixels_u8, state_u32] + list(weights_packed) + list(aux)
+              + list(v_init)
               + list(en_init) + list(vp_init)
               + [counts_init, first_init, steps_init])
     if gated:
@@ -659,24 +1006,19 @@ def fused_snn_stack_pallas(pixels_u8: jax.Array, state_u32: jax.Array,
         pl.BlockSpec((chunk_steps, bB, n_out), steps_block),
         row((B, n_out)),
         pl.BlockSpec((chunk_steps, bB, LANE), steps_block),
-        row((B, n_in)),
+        row(state_u32.shape),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((B, n_out), jnp.int32),
         jax.ShapeDtypeStruct((chunk_steps, B, n_out), jnp.int32),
         jax.ShapeDtypeStruct((B, n_out), jnp.int32),
         jax.ShapeDtypeStruct((chunk_steps, B, LANE), jnp.int32),
-        jax.ShapeDtypeStruct((B, n_in), jnp.uint32),
+        jax.ShapeDtypeStruct(state_u32.shape, jnp.uint32),
     ]
-    for l in range(L):
-        out_specs.append(row((B, sizes[l + 1])))
-        out_shape.append(jax.ShapeDtypeStruct((B, sizes[l + 1]), jnp.int32))
-    for l in range(L):
-        out_specs.append(row((B, sizes[l + 1])))
-        out_shape.append(jax.ShapeDtypeStruct((B, sizes[l + 1]), jnp.uint8))
-    for l in range(L):                     # per-layer peak membranes
-        out_specs.append(row((B, sizes[l + 1])))
-        out_shape.append(jax.ShapeDtypeStruct((B, sizes[l + 1]), jnp.int32))
+    for dtype in (jnp.int32, jnp.uint8, jnp.int32):   # v, en, peak membranes
+        for v in v_init:
+            out_specs.append(row(v.shape))
+            out_shape.append(jax.ShapeDtypeStruct(v.shape, dtype))
     # skipped tile pairs per step, layer and batch block: scalars, in SMEM
     out_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
     out_shape.append(

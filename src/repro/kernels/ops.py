@@ -70,14 +70,14 @@ def validate_weight_codes(weights) -> None:
 _resolve_sparse_skip = resolve_sparse_skip
 
 
-def _pad_to(x: jax.Array, axis: int, mult: int):
+def _pad_to(x: jax.Array, axis: int, mult: int, fill=0):
     size = x.shape[axis]
     pad = (-size) % mult
     if pad == 0:
         return x
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
-    return jnp.pad(x, widths)
+    return jnp.pad(x, widths, constant_values=fill)
 
 
 @partial(jax.jit, static_argnames=("num_steps", "interpret"))
@@ -160,7 +160,7 @@ def partial_contraction_op(spikes: jax.Array, en: jax.Array,
 @partial(jax.jit, static_argnames=(
     "num_steps", "chunk_steps", "decay_shift", "v_threshold", "v_rest",
     "v_min", "v_max", "active_pruning", "patience", "readout",
-    "sparse_skip", "streamed", "interpret", "block_b"))
+    "sparse_skip", "streamed", "interpret", "block_b", "layers"))
 def fused_snn_stack_op(pixels_u8: jax.Array, state_u32: jax.Array,
                        weights, *, num_steps: int, chunk_steps: int | None = None,
                        decay_shift: int, v_threshold: int, v_rest: int = 0,
@@ -171,7 +171,8 @@ def fused_snn_stack_op(pixels_u8: jax.Array, state_u32: jax.Array,
                        sparse_skip: bool | None = None,
                        streamed: bool = False,
                        interpret: bool | None = None,
-                       block_b: int | None = None):
+                       block_b: int | None = None,
+                       layers: tuple | None = None, packed=None):
     """Multi-layer encode→LIF stack in one resumable Pallas launch.
 
     Args:
@@ -204,6 +205,12 @@ def fused_snn_stack_op(pixels_u8: jax.Array, state_u32: jax.Array,
         heuristic.  Bit-identical for any valid value: blocking only
         changes launch geometry (and the telemetry tile-leaf shape that
         mirrors it), never the integer datapath.
+      layers: the stack's descriptors (``core.layers``) when it has
+        conv-pool layers, whose ``weights`` are (C_out, C_in, k, k) codes;
+        None for a dense stack.  Pixels, PRNG lanes and conv-layer state
+        stay flat here and are laid out as the kernel's maps per launch.
+      packed: the operands of ``fused_snn.pack_stack(weights, layers)``,
+        built once by the caller; None packs them in this launch.
 
     Returns a dict with ``spike_counts``/``first_spike_t``/``v_final``
     ((B, n_out) i32), ``v_trace`` ((chunk, B, n_out) i32), ``active_adds``
@@ -219,7 +226,11 @@ def fused_snn_stack_op(pixels_u8: jax.Array, state_u32: jax.Array,
         chunk_steps = num_steps
     B, n_in = pixels_u8.shape
     L = len(weights)
-    sizes = [n_in] + [w.shape[1] for w in weights]
+    geoms = fused_snn.stack_geometry(n_in, layers)
+    if geoms is None:
+        sizes = [n_in] + [w.shape[1] for w in weights]
+    else:
+        sizes = [n_in] + [d.neurons for d in layers]
     n_out = sizes[-1]
     if block_b is None:
         bB = fused_snn.block_b_for(B)
@@ -237,41 +248,66 @@ def fused_snn_stack_op(pixels_u8: jax.Array, state_u32: jax.Array,
     # the xorshift fixed point), so batch/input padding is invisible to the
     # datapath; padded neurons are masked out of the enable sets below so
     # they cannot fire and do not count toward the executed-add channel.
-    px = _pad_to(_pad_to(pixels_u8, 0, bB), 1, lane)
-    st = _pad_to(_pad_to(state_u32, 0, bB), 1, lane)
-    ws = tuple(fused_snn.pack_weights(_pad_to(_pad_to(w, 0, lane), 1, lane))
-               for w in weights)
+    aux = ()
+    if geoms is None:
+        px = _pad_to(_pad_to(pixels_u8, 0, bB), 1, lane)
+        st = _pad_to(_pad_to(state_u32, 0, bB), 1, lane)
+        ws = tuple(fused_snn.pack_weights(
+            _pad_to(_pad_to(w, 0, lane), 1, lane)) for w in weights)
+    else:
+        img = geoms[0].in_map()
+        px = fused_snn.to_map(_pad_to(pixels_u8, 0, bB), img, bB)
+        st = fused_snn.to_map(_pad_to(state_u32, 0, bB), img, bB)
+        if packed is None:
+            packed = fused_snn.pack_stack(weights, layers)
+        ws = tuple(p[0] for p in packed)
+        aux = tuple(a for p in packed for a in p[1:])
 
-    def valid_mask(n_true, n_pad):
-        # padded neurons AND padded batch rows are disabled — the rows so
-        # the tile-skip predicates (and their telemetry mirror) see the
+    def is_map(l):
+        return geoms is not None and isinstance(geoms[l],
+                                                fused_snn.ConvGeom)
+
+    def place(a, l, fill=0):
+        """(Bp, n_l) flat rows -> layer l's kernel array."""
+        if is_map(l):
+            return fused_snn.to_map(a, geoms[l].out_map(), bB, fill)
+        return _pad_to(a, 1, lane, fill)
+
+    def unplace(a, l):
+        if is_map(l):
+            return fused_snn.from_map(a, geoms[l].out_map(), bB)[:B]
+        return a[:B, :sizes[l + 1]]
+
+    def valid_mask(n):
+        # padded batch rows are disabled (padded neurons by ``place``) —
+        # so the tile-skip predicates (and their telemetry mirror) see the
         # identical enable geometry whether the state is fresh or carried
         # (_pad_to pads carried enables with False rows)
-        col = jnp.arange(n_pad, dtype=jnp.int32)[None, :]
         row = jnp.arange(Bp, dtype=jnp.int32)[:, None]
-        return jnp.logical_and(col < n_true, row < B)
+        return jnp.broadcast_to(row < B, (Bp, n))
+
+    def fresh(l, value):
+        return place(jnp.full((Bp, sizes[l + 1]), value, jnp.int32), l,
+                     value)
 
     def vp_fresh():
-        return tuple(jnp.full((Bp, ws[l].shape[2]), V_PEAK_INIT, jnp.int32)
-                     for l in range(L))
+        return tuple(fresh(l, V_PEAK_INIT) for l in range(L))
 
     if init is None:
-        v_in = tuple(jnp.full((Bp, ws[l].shape[2]), v_rest, jnp.int32)
-                     for l in range(L))
-        en_in = tuple(valid_mask(sizes[l + 1], ws[l].shape[2])
+        v_in = tuple(fresh(l, v_rest) for l in range(L))
+        en_in = tuple(place(valid_mask(sizes[l + 1]), l)
                       for l in range(L))
         vp_in = vp_fresh()
         cnt_in = jnp.zeros((Bp, ws[-1].shape[2]), jnp.int32)
         first_in = jnp.full((Bp, ws[-1].shape[2]), num_steps, jnp.int32)
         steps_in = jnp.zeros((Bp, 1), jnp.int32)
     else:
-        v_in = tuple(_pad_to(_pad_to(init["v"][l], 0, bB), 1, lane)
+        v_in = tuple(place(_pad_to(init["v"][l], 0, bB), l)
                      for l in range(L))
-        en_in = tuple(
-            _pad_to(_pad_to(init["en"][l].astype(bool), 0, bB), 1, lane)
-            for l in range(L))
+        en_in = tuple(place(_pad_to(init["en"][l].astype(bool), 0, bB), l)
+                      for l in range(L))
         vp_in = (vp_fresh() if init.get("v_peak") is None else
-                 tuple(_pad_to(_pad_to(init["v_peak"][l], 0, bB), 1, lane)
+                 tuple(place(_pad_to(init["v_peak"][l], 0, bB), l)
                        for l in range(L)))
         cnt_in = _pad_to(_pad_to(init["counts"], 0, bB), 1, lane)
         first_in = _pad_to(_pad_to(init["first"], 0, bB), 1, lane)
@@ -292,10 +328,13 @@ def fused_snn_stack_op(pixels_u8: jax.Array, state_u32: jax.Array,
         decay_shift=decay_shift, v_threshold=v_threshold, v_rest=v_rest,
         v_min=v_min, v_max=v_max, active_pruning=active_pruning,
         patience=patience, readout=readout, sparse_skip=sparse_skip,
-        streamed=streamed, block_b=bB, interpret=interpret)
+        streamed=streamed, block_b=bB, interpret=interpret, aux=aux,
+        geoms=geoms)
     (cnt, vtr, first, adds, st_out, v_fin, en_fin, vp_fin, tel,
      steps_out) = outs[:10]
     tspk, ten, ttile = tel
+    if geoms is not None:
+        st_out = fused_snn.from_map(st_out, geoms[0].in_map(), bB)
     res = {
         "spike_counts": cnt[:B, :n_out],
         "v_trace": vtr[:, :B, :n_out],
@@ -303,10 +342,9 @@ def fused_snn_stack_op(pixels_u8: jax.Array, state_u32: jax.Array,
         "v_final": v_fin[-1][:B, :n_out],
         "active_adds": adds[:, :B],
         "prng_state": st_out[:B, :n_in],
-        "v": tuple(v_fin[l][:B, :sizes[l + 1]] for l in range(L)),
-        "en": tuple(en_fin[l][:B, :sizes[l + 1]].astype(bool)
-                    for l in range(L)),
-        "v_peak": tuple(vp_fin[l][:B, :sizes[l + 1]] for l in range(L)),
+        "v": tuple(unplace(v_fin[l], l) for l in range(L)),
+        "en": tuple(unplace(en_fin[l], l).astype(bool) for l in range(L)),
+        "v_peak": tuple(unplace(vp_fin[l], l) for l in range(L)),
         "telemetry": ChunkTelemetry(n_spk=tspk[:, :, :B],
                                     n_en=ten[:, :, :B],
                                     tiles_skipped=ttile),

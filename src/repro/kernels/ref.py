@@ -17,7 +17,7 @@ from ..core.telemetry import ChunkTelemetry
 
 __all__ = ["poisson_encode_ref", "lif_forward_ref", "spike_matmul_ref",
            "fused_snn_ref", "fused_snn_stack_ref", "weight_pack_ref",
-           "tile_skips_ref"]
+           "tile_skips_ref", "conv_pool_ref"]
 
 # The megakernel's launch geometry, re-derived here independently of
 # kernels.fused_snn (oracle style): 128-lane neuron tiles, 8-row batch
@@ -57,6 +57,98 @@ def tile_skips_ref(x: jax.Array, en: jax.Array, *,
     if not sparse_skip:
         return jnp.zeros((nb,), jnp.int32)
     return jnp.sum(jnp.logical_not(live), axis=(1, 2)).astype(jnp.int32)
+
+
+def _pad_cols(a, mult):
+    return jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, (-a.shape[-1]) % mult),))
+
+
+def _conv_splits(layers):
+    """Per conv-pool layer: (m_in, m_out, hs_in, hs_out), the kernel's
+    row split of its input and output maps — the output rows y = m_out*k
+    + q, read by pre-pool rows pool*y + u from input rows s + m_in*k with
+    s = pool*q + u + dy; hs the rows a class holds, padded so every read
+    stays inside the map.  Re-derived here from the layer equations."""
+    convs = [d for d in layers if hasattr(d, "c_out")]
+    out, m_out, hs_out = [], 1, None
+    for d in reversed(convs):
+        k, p = d.kernel, d.pool
+        h_in = d.in_shape[1]
+        hp = (h_in - k + 1) // p
+        hs_out = hp if hs_out is None else hs_out
+        m_in = p * m_out
+        hs_in = max(-(-h_in // m_in),
+                    hs_out + (p * m_out - 1 + k - 1) // m_in)
+        out.append((m_in, m_out, hs_in, hs_out))
+        m_out, hs_out = m_in, hs_in
+    return out[::-1]
+
+
+def conv_pool_ref(x, w_q, layer):
+    """Oracle of a conv-pool layer: pooled currents (B, C*Hp*Wp) i32 and
+    the per-lane field counts pooled (B, Hp, Wp) i32 of spikes ``x``
+    (B, C_in*H*W), by XLA's convolution in f32 (exact: integer operands,
+    partial sums below 2**24)."""
+    c_in, h, w = layer.in_shape
+    k, p = layer.kernel, layer.pool
+    xs = x.reshape(-1, c_in, h, w).astype(jnp.float32)
+
+    def conv(a, b):
+        return jax.lax.conv_general_dilated(
+            a, b, (1, 1), "VALID", precision=jax.lax.Precision.HIGHEST)
+
+    a = conv(xs, w_q.astype(jnp.float32))
+    ones = conv(xs, jnp.ones((1, c_in, k, k), jnp.float32))
+    B, c, ho, wo = a.shape
+    cur = jnp.max(a.reshape(B, c, ho // p, p, wo // p, p), axis=(3, 5))
+    field = jnp.sum(ones.reshape(B, ho // p, p, wo // p, p), axis=(2, 4))
+    return (cur.reshape(B, -1).astype(jnp.int32), field.astype(jnp.int32))
+
+
+def _conv_tile_skips_ref(x, en, layer, split, *, sparse_skip):
+    """Skipped tile pairs of one conv-pool stage per 8-row batch block,
+    from the flat maps: a (q, u, dy) contraction's K tile is a 128-column
+    slice of the (W * C_in)-wide rows it reads, live if any lane of the
+    block spikes there; an N tile of its pre-pool currents is live if
+    class q holds an enabled neuron in the matching pooled column tile."""
+    m_in, m_out, hs_in, hs_out = split
+    c_in, h, w = layer.in_shape
+    k, p = layer.kernel, layer.pool
+    hp, wp = (h - k + 1) // p, (w - k + 1) // p
+    B = x.shape[0]
+    Bp = B + (-B) % _REF_BLOCK_B
+    nb = Bp // _REF_BLOCK_B
+
+    def row_tiles(a, c, rows, cols):
+        """(B, c*rows*cols) -> (nb, rows, col tiles) any over the block."""
+        a = jnp.transpose(a.reshape(B, c, rows, cols), (0, 2, 3, 1))
+        a = _pad_cols(a.reshape(B, rows, cols * c), _REF_LANE)
+        a = jnp.pad(a, ((0, Bp - B), (0, 0), (0, 0)))
+        return jnp.any(a.reshape(nb, _REF_BLOCK_B, rows, -1, _REF_LANE),
+                       axis=(1, 4))
+
+    xt = row_tiles(x.astype(bool), c_in, h, w)            # (nb, h, nkc)
+    et = row_tiles(en.astype(bool), layer.c_out, hp, wp)  # (nb, hp, nnh)
+    if not sparse_skip:
+        return jnp.zeros((nb,), jnp.int32)
+    skipped = jnp.zeros((nb,), jnp.int32)
+    for q in range(m_out):
+        ys = [y for y in range(q, hp, m_out)][:hs_out]
+        e_q = jnp.any(et[:, ys], axis=1)
+        e_pre = jnp.concatenate([e_q] * p, axis=1)
+        for u in range(p):
+            for dy in range(k):
+                s = p * q + u + dy
+                first = s // m_in
+                rows = [s % m_in + m_in * kk
+                        for kk in range(first, first + hs_out)
+                        if s % m_in + m_in * kk < h]
+                x_s = (jnp.any(xt[:, rows], axis=1) if rows
+                       else jnp.zeros(xt[:, 0].shape, bool))
+                live = jnp.logical_and(x_s[:, :, None], e_pre[:, None, :])
+                skipped = skipped + jnp.sum(~live, axis=(1, 2)).astype(
+                    jnp.int32)
+    return skipped
 
 
 def weight_pack_ref(w_q):
@@ -182,7 +274,7 @@ def fused_snn_stack_ref(pixels_u8: jax.Array, state_u32: jax.Array,
                         v_min: int = -(1 << 20), v_max: int = (1 << 20) - 1,
                         active_pruning: bool = False,
                         sparse_skip: bool | None = None,
-                        init: dict | None = None):
+                        init: dict | None = None, layers=None):
     """Oracle for the multi-layer resumable megakernel (fused_snn.py).
 
     Re-derives the whole stack — PRNG, comparator, the per-layer Σ W·S /
@@ -197,6 +289,9 @@ def fused_snn_stack_ref(pixels_u8: jax.Array, state_u32: jax.Array,
     full window).  ``sparse_skip`` only affects the telemetry tile
     counter (None resolves the same REPRO_SPARSE_SKIP env rule as the
     launcher, so oracle and kernel agree under the CI forcing).
+    ``layers`` (``SNNConfig.layers``) adds conv-pool layers
+    (:func:`conv_pool_ref`); their tile counts follow the kernel's row
+    splits, re-derived by :func:`_conv_splits`.
 
     Returns a dict shaped like ``kernels.ops.fused_snn_stack_op``'s.
     """
@@ -208,20 +303,28 @@ def fused_snn_stack_ref(pixels_u8: jax.Array, state_u32: jax.Array,
     L = len(weights)
     ws = [w.astype(jnp.int32) for w in weights]
     n_out = ws[-1].shape[1]
+    if layers is None:
+        widths = [w.shape[1] for w in ws]
+        kinds = [None] * L
+    else:
+        widths = [d.c_out * ((d.in_shape[1] - d.kernel + 1) // d.pool)
+                  * ((d.in_shape[2] - d.kernel + 1) // d.pool)
+                  if hasattr(d, "c_out") else d.n for d in layers]
+        splits = iter(_conv_splits(layers))
+        kinds = [next(splits) if hasattr(d, "c_out") else None
+                 for d in layers]
     vp0 = jnp.iinfo(jnp.int32).min
     if init is None:
         init = {
-            "v": tuple(jnp.full((B, w.shape[1]), v_rest, jnp.int32)
-                       for w in ws),
-            "en": tuple(jnp.ones((B, w.shape[1]), bool) for w in ws),
+            "v": tuple(jnp.full((B, n), v_rest, jnp.int32) for n in widths),
+            "en": tuple(jnp.ones((B, n), bool) for n in widths),
             "counts": jnp.zeros((B, n_out), jnp.int32),
             "first": jnp.full((B, n_out), num_steps, jnp.int32),
             "steps": jnp.zeros((B,), jnp.int32),
         }
     vp_init = init.get("v_peak")
     if vp_init is None:
-        vp_init = tuple(jnp.full((B, w.shape[1]), vp0, jnp.int32)
-                        for w in ws)
+        vp_init = tuple(jnp.full((B, n), vp0, jnp.int32) for n in widths)
 
     def step(carry, _):
         s, vs, ens, vps, cnt, first, steps = carry
@@ -234,17 +337,39 @@ def fused_snn_stack_ref(pixels_u8: jax.Array, state_u32: jax.Array,
         tel_spk, tel_en, tel_tiles = [], [], []
         for l in range(L):
             en = ens[l]
-            tel_tiles.append(tile_skips_ref(x, en, sparse_skip=sparse_skip))
-            cur = jnp.dot(x.astype(jnp.int32), ws[l])
+            n_spk = jnp.sum(x.astype(jnp.int32), axis=-1)
+            n_en = jnp.sum(en.astype(jnp.int32), axis=-1)
+            if kinds[l] is not None:
+                d = layers[l]
+                tel_tiles.append(_conv_tile_skips_ref(
+                    x, en, d, kinds[l], sparse_skip=sparse_skip))
+                cur, field = conv_pool_ref(x, ws[l], d)
+                per_x = jnp.sum(en.reshape(field.shape[:1] + (d.c_out,)
+                                           + field.shape[1:])
+                                .astype(jnp.int32), axis=1)
+                adds = adds + jnp.sum(field * per_x, axis=(1, 2))
+            else:
+                if l and kinds[l - 1] is not None:
+                    hs = kinds[l - 1][3]          # the last map: m = 1
+                    d = layers[l - 1]
+                    c, hp = d.c_out, (d.in_shape[1] - d.kernel + 1) // d.pool
+                    wp = (d.in_shape[2] - d.kernel + 1) // d.pool
+                    xm = jnp.transpose(x.reshape(B, c, hp, wp), (0, 2, 3, 1))
+                    xm = _pad_cols(xm.reshape(B, hp, wp * c), _REF_LANE)
+                    xm = jnp.pad(xm, ((0, 0), (0, hs - hp), (0, 0)))
+                    tel_tiles.append(tile_skips_ref(
+                        xm.reshape(B, -1), en, sparse_skip=sparse_skip))
+                else:
+                    tel_tiles.append(tile_skips_ref(
+                        x, en, sparse_skip=sparse_skip))
+                cur = jnp.dot(x.reshape(B, -1).astype(jnp.int32), ws[l])
+                adds = adds + n_spk * n_en
             cur = jnp.where(en, cur, 0)
             v_int = jnp.clip(vs[l] + cur, v_min, v_max)
             v_leak = v_int - (v_int >> decay_shift)
             fired = jnp.logical_and(v_leak >= v_threshold, en)
             v_new = jnp.where(fired, jnp.int32(v_rest), v_leak)
             v_new = jnp.where(en, v_new, vs[l])
-            n_spk = jnp.sum(x.astype(jnp.int32), axis=-1)
-            n_en = jnp.sum(en.astype(jnp.int32), axis=-1)
-            adds = adds + n_spk * n_en
             tel_spk.append(n_spk)
             tel_en.append(n_en)
             if active_pruning:

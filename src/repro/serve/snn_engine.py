@@ -67,8 +67,9 @@ from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from ..core import lif as lif_mod
 from ..core import prng as prng_mod
-from ..core.snn import (SNNConfig, readout_pred, snn_int_stack_step,
-                        snn_int_stack_step_sharded)
+from ..core.layers import ConvPool, dense_layers, weight_shapes
+from ..core.snn import (SNNConfig, check_weight_shapes, readout_pred,
+                        snn_int_stack_step, snn_int_stack_step_sharded)
 from ..core.telemetry import (ChunkTelemetry, EngineLoad,
                               telemetry_partition_specs, tiles_total)
 from .early_exit import StabilityGateState, stability_specs, stability_step
@@ -104,6 +105,16 @@ class LaneState(NamedTuple):
     adds: jax.Array        # (B,) int32 executed synaptic adds (energy)
     active: jax.Array      # (B,) bool — lane still consuming compute
     weight_version: jax.Array  # (B,) int32 admission-time WeightBank tag
+
+
+class ConvStackWeights(NamedTuple):
+    """Placed weights of a stack with conv-pool layers: the codes (the
+    jnp reference runs on them) and, where a fused backend may run, the
+    kernel's operands (``kernels.fused_snn.pack_stack``), built once per
+    weight version instead of at every launch."""
+
+    raw: tuple
+    packed: tuple | None
 
 
 @dataclass
@@ -147,7 +158,8 @@ def _stream_chunk_impl(lanes: LaneState, weights: tuple, *, chunk_steps: int,
                        interpret: bool | None = None,
                        model_axis: str | None = None,
                        model_ways: tuple[int, ...] | None = None,
-                       block_b: int | None = None):
+                       block_b: int | None = None,
+                       layers: tuple | None = None):
     """Un-jitted chunk body: every op is per-lane (no cross-batch contact),
     which is what lets the same code run whole-tile under ``jax.jit`` or
     per-device-slice under ``shard_map`` with bit-identical results.
@@ -167,7 +179,13 @@ def _stream_chunk_impl(lanes: LaneState, weights: tuple, *, chunk_steps: int,
     Pallas partial-contraction launches — still VMEM-resident weights,
     still bit-identical: the gate/freeze logic below runs on full
     (gathered) arrays identically on every model peer.
+
+    ``layers`` (``SNNConfig.layers``) runs a stack with conv-pool layers;
+    its ``weights`` are then a :class:`ConvStackWeights`.
     """
+    packed = None
+    if isinstance(weights, ConvStackWeights):
+        weights, packed = weights.raw, weights.packed
     if model_axis is not None and backend in ("fused", "fused_streamed"):
         contraction = "pallas"
     else:
@@ -187,7 +205,7 @@ def _stream_chunk_impl(lanes: LaneState, weights: tuple, *, chunk_steps: int,
                   "streak": lanes.gate_streak},
             patience=patience, readout=readout, sparse_skip=sparse_skip,
             streamed=(backend == "fused_streamed"), interpret=interpret,
-            block_b=block_b)
+            block_b=block_b, layers=layers, packed=packed)
         return LaneState(
             px=lanes.px, rng=k["prng_state"], v=k["v"], en=k["en"],
             v_peak=k["v_peak"],
@@ -215,7 +233,7 @@ def _stream_chunk_impl(lanes: LaneState, weights: tuple, *, chunk_steps: int,
             rng, new_states, fired, adds_t, tel = snn_int_stack_step(
                 st.rng, st.px, layer_states, weights, lif_cfg,
                 dot_impl=dot_impl, active_pruning=active_pruning,
-                sparse_skip=sparse_skip)
+                sparse_skip=sparse_skip, layers=layers)
         counts = st.counts + fired.astype(jnp.int32)
         first = jnp.where(
             jnp.logical_and(fired, st.first == num_steps),
@@ -274,14 +292,15 @@ def _stream_chunk_impl(lanes: LaneState, weights: tuple, *, chunk_steps: int,
 @partial(jax.jit, static_argnames=(
     "chunk_steps", "num_steps", "lif_cfg", "dot_impl", "active_pruning",
     "patience", "readout", "backend", "sparse_skip", "interpret",
-    "block_b"))
+    "block_b", "layers"))
 def stream_chunk(lanes: LaneState, weights: tuple, *, chunk_steps: int,
                  num_steps: int, lif_cfg: lif_mod.LIFConfig,
                  dot_impl: str, active_pruning: bool, patience: int,
                  readout: str = "count", backend: str = "reference",
                  sparse_skip: bool | None = None,
                  interpret: bool | None = None,
-                 block_b: int | None = None):
+                 block_b: int | None = None,
+                 layers: tuple | None = None):
     """Advance every active lane by up to ``chunk_steps`` window steps.
 
     ``backend="fused"`` runs the whole chunk — every layer, every step,
@@ -299,12 +318,14 @@ def stream_chunk(lanes: LaneState, weights: tuple, *, chunk_steps: int,
     bit-identical across the chunk backends.  ``block_b`` forwards the
     tuned batch-block override to the fused launch (value-neutral — it
     only reshapes the launch grid and its telemetry tile mirror).
+    ``layers`` carries a conv-pool stack's descriptors (static).
     """
     return _stream_chunk_impl(
         lanes, weights, chunk_steps=chunk_steps, num_steps=num_steps,
         lif_cfg=lif_cfg, dot_impl=dot_impl, active_pruning=active_pruning,
         patience=patience, readout=readout, backend=backend,
-        sparse_skip=sparse_skip, interpret=interpret, block_b=block_b)
+        sparse_skip=sparse_skip, interpret=interpret, block_b=block_b,
+        layers=layers)
 
 
 def lane_partition_specs(n_layers: int,
@@ -454,8 +475,14 @@ class SNNStreamEngine:
         from ..core.snn import fused_unsupported_reason
         from ..tune.cache import CacheDecision, decide_dispatch
         weights = tuple(layer["w_q"] for layer in params_q["layers"])
-        self.layer_sizes = tuple([weights[0].shape[0]]
-                                 + [w.shape[1] for w in weights])
+        # conv-pool descriptors (None: a dense stack, sized by its weights)
+        self.layers = cfg.layers
+        if cfg.layers is None:
+            self.layer_sizes = tuple([weights[0].shape[0]]
+                                     + [w.shape[1] for w in weights])
+        else:
+            check_weight_shapes(cfg, weights)
+            self.layer_sizes = cfg.layer_sizes
         # ---- dispatch cache (repro.tune): tuned startup shapes ----------
         # Resolved exactly once per engine (explicit argument → the
         # REPRO_DISPATCH_CACHE env → none; the sharded subclass passes a
@@ -612,6 +639,14 @@ class SNNStreamEngine:
         self._tiles_per_block = sum(
             tiles_total((k, n // w))[0] for k, n, w in
             zip(self.layer_sizes[:-1], self.layer_sizes[1:], ways))
+        self._conv_layers: tuple[int, ...] = ()
+        if self.layers is not None:
+            pairs = tiles_total(self.layer_sizes, self.layers)
+            self._tiles_per_block = sum(pairs)
+            self._conv_layers = tuple(l for l, d in enumerate(self.layers)
+                                      if isinstance(d, ConvPool))
+            self._conv_tiles_per_block = sum(pairs[l]
+                                             for l in self._conv_layers)
 
     _SERVICE_EWMA_ALPHA = 0.25
     n_devices = 1        # lane slot blocks; compaction keeps a lane in its own
@@ -624,8 +659,16 @@ class SNNStreamEngine:
 
     def _place_weights(self, weights: tuple) -> tuple:
         """Device-placement hook for a weight-plane tuple (the sharded
-        engine replicates over its mesh here)."""
-        return tuple(jnp.asarray(w) for w in weights)
+        engine replicates over its mesh here).  A conv-pool stack places a
+        :class:`ConvStackWeights`, packed once here for a fused ladder."""
+        placed = tuple(jnp.asarray(w) for w in weights)
+        if self.layers is None:
+            return placed
+        from ..kernels.fused_snn import pack_stack
+        fused = any(b.startswith("fused") for b in self._ladder)
+        return ConvStackWeights(
+            raw=placed,
+            packed=pack_stack(placed, self.layers) if fused else None)
 
     @property
     def chunk_steps(self) -> int:
@@ -808,6 +851,12 @@ class SNNStreamEngine:
                 counts.update(tiles_skipped=int(skipped.sum()),
                               tile_pairs=(self._tiles_per_block * blocks
                                           * chunk))
+                if self._conv_layers:
+                    counts.update(
+                        conv_tiles_skipped=int(
+                            skipped[:, list(self._conv_layers)].sum()),
+                        conv_tile_pairs=(self._conv_tiles_per_block
+                                         * blocks * chunk))
         waiting = bool(self.queue or self._adoptions)
         return bool((occupied & ~active).any() or (
             waiting and not (occupied & active).all()))
@@ -970,14 +1019,18 @@ class SNNStreamEngine:
         version-split dispatch in :meth:`_dispatch_chunk`).  The rollout
         completes — old planes freed, ``bank.history`` records it — when
         the last old-version lane retires.  Topology is fixed: the lane
-        state layout is a function of ``layer_sizes``.
+        state layout is a function of the layer descriptors, so the new
+        weights must have the shapes they give.
         """
         ws = tuple(layer["w_q"] for layer in params_q["layers"])
-        sizes = tuple([ws[0].shape[0]] + [w.shape[1] for w in ws])
-        if sizes != self.layer_sizes:
+        descs = (self.layers if self.layers is not None
+                 else dense_layers(self.layer_sizes))
+        want = weight_shapes(self.n_in, descs)
+        got = tuple(tuple(w.shape) for w in ws)
+        if got != want:
             raise ValueError(
                 f"rollout cannot change the topology: engine serves "
-                f"{self.layer_sizes}, new weights are {sizes}")
+                f"{descs} (weights {want}), new weights are {got}")
         if self.backend in ("fused", "fused_streamed"):
             from ..kernels.ops import validate_weight_codes
             validate_weight_codes(ws)
@@ -997,7 +1050,8 @@ class SNNStreamEngine:
             dot_impl=self.cfg.dot_impl,
             active_pruning=self.cfg.active_pruning, patience=self.patience,
             readout=self.cfg.readout, backend=self.backend_effective,
-            sparse_skip=self.cfg.sparse_skip, block_b=self._block_b)
+            sparse_skip=self.cfg.sparse_skip, block_b=self._block_b,
+            layers=self.layers)
 
     def _dispatch_versions(self, lanes: LaneState):
         """Version-aware chunk dispatch.
@@ -1278,6 +1332,11 @@ class ShardedSNNStreamEngine(SNNStreamEngine):
                  dispatch_cache=None):
         from ..kernels.fused_snn import layer_shard_ways
         from ..tune.cache import CacheDecision, decide_dispatch
+        if cfg.layers is not None:
+            raise ValueError(
+                "conv-pool layers are served by SNNStreamEngine on one "
+                "device: a model axis cannot split a conv map (its neurons "
+                "share every input row), and no data mesh runs them")
         if mesh is None:
             mesh = jax.make_mesh((len(jax.devices()),), (axis_name,),
                                  (AxisType.Auto,))

@@ -159,15 +159,20 @@ def planes_from_wire(d: list) -> tuple:
 # ---- configs / plans ------------------------------------------------------
 
 def snn_cfg_to_wire(cfg) -> dict:
-    return dataclasses.asdict(cfg)
+    from ..core.layers import layers_to_wire
+    d = dataclasses.asdict(cfg)
+    d["layers"] = layers_to_wire(cfg.layers)
+    return d
 
 
 def snn_cfg_from_wire(d: dict):
+    from ..core.layers import layers_from_wire
     from ..core.lif import LIFConfig
     from ..core.snn import SNNConfig
     d = dict(d)
     d["lif"] = LIFConfig(**d["lif"])
     d["layer_sizes"] = tuple(d["layer_sizes"])
+    d["layers"] = layers_from_wire(d.get("layers"))
     return SNNConfig(**d)
 
 

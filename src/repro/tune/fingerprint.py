@@ -27,9 +27,14 @@ __all__ = ["config_fingerprint", "fingerprint_payload"]
 
 
 def fingerprint_payload(cfg) -> dict:
-    """The identity-bearing fields of an ``SNNConfig``, JSON-canonical."""
+    """The identity-bearing fields of an ``SNNConfig``, JSON-canonical.
+
+    A conv-pool stack adds its layer descriptors: its flat widths alone
+    would match a dense stack of the same neuron counts.  A dense stack's
+    payload has no such key, as before descriptors existed."""
+    from ..core.layers import layers_to_wire
     lif = cfg.lif
-    return {
+    out = {
         "layer_sizes": [int(s) for s in cfg.layer_sizes],
         "num_steps": int(cfg.num_steps),
         "lif": {
@@ -51,6 +56,10 @@ def fingerprint_payload(cfg) -> dict:
             else float(cfg.spike_density_threshold)),
         "emit_trace": bool(cfg.emit_trace),
     }
+    layers = getattr(cfg, "layers", None)
+    if layers is not None:
+        out["layers"] = layers_to_wire(layers)
+    return out
 
 
 def config_fingerprint(cfg) -> str:

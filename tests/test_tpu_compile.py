@@ -19,8 +19,10 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.snn_mnist import SNN_CONFIG_DEEP, SNN_CONFIG_WIDE
-from repro.kernels import ops
+from repro.configs.snn_mnist import (SNN_CONFIG_CONV, SNN_CONFIG_DEEP,
+                                     SNN_CONFIG_WIDE)
+from repro.core.layers import weight_shapes
+from repro.kernels import fused_snn, ops
 
 LANES, CHUNK, T = 64, 4, 20
 
@@ -70,6 +72,31 @@ def _stack(spec, sizes, *, streamed=False):
                          ids=["paper", "deep"])
 def test_fused_stack_compiles(spec, sizes):
     assert _stack(spec, sizes).as_text().count("tpu_custom_call") == 1
+
+
+def test_conv_fused_stack_compiles(spec):
+    """12C5-MP2-64C5-MP2-1024FC10 at its published widths: the conv-pool
+    stages inside the one resumable launch, planes packed beforehand."""
+    cfg, B = SNN_CONFIG_CONV, LANES
+    sizes = cfg.layer_sizes
+    ws = tuple(spec(s, jnp.int16) for s in weight_shapes(sizes[0],
+                                                          cfg.layers))
+    packed = jax.tree.map(lambda a: spec(a.shape, a.dtype), jax.eval_shape(
+        lambda w: fused_snn.pack_stack(w, cfg.layers), ws))
+    init = {"v": tuple(spec((B, n), jnp.int32) for n in sizes[1:]),
+            "en": tuple(spec((B, n), jnp.bool_) for n in sizes[1:]),
+            "v_peak": tuple(spec((B, n), jnp.int32) for n in sizes[1:]),
+            "counts": spec((B, sizes[-1]), jnp.int32),
+            "first": spec((B, sizes[-1]), jnp.int32),
+            "steps": spec((B,), jnp.int32)}
+    gate = {"active": spec((B,), jnp.bool_), "prev": spec((B,), jnp.int32),
+            "streak": spec((B,), jnp.int32)}
+    compiled = ops.fused_snn_stack_op.lower(
+        spec((B, sizes[0]), jnp.uint8), spec((B, sizes[0]), jnp.uint32), ws,
+        num_steps=T, chunk_steps=CHUNK, decay_shift=4, v_threshold=128,
+        init=init, gate=gate, patience=2, readout="count", sparse_skip=True,
+        interpret=False, layers=cfg.layers, packed=packed).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
 
 
 def test_wide_fused_streamed_compiles(spec):
