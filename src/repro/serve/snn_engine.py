@@ -54,8 +54,9 @@ bit-identical to single-device serving because every op here is per-lane.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
-from functools import partial
+from functools import cached_property, partial
 from types import SimpleNamespace
 from typing import NamedTuple
 
@@ -83,8 +84,9 @@ from .telemetry import AdaptiveDispatchConfig, TelemetryController, \
     summarize_chunk
 
 __all__ = ["SNNStreamEngine", "ShardedSNNStreamEngine", "LaneState",
-           "RequestResult", "stream_chunk", "lane_partition_specs",
-           "weight_partition_specs", "make_sharded_stream_chunk"]
+           "LaneTileCodec", "RequestResult", "stream_chunk",
+           "lane_partition_specs", "weight_partition_specs",
+           "make_sharded_stream_chunk"]
 
 _V_PEAK_INIT = np.iinfo(np.int32).min   # window-start peak sentinel
 
@@ -148,6 +150,109 @@ def _init_lanes(batch: int, layer_sizes: tuple[int, ...], num_steps: int,
         active=jnp.zeros((batch,), bool),
         weight_version=jnp.zeros((batch,), jnp.int32),
     )
+
+
+class _Leaf(NamedTuple):
+    shape: tuple          # per-lane shape
+    dtype: np.dtype
+    lo: int               # first uint32 word of the leaf in a lane row
+    words: int            # its bytes, padded to a whole word
+
+
+@dataclass(frozen=True)
+class LaneTileCodec:
+    """A lane tile as one ``(B, W)`` uint32 slab, so that it crosses the
+    host↔device boundary in one transfer each way.
+
+    A lane row is every :class:`LaneState` leaf's per-lane bytes in field
+    order (``px``, ``rng``, the per-layer ``v``/``en``/``v_peak``, ...,
+    ``weight_version``), each padded to 4 bytes: the layout is a pure
+    function of ``layer_sizes``.  On the device :meth:`pack` and
+    :meth:`unpack` convert by bitcasts alone, so both are exact; on the
+    host :meth:`views` reads the slab's bytes as a ``LaneState`` of numpy
+    views, which the engine's host round writes through in place.
+    """
+
+    layer_sizes: tuple[int, ...]
+
+    @cached_property
+    def _layout(self) -> tuple:
+        shapes = jax.eval_shape(partial(_init_lanes, 1, self.layer_sizes,
+                                        1, 0))
+        flat, treedef = jax.tree.flatten(shapes)
+        leaves, lo = [], 0
+        for s in flat:
+            dtype = np.dtype(s.dtype)
+            if dtype.itemsize not in (1, 4):
+                raise TypeError(f"no lane-row layout for a {dtype} leaf")
+            words = -(-math.prod(s.shape) * dtype.itemsize // 4)
+            leaves.append(_Leaf(s.shape[1:], dtype, lo, words))
+            lo += words
+        return treedef, tuple(leaves), lo
+
+    @property
+    def width(self) -> int:
+        """uint32 words in one lane row."""
+        return self._layout[2]
+
+    @partial(jax.jit, static_argnums=0)
+    def pack(self, lanes: LaneState) -> jax.Array:
+        """Device ``LaneState`` → its ``(B, W)`` uint32 slab."""
+        treedef, leaves, _ = self._layout
+        words = []
+        for x, leaf in zip(treedef.flatten_up_to(lanes), leaves):
+            x = x.reshape(x.shape[0], -1)
+            if leaf.dtype.itemsize == 1:        # u8 and bool, as bytes
+                x = jnp.pad(x.astype(jnp.uint8),
+                            ((0, 0), (0, 4 * leaf.words - x.shape[1])))
+                x = x.reshape(x.shape[0], leaf.words, 4)
+            words.append(jax.lax.bitcast_convert_type(x, jnp.uint32))
+        return jnp.concatenate(words, axis=1)
+
+    @partial(jax.jit, static_argnums=(0, 2))
+    def unpack(self, slab: jax.Array, shardings=None) -> LaneState:
+        """``(B, W)`` uint32 slab → device ``LaneState``, each leaf under
+        ``shardings`` (a ``LaneState`` of them) where given."""
+        treedef, leaves, _ = self._layout
+        b = slab.shape[0]
+        out = []
+        for leaf in leaves:
+            w = slab[:, leaf.lo:leaf.lo + leaf.words]
+            n = math.prod(leaf.shape)
+            if leaf.dtype.itemsize == 1:
+                x = jax.lax.bitcast_convert_type(w, jnp.uint8)
+                x = x.reshape(b, -1)[:, :n].astype(leaf.dtype)
+            else:
+                x = jax.lax.bitcast_convert_type(w, leaf.dtype)
+            out.append(x.reshape((b,) + leaf.shape))
+        lanes = treedef.unflatten(out)
+        if shardings is not None:
+            lanes = jax.lax.with_sharding_constraint(lanes, shardings)
+        return lanes
+
+    def views(self, rows: np.ndarray) -> LaneState:
+        """Host ``(B, W)`` uint32 rows → a ``LaneState`` whose leaves are
+        numpy views into ``rows`` (writes to them write the rows)."""
+        treedef, leaves, _ = self._layout
+        b = rows.shape[0]
+        raw = rows.view(np.uint8)
+        return treedef.unflatten(
+            raw[:, 4 * l.lo:4 * l.lo + math.prod(l.shape) * l.dtype.itemsize]
+            .view(l.dtype).reshape((b,) + l.shape) for l in leaves)
+
+    def rows(self, st: LaneState) -> np.ndarray:
+        """The ``(B, W)`` uint32 rows that a host tile from :meth:`views`
+        looks into; a tile whose leaves are not all views of one such
+        buffer (a leaf replaced, say) is refused."""
+        treedef, _, width = self._layout
+        flat = treedef.flatten_up_to(st)
+        rows = flat[0].base
+        if not (isinstance(rows, np.ndarray) and rows.dtype == np.uint32
+                and rows.shape[1:] == (width,)
+                and all(a.base is rows for a in flat)):
+            raise ValueError("not a host lane tile of this layout: every "
+                             "leaf must view one (B, W) uint32 buffer")
+        return rows
 
 
 def _stream_chunk_impl(lanes: LaneState, weights: tuple, *, chunk_steps: int,
@@ -618,6 +723,10 @@ class SNNStreamEngine:
         self.n_in, self.n_out = self.layer_sizes[0], self.layer_sizes[-1]
         self.lanes = _init_lanes(batch_size, self.layer_sizes,
                                  cfg.num_steps, cfg.lif.v_rest)
+        # the tile crosses host<->device as one slab (the sharded engine
+        # places the slab and its leaves on its mesh)
+        self._codec = LaneTileCodec(self.layer_sizes)
+        self._slab_sharding = self._shardings = None
         self.lane_req: list[int | None] = [None] * batch_size
         self.queue: list[tuple[int, np.ndarray]] = []
         self.results: dict[int, RequestResult] = {}
@@ -823,13 +932,25 @@ class SNNStreamEngine:
         self.lane_req[slot] = rid
 
     def _upload(self, st: LaneState) -> LaneState:
-        """Host tile → device (the sharded engine re-places onto its mesh)."""
-        return jax.tree.map(jnp.asarray, st)
+        """Host tile → device: one put of the rows ``st`` views, then
+        :meth:`LaneTileCodec.unpack` on the device."""
+        rows = self._codec.rows(st)
+        with spans.span("snn.upload", bytes=rows.nbytes):
+            return self._codec.unpack(
+                jax.device_put(rows, self._slab_sharding), self._shardings)
 
     def _read_tile(self) -> LaneState:
-        """Device tile → host: a numpy copy of every lane-state leaf."""
-        with spans.span("snn.readback"):
-            return jax.tree.map(np.array, self.lanes)
+        """Device tile → host: :meth:`LaneTileCodec.pack` on the device,
+        one copy into a writable numpy buffer, read as views of it."""
+        with spans.span("snn.readback") as counts:
+            rows = np.array(self._codec.pack(self.lanes))
+            if counts is not None:
+                counts.update(bytes=rows.nbytes)
+            return self._codec.views(rows)
+
+    def _reorder(self, st: LaneState, order: np.ndarray) -> LaneState:
+        """Host tile with its lanes in ``order``: one gather of its rows."""
+        return self._codec.views(self._codec.rows(st)[order])
 
     def _needs_compaction(self) -> bool:
         """Cheap pre-check: only the (B,) active mask crosses the device
@@ -890,7 +1011,7 @@ class SNNStreamEngine:
             lane_req.extend([self.lane_req[int(i)] for i in keep]
                             + [None] * (size - len(keep)))
             free_slots.append(list(range(lo + len(keep), lo + size)))
-        st = jax.tree.map(lambda a: a[np.asarray(order, np.int32)], st)
+        st = self._reorder(st, np.asarray(order, np.int32))
         self.lane_req = lane_req
 
         with spans.span("snn.admit") as counts:
@@ -907,8 +1028,7 @@ class SNNStreamEngine:
                 counts.update(n=len(admitted), rids=tuple(admitted))
 
         self._sync_versions(st)
-        with spans.span("snn.upload"):
-            self.lanes = self._upload(st)
+        self.lanes = self._upload(st)
         return done_ids
 
     def _sync_versions(self, st: LaneState) -> None:
@@ -1416,6 +1536,7 @@ class ShardedSNNStreamEngine(SNNStreamEngine):
         # runtime dispatches (exactly one entry when frozen and healthy)
         self._chunk_fns: dict[tuple[int, str], object] = {}
         self._chunk_fn_for(self.controller.chunk_steps)
+        self._slab_sharding = NamedSharding(mesh, P(axis_name, None))
         self.lanes = jax.device_put(self.lanes, self._shardings)
 
     # ---- device placement ----------------------------------------------
@@ -1444,9 +1565,6 @@ class ShardedSNNStreamEngine(SNNStreamEngine):
                 model_ways=self.model_ways if self.model_axis else None,
                 block_b=self._block_b)
         return self._chunk_fns[key]
-
-    def _upload(self, st: LaneState) -> LaneState:
-        return jax.device_put(st, self._shardings)
 
     def _advance(self, lanes: LaneState, weights: tuple):
         return self._chunk_fn_for(self.controller.chunk_steps)(

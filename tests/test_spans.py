@@ -11,7 +11,8 @@ Contracts under test:
     nested under ``snn.step``;
   * the counts are the engine's: ``snn.harvest`` carries the requests
     retired, ``snn.admit`` those admitted, ``snn.sync`` the previous
-    chunk's skipped tile pairs out of the launch geometry's;
+    chunk's skipped tile pairs out of the launch geometry's,
+    ``snn.readback`` and ``snn.upload`` the bytes of the packed tile;
   * results are bit-identical with recording on and off;
   * each in-memory span has a host event of the same name and counts in
     the ``.xplane.pb`` the profiler writes.
@@ -142,6 +143,17 @@ def test_sync_and_dispatch_counts(traced):
     per_chunk = sum(tiles_total(SIZES)) * 1 * CHUNK
     assert all(c["tile_pairs"] == per_chunk
                and 0 <= c["tiles_skipped"] <= per_chunk for c in tiled)
+
+
+def test_tile_crossings_count_their_bytes(traced):
+    got = traced["spans"]
+    # a lane row, in 4-byte words: px 6, rng 24, v 12 + 10, en 3 + 3,
+    # v_peak 12 + 10, counts 10, first 10, six per-lane scalars
+    row = 4 * (6 + 24 + 22 + 6 + 22 + 10 + 10 + 6)
+    for name in ("snn.readback", "snn.upload"):
+        crossings = [s.counts for s in got if s.name == name]
+        assert crossings and all(c == {"bytes": 8 * row}
+                                 for c in crossings)
 
 
 def test_results_identical_with_recording_on_and_off(traced):
