@@ -92,12 +92,11 @@ def run():
     tuned_not_slower = ratio <= 1.05
     emit("autotune.search", None,
          f"candidates={len(result.records)} "
-         f"pruned={result.pruned} probe_density="
-         f"{result.probe['density_ewma']:.4f}")
+         f"pruned={result.pruned} probe_service_steps="
+         f"{result.probe['service_steps_ewma']:.4f}")
     emit("autotune.winner", tuned.seconds_per_retired_request * 1e6,
          f"chunk={tuned.chunk_steps} block_b={tuned.block_b} "
          f"lanes={tuned.lanes_per_device} "
-         f"threshold={tuned.spike_density_threshold} "
          f"backend={tuned.backend} "
          f"s_per_req_vs_default={ratio:.3f}x")
     assert result.bit_identical, \
@@ -120,14 +119,12 @@ def run():
     check_sched = ArrivalSchedule(n_requests=s["check_requests"],
                                   per_round=2, seed=53)
     pixels = check_sched.pixels(cfg.layer_sizes[0])
-    tuned_cfg = dataclasses.replace(
-        cfg, spike_density_threshold=tuned.spike_density_threshold)
     identity = {}
     for backend in ("reference", "fused"):
         base = SNNStreamEngine(params_q, cfg, backend=backend, patience=2,
                                seed=3, dispatch_cache=False)
         tuned_eng = SNNStreamEngine(
-            params_q, tuned_cfg, batch_size=tuned.lanes_per_device,
+            params_q, cfg, batch_size=tuned.lanes_per_device,
             chunk_steps=tuned.chunk_steps, block_b=tuned.block_b,
             backend=backend, patience=2, seed=3, dispatch_cache=False)
         identity[f"single.{backend}"] = (
@@ -137,7 +134,7 @@ def run():
             params_q, cfg, backend=backend, patience=2, seed=3,
             dispatch_cache=False)
         tuned_sh = ShardedSNNStreamEngine(
-            params_q, tuned_cfg, lanes_per_device=tuned.lanes_per_device,
+            params_q, cfg, lanes_per_device=tuned.lanes_per_device,
             chunk_steps=tuned.chunk_steps, block_b=tuned.block_b,
             backend=backend, patience=2, seed=3, dispatch_cache=False)
         identity[f"sharded.{backend}"] = (

@@ -57,10 +57,9 @@ CONTRACT_FIELDS = [
     "single_launch",
     "explicit_fused_raises",
     "devices",
-    # telemetry / adaptive-dispatch contract (BENCH_telemetry.json)
+    # telemetry / adaptive-controller contract (BENCH_telemetry.json)
     "telemetry_bit_identical",
     "adds_match",
-    "density_estimate_ok",
     "adaptive_matches_frozen",
     # serving-tier contract (BENCH_router.json)
     "tier_bit_identical",

@@ -88,13 +88,6 @@ class SNNConfig:
     # (bit-identical either way — skipped tiles contribute exactly zero).
     # None defers to the REPRO_SPARSE_SKIP env default (on).
     sparse_skip: bool | None = None
-    # Masked-vs-MXU dispatch boundary for the runtime density dispatch
-    # (kernels.ops.spike_matmul_op mode="auto") and the baseline the
-    # serving controller (serve.telemetry) retunes from live traffic.
-    # None resolves REPRO_SPIKE_DENSITY_THRESHOLD → the historical
-    # kernels.ops.SPIKE_DENSITY_THRESHOLD default (0.25).  Value-neutral
-    # by construction: both datapaths compute the identical contraction.
-    spike_density_threshold: float | None = None
     emit_trace: bool = True                    # False: no v/spike-train outputs
                                                # (prediction-only serving)
     # Float-threshold used during training; the int path scales it (below).

@@ -4,10 +4,8 @@ The paper's efficiency story is *event-driven* — work should track the
 spike activity the hardware actually observes (Bouvier et al. 2020 call
 activity monitoring the standard control plane of neuromorphic runtimes;
 SparrowSNN feeds measured spike statistics back into scheduling).  Until
-this module existed, the runtime steered itself with compile-time guesses:
-the masked-vs-MXU dispatch threshold was a hard-coded constant and the
-fused kernel's tile-skip decisions were invisible to the host even though
-the kernel computes every ingredient per step.
+this module existed, the fused kernel's tile-skip decisions were invisible
+to the host even though the kernel computes every ingredient per step.
 
 :class:`ChunkTelemetry` is the structured record every integer-engine
 backend emits for a window chunk — per-step, per-layer spike counts,
@@ -21,9 +19,8 @@ geometry independently, double-entry-bookkeeping style), so a telemetry
 regression is caught exactly like a datapath regression.
 
 On top of the record, ``serve.telemetry`` builds the adaptive controller
-that retunes the dispatch threshold and picks chunk lengths from live
-traffic; this module only defines the channel and the pure helpers shared
-by every producer.
+that picks chunk lengths from live traffic; this module only defines the
+channel and the pure helpers shared by every producer.
 """
 
 from __future__ import annotations
@@ -37,44 +34,16 @@ import jax.numpy as jnp
 __all__ = [
     "ChunkTelemetry",
     "EngineLoad",
-    "MatmulTelemetry",
-    "DEFAULT_SPIKE_DENSITY_THRESHOLD",
     "engine_load_from_wire",
     "engine_load_to_wire",
     "estimate_eta_steps",
     "load_score",
-    "resolve_density_threshold",
     "resolve_sparse_skip",
     "layer_tile_skips",
     "tiles_total",
     "telemetry_partition_specs",
     "concat_telemetry",
 ]
-
-# The compile-time guess this subsystem exists to replace: below this
-# per-batch spike density the masked (event-driven) spike-matmul kernel
-# wins over the MXU dot.  Kept under its historical home as
-# ``kernels.ops.SPIKE_DENSITY_THRESHOLD`` too — it is now only the
-# *default* for ``SNNConfig.spike_density_threshold`` / the env override,
-# and the serving controller may retune the live value.
-DEFAULT_SPIKE_DENSITY_THRESHOLD = 0.25
-
-
-def resolve_density_threshold(threshold: float | None = None) -> float:
-    """Explicit value → env ``REPRO_SPIKE_DENSITY_THRESHOLD`` → default.
-
-    The resolution order mirrors ``REPRO_SPARSE_SKIP``: an explicit config
-    value always wins, the env var lets CI sweep the dispatch boundary
-    across a whole run without touching call sites, and the exported
-    module constant keeps its historical meaning as the default.
-    """
-    if threshold is not None:
-        return float(threshold)
-    env = os.environ.get("REPRO_SPIKE_DENSITY_THRESHOLD")
-    if env:
-        return float(env)
-    return DEFAULT_SPIKE_DENSITY_THRESHOLD
-
 
 def resolve_sparse_skip(sparse_skip: bool | None) -> bool:
     """None → the REPRO_SPARSE_SKIP env default (on unless set to "0").
@@ -134,8 +103,7 @@ class ChunkTelemetry(NamedTuple):
     def densities(self, layer_sizes) -> jax.Array:
         """Observed input-spike density per (step, layer, lane) in [0, 1].
 
-        Layer ``l``'s fan-in is ``layer_sizes[l]`` — the quantity the
-        masked-vs-MXU dispatch threshold is compared against.
+        Layer ``l``'s fan-in is ``layer_sizes[l]``.
         """
         fan_in = jnp.asarray(layer_sizes[:-1], jnp.float32)
         return self.n_spk.astype(jnp.float32) / fan_in[None, :, None]
@@ -149,11 +117,10 @@ class EngineLoad(NamedTuple):
     maintains without extra device syncs: ``mean_service_steps`` is the
     EWMA of window steps retired requests actually consumed (early exit
     makes this traffic-dependent — exactly why routing on the *measured*
-    rate beats routing on ``num_steps``), ``density_ewma`` is the
-    adaptive controller's estimate (``None`` when frozen or unobserved).
-    The serving tier sprays requests by :func:`load_score` and gates
-    admission with :func:`estimate_eta_steps` — both pure functions of
-    this record, so routing decisions are deterministic and replayable.
+    rate beats routing on ``num_steps``).  The serving tier sprays
+    requests by :func:`load_score` and gates admission with
+    :func:`estimate_eta_steps` — both pure functions of this record, so
+    routing decisions are deterministic and replayable.
     """
 
     lanes_total: int               # batch-tile slots the engine owns
@@ -161,9 +128,8 @@ class EngineLoad(NamedTuple):
     queue_depth: int               # host-queue requests not yet admitted
     mean_service_steps: float      # EWMA of consumed steps per request
     retired_total: int             # requests completed since construction
-    density_ewma: float | None     # controller estimate (None if frozen)
     # Health surface (serve.faults) — defaulted so positional construction
-    # of the historical six-field record keeps meaning "healthy engine".
+    # of the five-field record means "healthy engine".
     consecutive_faults: int = 0    # dispatch faults since the last clean chunk
     demotion_level: int = 0        # rungs down the backend degradation ladder
     watchdog_margin: int | None = None  # chunks left before the hang deadline
@@ -265,13 +231,6 @@ def estimate_eta_steps(load: EngineLoad) -> float:
     else:
         waves = 1 + (load.queue_depth - free) // max(1, load.lanes_total)
     return (waves + 1) * _effective_service_steps(load)
-
-
-class MatmulTelemetry(NamedTuple):
-    """Side channel of one ``spike_matmul_op(mode="auto")`` dispatch."""
-
-    density: jax.Array     # f32 scalar — observed batch spike density
-    used_masked: jax.Array  # bool scalar — which datapath the cond took
 
 
 def _pad_axis(x: jax.Array, axis: int, mult: int) -> jax.Array:

@@ -1,8 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
 Handles: CPU-vs-TPU dispatch (interpret mode on CPU so the whole framework
-runs in this container), shape padding to tile multiples, density-based
-masked/MXU dispatch for the spike matmul, and unpadding of results.
+runs without a TPU), shape padding to tile multiples, and unpadding of
+results.
 """
 
 from __future__ import annotations
@@ -12,25 +12,12 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..core.telemetry import (ChunkTelemetry, MatmulTelemetry,
-                              DEFAULT_SPIKE_DENSITY_THRESHOLD,
-                              resolve_density_threshold, resolve_sparse_skip)
-from . import fused_snn, lif_step, poisson_encode, spike_matmul
+from ..core.telemetry import ChunkTelemetry, resolve_sparse_skip
+from . import fused_snn, lif_step, poisson_encode
 
-__all__ = ["poisson_encode_op", "lif_forward_op", "spike_matmul_op",
-           "fused_snn_op", "fused_snn_stack_op", "partial_contraction_op",
-           "validate_weight_codes",
-           "SPIKE_DENSITY_THRESHOLD", "resolve_density_threshold"]
-
-# Below this per-tile spike density the masked (event-driven) spike-matmul
-# kernel wins over the MXU dot; the ``mode="auto"`` runtime dispatch in
-# :func:`spike_matmul_op` branches on the *observed* density of the batch.
-# Kept under its historical name for backward compatibility — it is now
-# only the DEFAULT: the live value comes from ``SNNConfig``'s
-# ``spike_density_threshold`` / the ``REPRO_SPIKE_DENSITY_THRESHOLD`` env
-# override (``core.telemetry.resolve_density_threshold``), and the serving
-# controller may retune it from observed traffic.
-SPIKE_DENSITY_THRESHOLD = DEFAULT_SPIKE_DENSITY_THRESHOLD
+__all__ = ["poisson_encode_op", "lif_forward_op", "fused_snn_op",
+           "fused_snn_stack_op", "partial_contraction_op",
+           "validate_weight_codes"]
 
 # window-start sentinel for the carried peak-membrane accumulator: the
 # first real membrane value always wins the max-fold
@@ -378,66 +365,3 @@ def fused_snn_op(pixels_u8: jax.Array, state_u32: jax.Array, w_q: jax.Array,
         decay_shift=decay_shift, v_threshold=v_threshold, v_rest=v_rest,
         v_min=v_min, v_max=v_max, active_pruning=active_pruning,
         sparse_skip=sparse_skip, streamed=streamed, interpret=interpret)
-
-
-def spike_matmul_op(spikes: jax.Array, w_q: jax.Array, *,
-                    mode: str = "auto",
-                    density_threshold: float | None = None,
-                    with_telemetry: bool = False,
-                    interpret: bool | None = None):
-    """Event-driven spike×weight contraction.
-
-    ``mode="auto"`` dispatches at RUNTIME on the observed spike density of
-    the batch: a ``lax.cond`` picks the masked (event-driven) kernel below
-    the dispatch threshold and the MXU dot above it.  Both kernels compute
-    the identical int32 contraction (S ∈ {0,1} makes the masked add and
-    the dot arithmetically the same), so the dispatch can never change
-    results — only which datapath executes.  ``mode="masked"`` /
-    ``mode="mxu"`` force one branch.
-
-    ``density_threshold`` is the dispatch boundary: None resolves through
-    config/env/default (``core.telemetry.resolve_density_threshold``) —
-    the serving controller's retuned value
-    (``SNNStreamEngine.dispatch_threshold``) arrives through this
-    argument.  It enters the jitted computation as a TRACED scalar
-    operand, not a static argument, so the controller walking it per
-    chunk never recompiles.  ``with_telemetry=True`` additionally returns
-    a ``core.telemetry.MatmulTelemetry`` (observed density + branch
-    taken), the per-call twin of the fused kernel's chunk side channel.
-    ``w_q`` holds signed 9-bit codes (checked here when concrete).
-    """
-    validate_weight_codes((w_q,))
-    return _spike_matmul_impl(
-        spikes, w_q,
-        jnp.float32(resolve_density_threshold(density_threshold)),
-        mode=mode, with_telemetry=with_telemetry, interpret=interpret)
-
-
-@partial(jax.jit, static_argnames=("mode", "with_telemetry", "interpret"))
-def _spike_matmul_impl(spikes: jax.Array, w_q: jax.Array,
-                       threshold: jax.Array, *, mode: str,
-                       with_telemetry: bool, interpret: bool | None):
-    interpret = _use_interpret() if interpret is None else interpret
-    B, n_in = spikes.shape
-    n_out = w_q.shape[1]
-    bB, bN, bK = spike_matmul.DEFAULT_BLOCK
-    s = _pad_to(_pad_to(spikes, 0, bB), 1, bK)
-    w = fused_snn.pack_weights(_pad_to(_pad_to(w_q, 0, bK), 1, bN))
-    density = jnp.mean((spikes != 0).astype(jnp.float32))
-    if mode == "auto":
-        used_masked = density < threshold
-        out = jax.lax.cond(
-            used_masked,
-            lambda s, w: spike_matmul.spike_matmul_pallas(
-                s, w, mode="masked", interpret=interpret),
-            lambda s, w: spike_matmul.spike_matmul_pallas(
-                s, w, mode="mxu", interpret=interpret),
-            s, w)
-    else:
-        used_masked = jnp.asarray(mode == "masked")
-        out = spike_matmul.spike_matmul_pallas(s, w, mode=mode,
-                                               interpret=interpret)
-    out = out[:B, :n_out]
-    if with_telemetry:
-        return out, MatmulTelemetry(density=density, used_masked=used_masked)
-    return out

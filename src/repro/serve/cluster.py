@@ -290,8 +290,7 @@ class ClusterCoordinator:
     def _cold_load(self) -> EngineLoad:
         return EngineLoad(
             lanes_total=self.lanes_per_worker, lanes_busy=0, queue_depth=0,
-            mean_service_steps=float(self.cfg.num_steps), retired_total=0,
-            density_ewma=None)
+            mean_service_steps=float(self.cfg.num_steps), retired_total=0)
 
     def _kill_worker(self, h: WorkerHandle) -> None:
         h.alive = False

@@ -33,12 +33,10 @@ Every chunk also returns the structured **telemetry side channel**
 occupancy, skipped MXU tiles), produced bit-identically by the fused
 kernels and the jnp fallback.  The engines feed it to a
 ``serve.telemetry.TelemetryController``: frozen by default (static
-threshold + chunk length, zero readbacks — today's behavior bit-for-bit),
-or adaptive (``REPRO_ADAPTIVE_DISPATCH=1`` / an explicit
-``AdaptiveDispatchConfig``), where live traffic retunes the masked-vs-MXU
-dispatch threshold and picks the next chunk length.  Adaptivity is
-value-neutral: chunk splits and datapath choice are bit-identical by
-construction, so only wall-clock moves.
+chunk length, zero readbacks), or adaptive (``REPRO_ADAPTIVE_DISPATCH=1``
+/ an explicit ``AdaptiveDispatchConfig``), where the observed retirement
+rate picks the next chunk length.  Adaptivity is value-neutral: chunk
+splits are bit-identical by construction, so only wall-clock moves.
 
 Readouts: all three stream — ``count`` (spike-register argmax),
 ``first_spike`` (earliest spiking class, membrane tiebreak — the
@@ -57,7 +55,6 @@ from __future__ import annotations
 import math
 from dataclasses import dataclass
 from functools import cached_property, partial
-from types import SimpleNamespace
 from typing import NamedTuple
 
 import jax
@@ -79,8 +76,7 @@ from .faults import (DeviceLostFault, DispatchFault, EngineFailure,
                      PoisonDispatchError, injector_from_env, telemetry_ok)
 from .rollout import WeightBank, merge_version_chunks
 from . import spans
-from .telemetry import AdaptiveDispatchConfig, TelemetryController, \
-    make_controller, \
+from .telemetry import AdaptiveDispatchConfig, make_controller, \
     summarize_chunk
 
 __all__ = ["SNNStreamEngine", "ShardedSNNStreamEngine", "LaneState",
@@ -552,11 +548,10 @@ class SNNStreamEngine:
     ``adaptive`` configures the telemetry controller
     (serve.telemetry.TelemetryController): None reads the
     REPRO_ADAPTIVE_DISPATCH env default (frozen off it) — frozen mode
-    reproduces the static threshold/chunk choices with zero telemetry
-    readbacks; adaptive mode retunes the masked-vs-MXU dispatch threshold
-    (``engine.dispatch_threshold``) and picks each next chunk's length
-    from the observed density/retirement stream.  Either way results are
-    bit-identical — the controller only ever moves value-neutral knobs.
+    serves the static chunk length with zero telemetry readbacks;
+    adaptive mode picks each next chunk's length from the observed
+    retirement stream.  Either way results are bit-identical — the
+    controller only ever moves a value-neutral knob.
     """
 
     def __init__(self, params_q: dict, cfg: SNNConfig, *,
@@ -705,21 +700,11 @@ class SNNStreamEngine:
         self.batch_size = batch_size
         self.patience = patience
         self.seed = seed
-        if tuned is not None:
-            # tuned statics (threshold always; chunk length unless the
-            # caller overrode it — `chunk_steps` is the effective value
-            # here either way).  Frozen mode serves these with zero
-            # readbacks; adaptive walks its law from this start.
-            self.controller = TelemetryController.from_cache(
-                SimpleNamespace(
-                    chunk_steps=chunk_steps,
-                    spike_density_threshold=tuned.spike_density_threshold),
-                cfg_adaptive=adaptive, num_steps=cfg.num_steps)
-        else:
-            self.controller = make_controller(
-                adaptive,
-                spike_density_threshold=cfg.spike_density_threshold,
-                chunk_steps=chunk_steps, num_steps=cfg.num_steps)
+        # `chunk_steps` is already the effective value (tuned unless the
+        # caller overrode it); frozen mode serves it with zero readbacks,
+        # adaptive walks its law from it.
+        self.controller = make_controller(
+            adaptive, chunk_steps=chunk_steps, num_steps=cfg.num_steps)
         self.n_in, self.n_out = self.layer_sizes[0], self.layer_sizes[-1]
         self.lanes = _init_lanes(batch_size, self.layer_sizes,
                                  cfg.num_steps, cfg.lif.v_rest)
@@ -786,13 +771,6 @@ class SNNStreamEngine:
         the public attribute can never go stale under adaptive tuning."""
         return self.controller.chunk_steps
 
-    @property
-    def dispatch_threshold(self) -> float:
-        """Live masked-vs-MXU density boundary (static when frozen) —
-        the value routing layers pass to ``spike_matmul_op``'s
-        ``density_threshold``."""
-        return self.controller.dispatch_threshold
-
     # ---- request intake -------------------------------------------------
     def submit(self, pixels_u8: np.ndarray, *,
                request_id: int | None = None) -> int:
@@ -834,7 +812,6 @@ class SNNStreamEngine:
                                 if self._service_ewma is None
                                 else self._service_ewma),
             retired_total=self._retired_total,
-            density_ewma=self.controller.density_ewma,
             consecutive_faults=self.health.consecutive_faults,
             demotion_level=self.health.demotion_level,
             watchdog_margin=(None if self.injector is None

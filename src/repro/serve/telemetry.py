@@ -1,4 +1,4 @@
-"""Adaptive dispatch control from live telemetry (the serving control
+"""Adaptive chunk-length control from live telemetry (the serving control
 plane the activity side channel exists for).
 
 Hardware/software co-designs (SparrowSNN; the Bouvier et al. 2020 survey's
@@ -7,28 +7,18 @@ into scheduling instead of compile-time guesses.  This module is that
 loop's host side: :class:`TelemetryController` consumes per-chunk
 :class:`ChunkSummary` observations (reduced from the structured
 ``core.telemetry.ChunkTelemetry`` record every backend emits) and retunes
-two performance-facing knobs between chunk dispatches:
+one knob between chunk dispatches, the **chunk length** of the next
+streaming dispatch.  Lanes that retire mid-chunk burn host-invisible steps
+until the chunk ends, so a high observed retirement rate shrinks the chunk
+(tighter harvest granularity) while retirement-free steady state grows it
+(fewer host syncs per window step).
 
-  * the **masked-vs-MXU dispatch threshold** — the runtime density
-    dispatch of ``kernels.ops.spike_matmul_op(mode="auto")`` branches on
-    this boundary; the controller walks it with an EWMA of the observed
-    input density so marginal batches route to the datapath that wins on
-    the traffic actually being served, not on the 0.25 guess;
-  * the **chunk length** of the next streaming dispatch — lanes that
-    retire mid-chunk burn host-invisible steps until the chunk ends, so
-    a high observed retirement rate shrinks the chunk (tighter harvest
-    granularity) while retirement-free steady state grows it (fewer
-    host syncs per window step).
-
-Both knobs are *value-neutral by construction*: the masked and MXU
-datapaths compute the identical integer contraction, and chunked window
-execution is bit-identical under any split (the property tests pin both).
-Adaptivity can therefore never change predictions, retirement steps or
-energy counters — only wall-clock.  **Frozen mode** (the default, and
-what CI pins) bypasses every observation: the controller returns exactly
-the static threshold (``SNNConfig.spike_density_threshold`` → env →
-``kernels.ops.SPIKE_DENSITY_THRESHOLD``) and the configured chunk length,
-with zero device syncs — today's behavior, reproduced bit-for-bit.
+The knob is *value-neutral by construction*: chunked window execution is
+bit-identical under any split (the property tests pin it).  Adaptivity
+can therefore never change predictions, retirement steps or energy
+counters — only wall-clock.  **Frozen mode** (the default, and what CI
+pins) bypasses every observation: the controller returns exactly the
+configured chunk length, with zero device syncs.
 """
 
 from __future__ import annotations
@@ -38,7 +28,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-from ..core.telemetry import ChunkTelemetry, resolve_density_threshold
+from ..core.telemetry import ChunkTelemetry
 
 __all__ = ["AdaptiveDispatchConfig", "ChunkSummary", "TelemetryController",
            "adaptive_config_from_env", "make_controller", "summarize_chunk"]
@@ -48,23 +38,14 @@ __all__ = ["AdaptiveDispatchConfig", "ChunkSummary", "TelemetryController",
 class AdaptiveDispatchConfig:
     """Knobs of the serving telemetry controller.
 
-    ``adaptive=False`` is frozen mode: static threshold, static chunk
-    length, no telemetry readbacks.  The env override
-    ``REPRO_ADAPTIVE_DISPATCH=1`` (see :func:`adaptive_config_from_env`)
-    flips the default on for a whole run — CI uses it to prove adaptivity
-    is value-neutral across the entire suite.
+    ``adaptive=False`` is frozen mode: static chunk length, no telemetry
+    readbacks.  The env override ``REPRO_ADAPTIVE_DISPATCH=1`` (see
+    :func:`adaptive_config_from_env`) flips the default on for a whole
+    run — CI uses it to prove adaptivity is value-neutral across the
+    entire suite.
     """
 
     adaptive: bool = False
-    # EWMA weight of the newest chunk's observed density (0 < alpha <= 1).
-    ewma_alpha: float = 0.25
-    # Dispatch boundary = clip(gain · density_ewma, lo, hi): traffic much
-    # sparser than the static guess pulls the masked/MXU boundary down to
-    # just above typical density (marginal batches go MXU only when truly
-    # denser than the traffic), denser traffic pushes it up to the cap.
-    threshold_gain: float = 1.5
-    threshold_min: float = 0.05
-    threshold_max: float = 0.5
     # Chunk-length control: shrink when ≥ shrink_retire_frac of the active
     # lanes retired inside the chunk, grow after grow_patience consecutive
     # retirement-free chunks.
@@ -128,21 +109,19 @@ def summarize_chunk(tel: ChunkTelemetry, layer_sizes, *,
 
 @dataclass
 class TelemetryController:
-    """EWMA density estimator + the two dispatch decisions it drives.
+    """Chunk-length law driven by the observed retirement rate.
 
     Deterministic: the decision trajectory is a pure function of the
     observation sequence, so the same traffic replayed gives the same
-    thresholds and chunk lengths (the benchmark records the trajectory as
-    a contract artifact).  In frozen mode every property returns the
+    chunk lengths (the benchmark records the trajectory as a contract
+    artifact).  In frozen mode every property returns the
     static choice and :meth:`observe` is a no-op — bit-for-bit today's
     behavior.
     """
 
     cfg: AdaptiveDispatchConfig
-    static_threshold: float
     static_chunk_steps: int
     num_steps: int
-    density_ewma: float | None = None
     history: list = field(default_factory=list)
     _chunk: int = 0
     _quiet: int = 0
@@ -153,15 +132,6 @@ class TelemetryController:
     @property
     def frozen(self) -> bool:
         return not self.cfg.adaptive
-
-    @property
-    def dispatch_threshold(self) -> float:
-        """Masked-vs-MXU density boundary for the next dispatch."""
-        if self.frozen or self.density_ewma is None:
-            return self.static_threshold
-        lo, hi = self.cfg.threshold_min, self.cfg.threshold_max
-        return float(np.clip(self.cfg.threshold_gain * self.density_ewma,
-                             lo, hi))
 
     @property
     def chunk_steps(self) -> int:
@@ -177,43 +147,14 @@ class TelemetryController:
             return self.static_chunk_steps
         return max(1, min(self.cfg.min_chunk_steps, self.num_steps))
 
-    @classmethod
-    def from_cache(cls, tuned, *,
-                   cfg_adaptive: AdaptiveDispatchConfig | None = None,
-                   num_steps: int) -> "TelemetryController":
-        """Start at cache-tuned values instead of the static defaults.
-
-        ``tuned`` is a :class:`repro.tune.cache.TunedShapes` (anything
-        with ``chunk_steps`` / ``spike_density_threshold`` attributes):
-        the measured winner becomes the controller's *static* choice, so
-        frozen mode — still the default, still what CI pins — serves the
-        tuned shapes with zero readbacks, and adaptive mode walks its
-        shrink/grow law from the tuned starting point rather than from
-        the heuristics.  Duck-typed on purpose: ``serve`` must not
-        import ``repro.tune`` at module scope (tune's search side
-        imports serve).
-        """
-        return cls(
-            cfg=(adaptive_config_from_env() if cfg_adaptive is None
-                 else cfg_adaptive),
-            static_threshold=float(tuned.spike_density_threshold),
-            static_chunk_steps=int(tuned.chunk_steps),
-            num_steps=num_steps)
-
     def observe(self, summary: ChunkSummary) -> None:
-        """Fold one chunk's summary into the estimator and retune.
+        """Fold one chunk's summary into the chunk-length law.
 
-        No-op in frozen mode.  Chunks that consumed no lane-steps carry
-        no density signal and leave the estimator untouched.
+        No-op in frozen mode.
         """
         if self.frozen:
             return
         c = self.cfg
-        if summary.active_lane_steps > 0:
-            d = summary.density_in
-            self.density_ewma = (d if self.density_ewma is None else
-                                 (1 - c.ewma_alpha) * self.density_ewma
-                                 + c.ewma_alpha * d)
         # chunk-length control from the observed retirement rate
         if summary.lanes_active > 0:
             frac = summary.lanes_retired / summary.lanes_active
@@ -240,8 +181,6 @@ class TelemetryController:
                 self._quiet = 0
         self.history.append({
             "density_in": summary.density_in,
-            "density_ewma": self.density_ewma,
-            "dispatch_threshold": self.dispatch_threshold,
             "chunk_steps": self.chunk_steps,
             "lanes_retired": summary.lanes_retired,
             "executed_adds": summary.executed_adds,
@@ -249,15 +188,11 @@ class TelemetryController:
         })
 
 
-def make_controller(cfg_adaptive: AdaptiveDispatchConfig | None,
-                    *, spike_density_threshold: float | None,
+def make_controller(cfg_adaptive: AdaptiveDispatchConfig | None, *,
                     chunk_steps: int, num_steps: int) -> TelemetryController:
-    """Engine-side constructor: None → the env-resolved default config,
-    static threshold resolved through config → env → the historical
-    ``kernels.ops.SPIKE_DENSITY_THRESHOLD`` constant."""
+    """Engine-side constructor: None → the env-resolved default config."""
     return TelemetryController(
         cfg=(adaptive_config_from_env() if cfg_adaptive is None
              else cfg_adaptive),
-        static_threshold=resolve_density_threshold(spike_density_threshold),
         static_chunk_steps=chunk_steps,
         num_steps=num_steps)

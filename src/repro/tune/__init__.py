@@ -12,8 +12,8 @@ Three layers, importable independently:
   construction);
 * :mod:`repro.tune.search` — the telemetry-seeded
   seconds-per-retired-request search over
-  ``(chunk_steps, block_b, lanes_per_device, spike_density_threshold)``
-  (imports the serving stack lazily).
+  ``(chunk_steps, block_b, lanes_per_device)`` (imports the serving stack
+  lazily).
 """
 
 from .cache import (CACHE_CODEC_VERSION, ENV_DISPATCH_CACHE, CacheDecision,

@@ -2,26 +2,25 @@
 startup.
 
 The autotuner (``tune.search``) measures real engine runs and writes the
-winning shapes — ``chunk_steps``, kernel batch block ``block_b``,
-``lanes_per_device``, ``spike_density_threshold``, plus the backend that
-feasibility-resolved for them — into a JSON cache under ``results/tune/``
-keyed by ``(config fingerprint, device kind, mesh shape, backend
-request)``.  Engines resolve the cache at construction (explicit
-``dispatch_cache=`` argument → ``REPRO_DISPATCH_CACHE`` env → none) and
-record a :class:`CacheDecision` either way: a hit starts the
+winning shapes — ``chunk_steps``, kernel batch block ``block_b`` and
+``lanes_per_device``, plus the backend that feasibility-resolved for
+them — into a JSON cache under ``results/tune/`` keyed by ``(config
+fingerprint, device kind, mesh shape, backend request)``. Engines
+resolve the cache at construction (explicit ``dispatch_cache=`` argument
+→ ``REPRO_DISPATCH_CACHE`` env → none) and record a
+:class:`CacheDecision` either way: a hit starts the
 :class:`~repro.serve.telemetry.TelemetryController` at tuned values and
 skips re-deriving the backend; a miss — or a rejected file — falls back
-to today's static defaults.  **A bad cache must never take serving
+to today's static defaults. **A bad cache must never take serving
 down**: corrupt, stale-codec or future-codec files are rejected with an
 actionable message (mirroring the ``serve.wire`` codec-version pattern),
 warned about once, and treated as "no cache".
 
 Tuned shapes are value-neutral by construction — chunked execution is
-bit-identical under any split, lane placement is invisible to per-request
-PRNG purity, and both dispatch datapaths compute the identical integer
-contraction — so the cache may only ever change *when* work happens,
-never *what* is computed (``benchmarks/bench_autotune.py`` pins this as
-the ``tuned_bit_identical`` contract).
+bit-identical under any split, and lane placement is invisible to
+per-request PRNG purity — so the cache may only ever change *when* work
+happens, never *what* is computed (``benchmarks/bench_autotune.py`` pins
+this as the ``tuned_bit_identical`` contract).
 
 No jax at module scope: the cache must be loadable before any device
 exists (the cluster coordinator arms workers by env).
@@ -41,7 +40,9 @@ __all__ = [
 ]
 
 # Bump when the entry layout (fields, meaning, key grammar) changes.
-CACHE_CODEC_VERSION = 1
+# v2: entries and the config fingerprint lost the density-dispatch
+# threshold, so a v1 file is refused as stale.
+CACHE_CODEC_VERSION = 2
 
 # Engines with no explicit dispatch_cache= argument resolve this env var
 # to a cache file path (unset/empty = no cache, static defaults).
@@ -69,7 +70,6 @@ class TunedShapes:
     chunk_steps: int
     block_b: int
     lanes_per_device: int
-    spike_density_threshold: float
     backend: str
     seconds_per_retired_request: float | None = None
     baseline_seconds_per_retired_request: float | None = None
@@ -80,7 +80,6 @@ class TunedShapes:
             "chunk_steps": self.chunk_steps,
             "block_b": self.block_b,
             "lanes_per_device": self.lanes_per_device,
-            "spike_density_threshold": self.spike_density_threshold,
             "backend": self.backend,
             "seconds_per_retired_request": self.seconds_per_retired_request,
             "baseline_seconds_per_retired_request":
@@ -111,12 +110,6 @@ def _entry_from_json(key: str, d) -> TunedShapes:
             f"cache entry {key!r} block_b={block_b} is not a multiple of "
             f"8 (the fused kernel's sublane granularity) — regenerate "
             f"the cache")
-    thr = d.get("spike_density_threshold")
-    if not isinstance(thr, (int, float)) or isinstance(thr, bool) \
-            or not (0.0 < float(thr) <= 1.0):
-        raise DispatchCacheError(
-            f"cache entry {key!r} spike_density_threshold={thr!r} is not "
-            f"a density in (0, 1] — regenerate the cache")
     backend = d.get("backend")
     if backend not in _BACKENDS:
         raise DispatchCacheError(
@@ -126,7 +119,6 @@ def _entry_from_json(key: str, d) -> TunedShapes:
         chunk_steps=_int("chunk_steps"),
         block_b=block_b,
         lanes_per_device=_int("lanes_per_device"),
-        spike_density_threshold=float(thr),
         backend=backend,
         seconds_per_retired_request=d.get("seconds_per_retired_request"),
         baseline_seconds_per_retired_request=d.get(

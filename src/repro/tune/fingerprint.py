@@ -1,16 +1,15 @@
 """Config fingerprinting for the dispatch cache.
 
 A cache entry is only as trustworthy as its key: tuned shapes measured
-for one network must never be adopted by a different one.  The
+for one network must never be adopted by a different one. The
 fingerprint is a SHA-256 over the **canonical JSON** of every
 ``SNNConfig`` field that changes what the datapath computes or how big
 its launches are — topology, window length, LIF constants, quantization
-width, readout, pruning, dot implementation, sparse skipping and the
-static dispatch threshold.  Fields that are pure training-side concerns
-(``qat``, ``surrogate_slope``, ``train_threshold``) and the backend
-*request* (the cache key carries the backend separately) are excluded:
-two configs that serve identically share a fingerprint even if they
-were trained differently.
+width, readout, pruning, dot implementation and sparse skipping. Fields
+that are pure training-side concerns (``qat``, ``surrogate_slope``,
+``train_threshold``) and the backend *request* (the cache key carries
+the backend separately) are excluded: two configs that serve identically
+share a fingerprint even if they were trained differently.
 
 Conservatism is deliberate: a fingerprint that splits two equivalent
 configs costs one cache miss (static defaults — always safe); one that
@@ -51,9 +50,6 @@ def fingerprint_payload(cfg) -> dict:
         "fuse_encoder": bool(cfg.fuse_encoder),
         "sparse_skip": (None if cfg.sparse_skip is None
                         else bool(cfg.sparse_skip)),
-        "spike_density_threshold": (
-            None if cfg.spike_density_threshold is None
-            else float(cfg.spike_density_threshold)),
         "emit_trace": bool(cfg.emit_trace),
     }
     layers = getattr(cfg, "layers", None)

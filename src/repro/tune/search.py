@@ -2,9 +2,8 @@
 
 The runtime already exposes every knob this module tunes — ``chunk_steps``
 (host-sync granularity of the streaming window), the kernel batch block
-``block_b`` (MXU tile height of the fused launch), ``lanes_per_device``
-(continuous-batching tile width) and ``spike_density_threshold`` (the
-masked-vs-MXU dispatch boundary) — and PR 5 proved every one of them
+``block_b`` (MXU tile height of the fused launch) and ``lanes_per_device``
+(continuous-batching tile width) — and every one of them is
 value-neutral.  What none of them had is a *measured* setting: the
 controller walks them by fixed law, the benches reported analytic bytes.
 This module closes that gap the way the SNN design-space-exploration
@@ -15,16 +14,13 @@ request**.
 
 The sweep is kept tractable by seeding it from telemetry rather than
 enumerating the full grid: a short probe run with the adaptive
-:class:`~repro.serve.telemetry.TelemetryController` yields the observed
-density EWMA and mean retirement steps, which prune the threshold grid
-to the two values bracketing the observed density (every threshold on
-the same side of the traffic density dispatches identically — one
-representative per equivalence class suffices) and drop chunk lengths
-far past the observed retirement horizon.  The **default shapes are
-always a candidate** and measured first: they are both the bit-identity
-baseline every candidate must reproduce exactly and the floor the winner
-is compared against, so within a tuning session the winner is never
-slower than the defaults by construction.
+:class:`~repro.serve.telemetry.TelemetryController` yields the mean
+retirement steps, which drop chunk lengths far past the observed
+retirement horizon. The **default shapes are always a candidate** and
+measured first: they are both the bit-identity baseline every candidate
+must reproduce exactly and the floor the winner is compared against, so
+within a tuning session the winner is never slower than the defaults by
+construction.
 
 Determinism: the schedule's pixels and arrival pattern come from a
 seeded generator, engines are seeded, and the candidate order is sorted —
@@ -40,7 +36,7 @@ from __future__ import annotations
 
 import itertools
 import math
-from dataclasses import dataclass, field, replace as dc_replace
+from dataclasses import dataclass, field
 
 import numpy as np
 
@@ -95,12 +91,10 @@ class Candidate:
     chunk_steps: int
     block_b: int
     lanes_per_device: int
-    threshold: float
 
     def to_json(self) -> dict:
         return {"chunk_steps": self.chunk_steps, "block_b": self.block_b,
-                "lanes_per_device": self.lanes_per_device,
-                "threshold": self.threshold}
+                "lanes_per_device": self.lanes_per_device}
 
 
 @dataclass(frozen=True)
@@ -110,12 +104,11 @@ class AutotuneConfig:
     chunk_steps_grid: tuple = (2, 3, 4, 6, 8)
     block_b_grid: tuple = (8, 16)
     lanes_grid: tuple = (4, 8, 16)
-    threshold_grid: tuple = (0.1, 0.25, 0.4)
     schedule: ArrivalSchedule = field(default_factory=ArrivalSchedule)
     repeats: int = 3
     warmup: int = 1
-    # telemetry seeding: prune the grids from a probe run's observed
-    # density / retirement EWMAs before measuring anything
+    # telemetry seeding: prune the chunk grid from a probe run's observed
+    # retirement EWMA before measuring anything
     telemetry_prune: bool = True
     # hard cap on measured candidates (default shapes always included)
     max_candidates: int = 12
@@ -138,25 +131,18 @@ class AutotuneResult:
 
 def _default_candidate(cfg) -> Candidate:
     """Today's static shapes: what an engine runs with no cache."""
-    from ..core.telemetry import resolve_density_threshold
     from ..kernels.fused_snn import block_b_for
     lanes = 8                            # SNNStreamEngine's default tile
     return Candidate(
         chunk_steps=min(4, cfg.num_steps),
         block_b=block_b_for(lanes),
-        lanes_per_device=lanes,
-        threshold=float(resolve_density_threshold(
-            cfg.spike_density_threshold)))
+        lanes_per_device=lanes)
 
 
 def prune_grids(tune_cfg: AutotuneConfig, cfg, *,
-                density_ewma: float | None,
                 service_steps: float | None) -> tuple[dict, dict]:
     """Telemetry-seeded grid pruning.  Returns (grids, prune_report).
 
-    * thresholds: every value on the same side of the observed density
-      EWMA dispatches every chunk identically, so only the two values
-      bracketing the EWMA survive (plus clipping to the config window).
     * chunk lengths: lanes retire after ~``service_steps`` on average —
       chunks much longer than that horizon only burn frozen-lane steps,
       so lengths past ``2 × service_steps`` are dropped (never below the
@@ -165,29 +151,22 @@ def prune_grids(tune_cfg: AutotuneConfig, cfg, *,
       fill; such widths are dropped.
     """
     sched = tune_cfg.schedule
-    thr = sorted(set(float(t) for t in tune_cfg.threshold_grid))
     chunks = sorted(set(int(c) for c in tune_cfg.chunk_steps_grid
                         if 1 <= c <= cfg.num_steps))
     lanes = sorted(set(int(b) for b in tune_cfg.lanes_grid))
     blocks = sorted(set(int(b) for b in tune_cfg.block_b_grid))
-    report = {"threshold": [len(thr)], "chunk_steps": [len(chunks)],
+    report = {"chunk_steps": [len(chunks)],
               "lanes_per_device": [len(lanes)], "block_b": [len(blocks)]}
-    if tune_cfg.telemetry_prune and density_ewma is not None:
-        below = [t for t in thr if t <= density_ewma]
-        above = [t for t in thr if t > density_ewma]
-        thr = ([max(below)] if below else []) + \
-              ([min(above)] if above else [])
     if tune_cfg.telemetry_prune and service_steps is not None and chunks:
         horizon = max(min(chunks), int(math.ceil(2.0 * service_steps)))
         chunks = [c for c in chunks if c <= horizon] or [min(chunks)]
     lanes = [b for b in lanes if b <= sched.n_requests] or \
         ([min(lanes)] if lanes else [])
-    report["threshold"].append(len(thr))
     report["chunk_steps"].append(len(chunks))
     report["lanes_per_device"].append(len(lanes))
     report["block_b"].append(len(blocks))
-    return ({"threshold": thr, "chunk_steps": chunks, "lanes": lanes,
-             "blocks": blocks}, report)
+    return ({"chunk_steps": chunks, "lanes": lanes, "blocks": blocks},
+            report)
 
 
 def _result_bits(results: dict) -> dict:
@@ -220,10 +199,8 @@ def autotune_engine(params_q: dict, cfg, *,
 
     if make_engine is None:
         def make_engine(cand: Candidate, adaptive):
-            c = (cfg if cand.threshold is None else
-                 dc_replace(cfg, spike_density_threshold=cand.threshold))
             return SNNStreamEngine(
-                params_q, c, batch_size=cand.lanes_per_device,
+                params_q, cfg, batch_size=cand.lanes_per_device,
                 chunk_steps=cand.chunk_steps, block_b=cand.block_b,
                 patience=patience, seed=seed, backend=backend,
                 adaptive=adaptive, dispatch_cache=False)
@@ -234,24 +211,20 @@ def autotune_engine(params_q: dict, cfg, *,
     probe_eng = make_engine(default, AdaptiveDispatchConfig(adaptive=True))
     serve_schedule(probe_eng, sched, pixels)
     probe = {
-        "density_ewma": probe_eng.controller.density_ewma,
         "service_steps_ewma": probe_eng._service_ewma,
         "chunk_steps_final": probe_eng.controller.chunk_steps,
         "backend": probe_eng.backend,
     }
 
     grids, prune_report = prune_grids(
-        tc, cfg, density_ewma=probe["density_ewma"],
-        service_steps=probe["service_steps_ewma"])
+        tc, cfg, service_steps=probe["service_steps_ewma"])
 
-    cands = [Candidate(chunk_steps=c, block_b=b, lanes_per_device=l,
-                       threshold=t)
-             for c, b, l, t in itertools.product(
-                 grids["chunk_steps"], grids["blocks"], grids["lanes"],
-                 grids["threshold"])]
+    cands = [Candidate(chunk_steps=c, block_b=b, lanes_per_device=l)
+             for c, b, l in itertools.product(
+                 grids["chunk_steps"], grids["blocks"], grids["lanes"])]
     cands = [c for c in cands if c != default]
     cands.sort(key=lambda c: (c.chunk_steps, c.block_b,
-                              c.lanes_per_device, c.threshold))
+                              c.lanes_per_device))
     cands = [default] + cands[:max(0, tc.max_candidates - 1)]
 
     # ---- measure: default first (it is the bit-identity baseline) ---------
@@ -297,7 +270,6 @@ def autotune_engine(params_q: dict, cfg, *,
     tuned = TunedShapes(
         chunk_steps=winner.chunk_steps, block_b=winner.block_b,
         lanes_per_device=winner.lanes_per_device,
-        spike_density_threshold=float(winner.threshold),
         backend=winner_rec["backend"],
         seconds_per_retired_request=winner_rec[
             "seconds_per_retired_request"],

@@ -42,7 +42,7 @@ import pytest
 from repro.configs.snn_mnist import SNN_CONFIG
 from repro.core import snn
 from repro.serve import (AdaptiveDispatchConfig, ShardedSNNStreamEngine,
-                         SNNStreamEngine, TelemetryController)
+                         SNNStreamEngine)
 from repro.serve.router import SNNServingTier
 from repro.serve.telemetry import ChunkSummary, make_controller
 from repro.tune import (ArrivalSchedule, AutotuneConfig, CacheDecision,
@@ -69,7 +69,7 @@ def _small_cfg(**kw):
 
 def _tuned(**kw):
     base = dict(chunk_steps=3, block_b=8, lanes_per_device=4,
-                spike_density_threshold=0.2, backend="reference")
+                backend="reference")
     base.update(kw)
     return TunedShapes(**base)
 
@@ -83,6 +83,20 @@ def _write(tmp_path, cfg, tuned=None, mesh_shapes=((1,),),
     for mesh in mesh_shapes:
         cache.put(cache_key(fp, device_kind_now(), mesh, backend), tuned)
     return cache.save(str(tmp_path / name))
+
+
+def _v1_blob(cfg):
+    """A codec-v1 cache file with an entry keyed for ``cfg`` on this host.
+
+    v1 entries also carried the density-dispatch threshold that v2
+    dropped; the version alone refuses the file, before any entry is
+    read."""
+    key = cache_key(config_fingerprint(cfg), device_kind_now(), (1,),
+                    "auto")
+    return {"codec_version": 1,
+            "entries": {key: {"chunk_steps": 3, "block_b": 8,
+                              "lanes_per_device": 4, "threshold": 0.2,
+                              "backend": "reference"}}}
 
 
 def _bits(results):
@@ -103,7 +117,7 @@ def test_fingerprint_stable_and_diverges():
     for other in (dataclasses.replace(cfg, num_steps=9),
                   dataclasses.replace(cfg, layer_sizes=(16, 12, 10)),
                   dataclasses.replace(cfg, readout="first_spike"),
-                  dataclasses.replace(cfg, spike_density_threshold=0.3)):
+                  dataclasses.replace(cfg, sparse_skip=False)):
         assert config_fingerprint(other) != config_fingerprint(cfg)
     # training-only fields do not (two configs that SERVE identically
     # share tuned shapes even if trained differently)
@@ -144,8 +158,8 @@ def test_cache_rejects_corrupt_stale_future(tmp_path):
         DispatchCache.load(str(future))
 
     stale = tmp_path / "stale.json"
-    stale.write_text(json.dumps({"codec_version": 0, "entries": {}}))
-    with pytest.raises(DispatchCacheError, match="regenerate"):
+    stale.write_text(json.dumps(_v1_blob(_small_cfg())))
+    with pytest.raises(DispatchCacheError, match="stale codec v1"):
         DispatchCache.load(str(stale))
 
     noversion = tmp_path / "nover.json"
@@ -158,7 +172,6 @@ def test_cache_rejects_corrupt_stale_future(tmp_path):
         "codec_version": CACHE_CODEC_VERSION,
         "entries": {"k": {"chunk_steps": 0, "block_b": 8,
                           "lanes_per_device": 4,
-                          "spike_density_threshold": 0.2,
                           "backend": "reference"}}}))
     with pytest.raises(DispatchCacheError, match="chunk_steps"):
         DispatchCache.load(str(badentry))
@@ -167,7 +180,6 @@ def test_cache_rejects_corrupt_stale_future(tmp_path):
         "codec_version": CACHE_CODEC_VERSION,
         "entries": {"k": {"chunk_steps": 2, "block_b": 12,
                           "lanes_per_device": 4,
-                          "spike_density_threshold": 0.2,
                           "backend": "reference"}}}))
     with pytest.raises(DispatchCacheError, match="multiple of"):
         DispatchCache.load(str(badblock))
@@ -175,13 +187,15 @@ def test_cache_rejects_corrupt_stale_future(tmp_path):
 
 def test_engine_falls_back_on_bad_cache_never_crashes(tmp_path, rng):
     """Every rejected-cache shape constructs a working engine on static
-    defaults, with one UserWarning and the reason recorded."""
+    defaults, with one UserWarning and the reason recorded — including a
+    v1 file holding an entry keyed for this very config."""
     cfg = _small_cfg()
     params_q = _net(rng, cfg.layer_sizes)
     for blob in ("{nope",
                  json.dumps({"codec_version": CACHE_CODEC_VERSION + 1,
                              "entries": {}}),
-                 json.dumps({"codec_version": 0, "entries": {}})):
+                 json.dumps({"codec_version": 0, "entries": {}}),
+                 json.dumps(_v1_blob(cfg))):
         p = tmp_path / "bad.json"
         p.write_text(blob)
         with warnings.catch_warnings(record=True) as caught:
@@ -191,6 +205,7 @@ def test_engine_falls_back_on_bad_cache_never_crashes(tmp_path, rng):
         assert not eng.cache_decision.hit
         assert "static defaults" in eng.cache_decision.reason
         assert any(issubclass(w.category, UserWarning) for w in caught)
+        assert eng.batch_size == 8 and eng.chunk_steps == 4
         eng.submit(np.full(cfg.n_in, 40, np.uint8))
         res = eng.run()
         assert res[0].steps == cfg.num_steps
@@ -231,8 +246,6 @@ def test_env_resolution_single_sharded_tier(tmp_path, rng, monkeypatch):
     assert eng.cache_decision.hit and eng.cache_decision.source == path
     assert eng.batch_size == _tuned().lanes_per_device
     assert eng.controller.chunk_steps == _tuned().chunk_steps
-    assert eng.dispatch_threshold == \
-        pytest.approx(_tuned().spike_density_threshold)
 
     sh = ShardedSNNStreamEngine(params_q, cfg, patience=2, seed=0)
     assert sh.cache_decision.hit
@@ -347,8 +360,7 @@ def test_proportional_shrink():
     cfg = AdaptiveDispatchConfig(adaptive=True, min_chunk_steps=2,
                                  max_chunk_steps=16,
                                  shrink_retire_frac=0.25)
-    ctl = make_controller(cfg, spike_density_threshold=0.25,
-                          chunk_steps=12, num_steps=20)
+    ctl = make_controller(cfg, chunk_steps=12, num_steps=20)
     # exactly AT the trigger fraction: one step, as before this PR
     ctl.observe(_summary(retired=2, active=8, chunk=12))
     assert ctl.chunk_steps == 11
@@ -366,21 +378,25 @@ def test_proportional_shrink():
 
 def test_shrink_frozen_noop():
     ctl = make_controller(AdaptiveDispatchConfig(adaptive=False),
-                          spike_density_threshold=0.25, chunk_steps=12,
-                          num_steps=20)
+                          chunk_steps=12, num_steps=20)
     ctl.observe(_summary(retired=8, active=8, chunk=12))
     assert ctl.chunk_steps == 12 and ctl.history == []
 
 
-def test_controller_from_cache():
-    tuned = _tuned(chunk_steps=6, spike_density_threshold=0.11)
-    ctl = TelemetryController.from_cache(tuned, num_steps=20)
+def test_controller_from_cache(tmp_path, rng, monkeypatch):
+    """A cache hit starts the engine's controller at the tuned chunk
+    length: frozen by default, adaptive walking its law from there."""
+    monkeypatch.delenv("REPRO_ADAPTIVE_DISPATCH", raising=False)
+    cfg = _small_cfg()
+    params_q = _net(rng, cfg.layer_sizes)
+    path = _write(tmp_path, cfg, tuned=_tuned(chunk_steps=6))
+    ctl = SNNStreamEngine(params_q, cfg, patience=2, seed=0,
+                          dispatch_cache=path).controller
     assert ctl.frozen                       # env default stays frozen
-    assert ctl.chunk_steps == 6
-    assert ctl.dispatch_threshold == pytest.approx(0.11)
-    adaptive = TelemetryController.from_cache(
-        tuned, cfg_adaptive=AdaptiveDispatchConfig(adaptive=True),
-        num_steps=20)
+    assert ctl.chunk_steps == 6 and ctl.static_chunk_steps == 6
+    adaptive = SNNStreamEngine(
+        params_q, cfg, patience=2, seed=0, dispatch_cache=path,
+        adaptive=AdaptiveDispatchConfig(adaptive=True)).controller
     assert not adaptive.frozen and adaptive.chunk_steps == 6
 
 
@@ -449,7 +465,6 @@ def test_autotune_engine_and_write_cache(tmp_path, rng):
     params_q = _net(rng, cfg.layer_sizes)
     tc = AutotuneConfig(
         chunk_steps_grid=(2, 4), block_b_grid=(8,), lanes_grid=(4,),
-        threshold_grid=(0.1, 0.4),
         schedule=ArrivalSchedule(n_requests=6, per_round=2, seed=3),
         repeats=2, warmup=1, max_candidates=4)
     result = autotune_engine(params_q, cfg, tune_cfg=tc, patience=2,

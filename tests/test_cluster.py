@@ -203,7 +203,7 @@ def test_write_msg_with_deadline_roundtrips():
 def test_engine_load_wire_roundtrip():
     load = EngineLoad(lanes_total=8, lanes_busy=3, queue_depth=2,
                       mean_service_steps=5.5, retired_total=7,
-                      density_ewma=0.125, consecutive_faults=1,
+                      consecutive_faults=1,
                       demotion_level=2, watchdog_margin=None, alive=False)
     back = engine_load_from_wire(json.loads(json.dumps(
         engine_load_to_wire(load))))

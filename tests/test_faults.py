@@ -479,9 +479,9 @@ def test_submit_validation_leaves_tier_untouched():
        dq=st.integers(1, 32), dm=st.floats(0.1, 50.0))
 def test_eta_monotone_and_nonnegative(lanes, busy, q, mean, dq, dm):
     busy = min(busy, lanes)
-    base = EngineLoad(lanes, busy, q, mean, 0, None)
-    deeper = EngineLoad(lanes, busy, q + dq, mean, 0, None)
-    longer = EngineLoad(lanes, busy, q, mean + dm, 0, None)
+    base = EngineLoad(lanes, busy, q, mean, 0)
+    deeper = EngineLoad(lanes, busy, q + dq, mean, 0)
+    longer = EngineLoad(lanes, busy, q, mean + dm, 0)
     assert 0 <= estimate_eta_steps(base)
     assert estimate_eta_steps(base) <= estimate_eta_steps(deeper)
     assert estimate_eta_steps(base) <= estimate_eta_steps(longer)
@@ -493,18 +493,18 @@ def test_eta_monotone_and_nonnegative(lanes, busy, q, mean, dq, dm):
        q=st.integers(0, 128), mean=st.floats(0.5, 200.0),
        faults=st.integers(0, 8), level=st.integers(0, 2))
 def test_load_score_health_penalty(lanes, busy, q, mean, faults, level):
-    """Healthy == the historical six-field score; degradation only ever
+    """Healthy == the historical undegraded score; degradation only ever
     raises the bid; dead is never routable."""
     busy = min(busy, lanes)
-    healthy = EngineLoad(lanes, busy, q, mean, 0, None)
+    healthy = EngineLoad(lanes, busy, q, mean, 0)
     legacy = (0.5 * busy + q) * mean / max(1, lanes)
     assert load_score(healthy) == pytest.approx(legacy)
-    degraded = EngineLoad(lanes, busy, q, mean, 0, None,
+    degraded = EngineLoad(lanes, busy, q, mean, 0,
                           consecutive_faults=faults, demotion_level=level)
     assert load_score(degraded) >= load_score(healthy)
     if faults or level:
         assert load_score(degraded) > load_score(healthy)
-    dead = EngineLoad(lanes, busy, q, mean, 0, None, alive=False)
+    dead = EngineLoad(lanes, busy, q, mean, 0, alive=False)
     assert load_score(dead) == float("inf")
 
 
@@ -522,7 +522,7 @@ def test_eta_and_score_cold_engine_edges(lanes, busy, q, mean):
     warmed healthy engine; NaN poisoned both estimators outright."""
     import math
     busy = min(busy, lanes)
-    cold = EngineLoad(lanes, busy, q, mean, 0, None)
+    cold = EngineLoad(lanes, busy, q, mean, 0)
     eta = estimate_eta_steps(cold)
     assert math.isfinite(eta) and eta >= 1.0
     score = load_score(cold)
@@ -530,7 +530,7 @@ def test_eta_and_score_cold_engine_edges(lanes, busy, q, mean):
     if busy or q:
         # outstanding work still counts: the cold engine must not tie a
         # warmed, completely idle engine (score 0) in a least-loaded pick
-        warmed_idle = EngineLoad(lanes, 0, 0, 20.0, 100, None)
+        warmed_idle = EngineLoad(lanes, 0, 0, 20.0, 100)
         assert load_score(warmed_idle) == 0.0
         assert score > load_score(warmed_idle)
 
@@ -539,8 +539,8 @@ def test_cold_engine_does_not_beat_warmed_busy_engine():
     """The routing comparison the bug corrupted, pinned directly: a cold
     engine drowning in queued work must score WORSE than a warmed healthy
     engine with a couple of free lanes — not 0 or NaN."""
-    cold_drowning = EngineLoad(8, 8, 100, 0.0, 0, None)
-    warmed_light = EngineLoad(8, 6, 0, 20.0, 50, None)
+    cold_drowning = EngineLoad(8, 8, 100, 0.0, 0)
+    warmed_light = EngineLoad(8, 6, 0, 20.0, 50)
     assert load_score(cold_drowning) > load_score(warmed_light) > 0
     # and the admission gate sees a usable wait bound from both
     for load in (cold_drowning, warmed_light):

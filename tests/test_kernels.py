@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import fused_snn, ops, ref
 from repro.core import prng
 
 
@@ -46,24 +46,34 @@ def test_lif_forward_matches_ref(rng, b, n_in, n_out, t, shift, prune):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(we))
 
 
-@pytest.mark.parametrize("mode", ["masked", "mxu"])
+@pytest.mark.parametrize("density", [0.0, 0.1, 0.9])
 @pytest.mark.parametrize("b,n_in,n_out", [
     (8, 784, 10), (4, 256, 384), (1, 300, 7), (16, 64, 128),
 ])
-def test_spike_matmul_matches_ref(rng, mode, b, n_in, n_out):
-    spikes = jnp.asarray(rng.integers(0, 2, (b, n_in), dtype=np.uint8))
-    w = jnp.asarray(rng.integers(-128, 128, (n_in, n_out), dtype=np.int8))
-    got = ops.spike_matmul_op(spikes, w, mode=mode, interpret=True)
+def test_spike_dot_matches_ref(rng, density, b, n_in, n_out):
+    """The stack kernel's contraction (two int8 passes over the packed
+    planes) == the int32 oracle over the full signed 9-bit code range."""
+    spikes = jnp.asarray((rng.random((b, n_in)) < density).astype(np.uint8))
+    w = jnp.asarray(rng.integers(-256, 256, (n_in, n_out)), jnp.int16)
+    got = fused_snn.spike_dot(spikes, fused_snn.pack_weights(w))
     want = ref.spike_matmul_ref(spikes, w)
+    assert got.dtype == jnp.int32
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_spike_matmul_modes_agree(rng):
-    spikes = jnp.asarray(rng.integers(0, 2, (8, 512), dtype=np.uint8))
-    w = jnp.asarray(rng.integers(-100, 100, (512, 64), dtype=np.int8))
-    a = ops.spike_matmul_op(spikes, w, mode="masked", interpret=True)
-    b = ops.spike_matmul_op(spikes, w, mode="mxu", interpret=True)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+@pytest.mark.parametrize("k", [784, 2048])
+def test_spike_dot_exact_at_code_extremes(k):
+    """All-ones spikes against the extreme codes: every row sums k copies
+    of -256 (hi plane -128, lo 0) or +255 (hi 127, lo 1), where an
+    inexact hi/lo split or a narrow accumulator would show."""
+    spikes = jnp.ones((8, k), jnp.uint8)
+    for code in (-256, 255):
+        w = jnp.full((k, 128), code, jnp.int16)
+        got = fused_snn.spike_dot(spikes, fused_snn.pack_weights(w))
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.full((8, 128), code * k, np.int32))
+        np.testing.assert_array_equal(
+            np.asarray(got), np.asarray(ref.spike_matmul_ref(spikes, w)))
 
 
 def test_lif_kernel_weight_dtypes(rng):

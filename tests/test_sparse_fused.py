@@ -15,9 +15,9 @@ Contracts under test:
     reference on oversized stacks in ONE Pallas launch, while an explicit
     ``fused`` request raises; ``resolve_backend`` walks the
     fused → fused_streamed → staged chain on TPU;
-  * ``ops.spike_matmul_op``'s runtime density dispatch (``mode="auto"``,
-    a ``lax.cond`` over the masked/MXU kernels) is bit-identical to both
-    forced kernels across densities, including all-zero spike tiles.
+  * ``fused_snn.spike_dot``, the kernels' two-pass int8 contraction over
+    the packed planes, equals the int32 oracle across densities,
+    including all-zero spike tiles.
 
 The suite is REPRO_SPARSE_SKIP-sensitive by design: CI runs it twice with
 the env default forced on and off (plus the explicit parametrisations
@@ -311,15 +311,13 @@ def test_resolve_backend_streamed_chain(monkeypatch):
 @given(seed=st.integers(0, 2**31), b=st.integers(1, 9),
        n_in=st.integers(1, 300), n_out=st.integers(1, 140),
        density=st.sampled_from([0.0, 0.1, 0.5, 1.0]))
-def test_spike_matmul_runtime_dispatch(seed, b, n_in, n_out, density):
-    """Property: mode="auto" (runtime lax.cond on observed density) ==
-    masked == mxu == oracle across densities, incl. all-zero tiles."""
+def test_spike_dot_matches_oracle(seed, b, n_in, n_out, density):
+    """Property: spike_dot over the packed planes == the int32 oracle
+    across densities and shapes, incl. all-zero spike tiles."""
     rng = np.random.default_rng(seed)
     spikes = jnp.asarray(
         (rng.random((b, n_in)) < density).astype(np.uint8))
     w = jnp.asarray(rng.integers(-256, 256, (n_in, n_out)), jnp.int16)
     want = np.asarray(ref.spike_matmul_ref(spikes, w))
-    for mode in ("auto", "masked", "mxu"):
-        got = np.asarray(ops.spike_matmul_op(spikes, w, mode=mode,
-                                             interpret=True))
-        np.testing.assert_array_equal(got, want, err_msg=mode)
+    got = np.asarray(fused_snn.spike_dot(spikes, fused_snn.pack_weights(w)))
+    np.testing.assert_array_equal(got, want)

@@ -1,4 +1,4 @@
-"""Telemetry side channel + adaptive dispatch controller.
+"""Telemetry side channel + adaptive chunk-length controller.
 
 Contracts under test:
 
@@ -12,13 +12,9 @@ Contracts under test:
   * the tile-skip mirror (``core.telemetry.layer_tile_skips``) agrees
     with the independently-derived ``kernels.ref.tile_skips_ref`` oracle
     (double-entry bookkeeping for the launch geometry);
-  * the dispatch threshold resolves config → env → the historical
-    ``kernels.ops.SPIKE_DENSITY_THRESHOLD`` constant, and
-    ``spike_matmul_op`` honors the boundary + reports its density
-    telemetry;
   * the controller in frozen mode reproduces the static choices exactly
     (and never syncs), while adaptive mode — property-tested over random
-    traffic — changes ONLY performance-facing knobs: engine results are
+    traffic — changes ONLY the chunk length: engine results are
     bit-identical with adaptivity on and off.
 """
 
@@ -33,8 +29,8 @@ from hypothesis import given, settings, strategies as st
 from repro.configs.snn_mnist import SNN_CONFIG, SNN_CONFIG_DEEP
 from repro.core import prng, snn
 from repro.core.telemetry import (concat_telemetry, layer_tile_skips,
-                                  resolve_density_threshold, tiles_total)
-from repro.kernels import ops, ref
+                                  tiles_total)
+from repro.kernels import ref
 from repro.serve import (AdaptiveDispatchConfig, SNNStreamEngine,
                          TelemetryController, summarize_chunk)
 from repro.serve.telemetry import make_controller
@@ -165,70 +161,28 @@ def test_tile_skip_mirror_matches_ref_oracle(rng):
 
 
 # ---------------------------------------------------------------------------
-# dispatch threshold resolution + spike_matmul telemetry
-# ---------------------------------------------------------------------------
-
-def test_density_threshold_resolution(monkeypatch):
-    """Explicit config value → env override → the historical constant."""
-    monkeypatch.delenv("REPRO_SPIKE_DENSITY_THRESHOLD", raising=False)
-    assert resolve_density_threshold(None) == ops.SPIKE_DENSITY_THRESHOLD
-    monkeypatch.setenv("REPRO_SPIKE_DENSITY_THRESHOLD", "0.4")
-    assert resolve_density_threshold(None) == 0.4
-    assert resolve_density_threshold(0.1) == 0.1        # explicit wins
-    cfg = dataclasses.replace(SNN_CONFIG, spike_density_threshold=0.33)
-    assert resolve_density_threshold(cfg.spike_density_threshold) == 0.33
-
-
-def test_spike_matmul_threshold_and_telemetry(rng):
-    """The dispatch boundary is honored and reported: threshold 1.0 forces
-    the masked kernel, 0.0 forces MXU, and the result never changes."""
-    spikes = jnp.asarray((rng.random((6, 96)) < 0.3).astype(np.uint8))
-    w = jnp.asarray(rng.integers(-256, 256, (96, 40)), jnp.int16)
-    want = np.asarray(ref.spike_matmul_ref(spikes, w))
-    outs = {}
-    for thr in (1.0, 0.0):
-        out, tel = ops.spike_matmul_op(spikes, w, mode="auto",
-                                       density_threshold=thr,
-                                       with_telemetry=True, interpret=True)
-        outs[thr] = np.asarray(out)
-        np.testing.assert_array_equal(outs[thr], want)
-        assert bool(tel.used_masked) == (thr == 1.0)
-        np.testing.assert_allclose(float(tel.density),
-                                   float(np.mean(np.asarray(spikes) != 0)),
-                                   rtol=1e-6)
-    np.testing.assert_array_equal(outs[1.0], outs[0.0])
-
-
-# ---------------------------------------------------------------------------
 # adaptive controller
 # ---------------------------------------------------------------------------
 
 def test_frozen_controller_reproduces_static_choices(monkeypatch):
-    """Frozen mode IS today's behavior: the static threshold and chunk
-    length come back verbatim and observations are no-ops."""
+    """Frozen mode IS today's behavior: the static chunk length comes
+    back verbatim and observations are no-ops."""
     monkeypatch.delenv("REPRO_ADAPTIVE_DISPATCH", raising=False)
-    monkeypatch.delenv("REPRO_SPIKE_DENSITY_THRESHOLD", raising=False)
-    ctl = make_controller(None, spike_density_threshold=None,
-                          chunk_steps=4, num_steps=20)
+    ctl = make_controller(None, chunk_steps=4, num_steps=20)
     assert ctl.frozen
-    assert ctl.dispatch_threshold == ops.SPIKE_DENSITY_THRESHOLD
     assert ctl.chunk_steps == 4 and ctl.min_chunk_steps == 4
     ctl.observe(None)           # frozen observe never touches the summary
-    assert ctl.history == [] and ctl.density_ewma is None
-    ctl2 = make_controller(None, spike_density_threshold=0.4,
-                           chunk_steps=6, num_steps=20)
-    assert ctl2.dispatch_threshold == 0.4 and ctl2.chunk_steps == 6
+    assert ctl.history == []
+    ctl2 = make_controller(None, chunk_steps=6, num_steps=20)
+    assert ctl2.chunk_steps == 6
 
 
-def test_adaptive_controller_tracks_density_and_retunes():
-    """Deterministic control law: the EWMA converges toward the observed
-    density, the threshold follows it within bounds, and the chunk length
-    shrinks under retirement pressure / grows in quiet steady state."""
-    cfg = AdaptiveDispatchConfig(adaptive=True, ewma_alpha=0.5,
-                                 min_chunk_steps=2, max_chunk_steps=8,
-                                 grow_patience=2)
-    ctl = TelemetryController(cfg=cfg, static_threshold=0.25,
-                              static_chunk_steps=4, num_steps=20)
+def test_adaptive_controller_retunes_chunk_length():
+    """Deterministic control law: the chunk length shrinks under
+    retirement pressure and grows in quiet steady state."""
+    cfg = AdaptiveDispatchConfig(adaptive=True, min_chunk_steps=2,
+                                 max_chunk_steps=8, grow_patience=2)
+    ctl = TelemetryController(cfg=cfg, static_chunk_steps=4, num_steps=20)
 
     def summary(density, retired, active):
         from repro.serve import ChunkSummary
@@ -239,17 +193,13 @@ def test_adaptive_controller_tracks_density_and_retunes():
 
     for _ in range(8):
         ctl.observe(summary(0.04, retired=4, active=8))
-    assert abs(ctl.density_ewma - 0.04) < 1e-3
-    # gain 1.5 × 0.04 = 0.06 — the boundary walked down toward the traffic
-    assert 0.05 <= ctl.dispatch_threshold < 0.25
     assert ctl.chunk_steps == cfg.min_chunk_steps   # retirement pressure
     for _ in range(10):
         ctl.observe(summary(0.04, retired=0, active=8))
     assert ctl.chunk_steps > cfg.min_chunk_steps    # quiet → grow
     assert len(ctl.history) == 18
     # trajectory is replayable: same observations → same decisions
-    ctl2 = TelemetryController(cfg=cfg, static_threshold=0.25,
-                               static_chunk_steps=4, num_steps=20)
+    ctl2 = TelemetryController(cfg=cfg, static_chunk_steps=4, num_steps=20)
     for _ in range(8):
         ctl2.observe(summary(0.04, retired=4, active=8))
     for _ in range(10):
@@ -284,7 +234,7 @@ def test_summarize_chunk_measures_known_density(rng):
        patience=st.sampled_from([1, 2, 10_000]))
 def test_adaptive_never_changes_predictions(seed, level, patience):
     """THE acceptance property: with the controller adaptive (live chunk
-    lengths + threshold) every request's prediction, retirement step,
+    lengths) every request's prediction, retirement step,
     spike registers and frozen add counter are bit-identical to frozen
     mode — adaptivity moves wall-clock only."""
     rng = np.random.default_rng(seed % (2**31))
@@ -347,18 +297,15 @@ def test_summarize_chunk_all_frozen_lanes_no_blowup():
 
 def test_zero_signal_chunks_leave_estimator_untouched():
     """Zero-lane-step / zero-active observations carry no information:
-    the EWMA, threshold and chunk length must not move (in particular the
-    retirement fraction 0/0 must not be computed)."""
-    cfg = AdaptiveDispatchConfig(adaptive=True, ewma_alpha=0.5)
-    ctl = TelemetryController(cfg=cfg, static_threshold=0.25,
-                              static_chunk_steps=4, num_steps=20)
+    the chunk length must not move (in particular the retirement
+    fraction 0/0 must not be computed)."""
+    cfg = AdaptiveDispatchConfig(adaptive=True)
+    ctl = TelemetryController(cfg=cfg, static_chunk_steps=4, num_steps=20)
     ctl.observe(_summary(0.1, retired=0, active=8))
-    ewma, thr, chunk, quiet = (ctl.density_ewma, ctl.dispatch_threshold,
-                               ctl.chunk_steps, ctl._quiet)
+    chunk, quiet = ctl.chunk_steps, ctl._quiet
     for _ in range(5):
         ctl.observe(_summary(0.0, retired=0, active=0, lane_steps=0))
-    assert ctl.density_ewma == ewma
-    assert ctl.dispatch_threshold == thr and ctl.chunk_steps == chunk
+    assert ctl.chunk_steps == chunk
     assert ctl._quiet == quiet      # empty chunks are not "quiet traffic"
 
 
@@ -368,8 +315,7 @@ def test_chunk_length_clamps_at_bounds():
     by the window itself (num_steps)."""
     cfg = AdaptiveDispatchConfig(adaptive=True, min_chunk_steps=2,
                                  max_chunk_steps=12, grow_patience=1)
-    ctl = TelemetryController(cfg=cfg, static_threshold=0.25,
-                              static_chunk_steps=4, num_steps=8)
+    ctl = TelemetryController(cfg=cfg, static_chunk_steps=4, num_steps=8)
     for _ in range(50):             # retirement storm, far past the clamp
         ctl.observe(_summary(0.1, retired=8, active=8))
     assert ctl._chunk == cfg.min_chunk_steps
@@ -378,14 +324,6 @@ def test_chunk_length_clamps_at_bounds():
         ctl.observe(_summary(0.1, retired=0, active=8))
     assert ctl._chunk == cfg.max_chunk_steps
     assert ctl.chunk_steps == min(cfg.max_chunk_steps, ctl.num_steps) == 8
-    # threshold clamp: an absurd density pins at threshold_max, silence
-    # at threshold_min
-    for _ in range(20):
-        ctl.observe(_summary(1.0, retired=0, active=8))
-    assert ctl.dispatch_threshold == cfg.threshold_max
-    for _ in range(200):
-        ctl.observe(_summary(0.0, retired=0, active=8))
-    assert ctl.dispatch_threshold == cfg.threshold_min
 
 
 def test_controller_pickle_restore_determinism():
@@ -395,32 +333,26 @@ def test_controller_pickle_restore_determinism():
     def drive(ctl, summaries):
         for s in summaries:
             ctl.observe(s)
-        return [(h["chunk_steps"], h["dispatch_threshold"])
-                for h in ctl.history]
+        return [h["chunk_steps"] for h in ctl.history]
 
     traffic = ([_summary(0.05, retired=2, active=8)] * 6
                + [_summary(0.3, retired=0, active=8)] * 6)
-    cfg = AdaptiveDispatchConfig(adaptive=True, ewma_alpha=0.5,
-                                 min_chunk_steps=2, max_chunk_steps=8,
-                                 grow_patience=2)
-    a = TelemetryController(cfg=cfg, static_threshold=0.25,
-                            static_chunk_steps=4, num_steps=20)
+    cfg = AdaptiveDispatchConfig(adaptive=True, min_chunk_steps=2,
+                                 max_chunk_steps=8, grow_patience=2)
+    a = TelemetryController(cfg=cfg, static_chunk_steps=4, num_steps=20)
     full = drive(a, traffic)
-    b = TelemetryController(cfg=cfg, static_threshold=0.25,
-                            static_chunk_steps=4, num_steps=20)
+    b = TelemetryController(cfg=cfg, static_chunk_steps=4, num_steps=20)
     drive(b, traffic[:5])
     b2 = pickle.loads(pickle.dumps(b))
-    assert (b2.density_ewma, b2._chunk, b2._quiet) == \
-        (b.density_ewma, b._chunk, b._quiet)
+    assert (b2._chunk, b2._quiet) == (b._chunk, b._quiet)
     resumed = drive(b2, traffic[5:])
     assert resumed == full
     # frozen controller: the round-trip preserves the static choices and
     # observe stays a no-op
     f = make_controller(AdaptiveDispatchConfig(adaptive=False),
-                        spike_density_threshold=0.4, chunk_steps=6,
-                        num_steps=20)
+                        chunk_steps=6, num_steps=20)
     f2 = pickle.loads(pickle.dumps(f))
-    assert f2.frozen and f2.dispatch_threshold == 0.4
+    assert f2.frozen
     assert f2.chunk_steps == 6 and f2.min_chunk_steps == 6
     f2.observe(None)
-    assert f2.history == [] and f2.density_ewma is None
+    assert f2.history == []

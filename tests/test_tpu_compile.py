@@ -23,6 +23,7 @@ from repro.configs.snn_mnist import (SNN_CONFIG_CONV, SNN_CONFIG_DEEP,
                                      SNN_CONFIG_WIDE)
 from repro.core.layers import weight_shapes
 from repro.kernels import fused_snn, ops
+from repro.serve.snn_engine import LaneTileCodec, _init_lanes
 
 LANES, CHUNK, T = 64, 4, 20
 
@@ -128,9 +129,16 @@ def test_staged_lif_compiles(spec, n_in, n_out):
         interpret=False).compile()
 
 
-@pytest.mark.parametrize("mode", ["mxu", "masked", "auto"])
-def test_spike_matmul_compiles(spec, mode):
-    f = jax.jit(lambda s, w: ops.spike_matmul_op(s, w, mode=mode,
-                                                 interpret=False))
-    assert f.lower(spec((LANES, 784), jnp.uint8),
-                   spec((784, 128), jnp.int16)).compile()
+@pytest.mark.parametrize("sizes", [
+    (784, 10), SNN_CONFIG_WIDE.layer_sizes, SNN_CONFIG_CONV.layer_sizes,
+], ids=["paper", "wide", "conv"])
+def test_lane_tile_codec_compiles(spec, sizes):
+    """The engine's one-slab tile transfer: pack on the way down, unpack
+    on the way up, at the benchmark cells' networks and 64 lanes."""
+    codec = LaneTileCodec(tuple(sizes))
+    lanes = jax.tree.map(
+        lambda a: spec(a.shape, a.dtype),
+        jax.eval_shape(lambda: _init_lanes(LANES, tuple(sizes), T, 0)))
+    assert LaneTileCodec.pack.lower(codec, lanes).compile()
+    assert LaneTileCodec.unpack.lower(
+        codec, spec((LANES, codec.width), jnp.uint32), None).compile()
